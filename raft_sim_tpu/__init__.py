@@ -7,25 +7,6 @@ adjacency-masked message scatter and the event loop as a jit-compiled `lax.scan`
 See SURVEY.md for the structural map between the two designs.
 """
 
-import os as _os
-
-import jax as _jax
-
-# The simulator's reproducibility contract -- cluster i's trajectory is
-# independent of batch size and device count (tests/test_fuzz.py
-# test_batch_size_invariance, tests/test_parallel.py) -- requires
-# jax.random.split(key, n) to be a prefix-stable function of the key. That is
-# the partitionable-threefry semantics, the default from jax 0.6 on; on older
-# jax (this image ships 0.4.x) the legacy stateful-counter derivation makes
-# split(k, 4) disagree with split(k, 64)[:4], silently breaking the invariance
-# the whole fleet design leans on. Pin the partitionable semantics explicitly
-# so every jax version runs the same (documented) key-derivation scheme -- but
-# respect a host program that explicitly pinned the flag itself via the
-# standard env var (importing this package for one utility must not silently
-# re-derive an embedding application's own random streams).
-if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
-    _jax.config.update("jax_threefry_partitionable", True)
-
 from raft_sim_tpu.types import (
     CANDIDATE,
     FOLLOWER,

@@ -185,7 +185,7 @@ def bench_anchor(root: str | None = None):
         # >= r06 records `backend` per row; obs/reconcile.py marks such rows
         # non-anchor for the same reason) would silently rebase the implied
         # rate onto host-memory throughput. Rows with no backend field
-        # (BENCH_r01-r05) are kept: all were recorded on the real chip.
+        # (BENCH_r05) are kept: it was recorded on a chip.
         if v.get("backend") == "cpu":
             notes.append(
                 f"{newest}: {k} row measured on the cpu backend: ignored "

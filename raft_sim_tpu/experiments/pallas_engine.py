@@ -49,15 +49,6 @@ from raft_sim_tpu.types import ClusterState, StepInfo, StepInputs
 from raft_sim_tpu.utils.config import RaftConfig
 
 
-# jax renamed TPUCompilerParams -> CompilerParams across the 0.5/0.6 line;
-# resolve whichever this version has (same kwargs) so the compiled path reaches
-# the real Mosaic verdict on every supported jax instead of an AttributeError
-# -- the same version-portability treatment parallel/mesh.py's shard_map got.
-_compiler_params = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-
 def _lift(x):
     """[B] -> [1, B] so every ref is at least 2-D."""
     return x[None, :] if x.ndim == 1 else x
@@ -123,7 +114,7 @@ def step_pallas(
         interpret=interpret,
         compiler_params=None
         if interpret
-        else _compiler_params(
+        else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # The one-hot intermediates ([N,N,E,CAP,BB] etc.) are VMEM-hungry; let
             # Mosaic use the whole budget instead of its conservative default.

@@ -1280,8 +1280,10 @@ def _step(cfg: RaftConfig, s: ClusterState, inp: StepInputs) -> tuple[ClusterSta
     # core.clj:48-54, append-entries-rpc core.clj:56-67); the only per-edge request
     # datum is the AE window offset (Mailbox docstring).
     ae_edge = send_append[:, None] & ~eye
+    # A strong int32 0: a weak-typed req_type would flip the carry's type
+    # after the first chunk and recompile every chunk program once more.
     out_req_type = jnp.where(
-        start_election, REQ_VOTE, jnp.where(send_append, REQ_APPEND, 0)
+        start_election, REQ_VOTE, jnp.where(send_append, REQ_APPEND, jnp.int32(0))
     )  # [N]
     if cfg.pre_vote:
         out_req_type = jnp.where(start_prevote, REQ_PREVOTE, out_req_type)

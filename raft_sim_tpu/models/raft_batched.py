@@ -1188,8 +1188,10 @@ def _step_b(
     # Request headers are per sender (both RPCs are broadcasts); only the AE window
     # offset is per edge (Mailbox docstring; raft.py phase 8).
     ae_edge = send_append[:, None, :] & ~eye_ls
+    # A strong int32 0: a weak-typed req_type would flip the carry's type
+    # after the first chunk and recompile every chunk program once more.
     out_req_type = jnp.where(
-        start_election, REQ_VOTE, jnp.where(send_append, REQ_APPEND, 0)
+        start_election, REQ_VOTE, jnp.where(send_append, REQ_APPEND, jnp.int32(0))
     )  # [N, B]
     if cfg.pre_vote:
         out_req_type = jnp.where(start_prevote, REQ_PREVOTE, out_req_type)

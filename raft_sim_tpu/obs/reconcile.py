@@ -54,7 +54,7 @@ def load_pins(path: str | None = None) -> dict:
 def _measured(row: dict) -> tuple[float | None, str]:
     """(cluster-ticks/s, source) from a bench row: the warmup-excluded steady
     value when the row carries one (bench >= r06), else the legacy
-    best-of-repeats headline (BENCH_r01-r05 artifacts)."""
+    best-of-repeats headline (the BENCH_r05 artifact)."""
     v = row.get("steady_ticks_per_s")
     if v:
         return float(v), "steady"

@@ -13,6 +13,10 @@ property tested in tests/test_parallel.py. `shard_map` (not bare jit-with-shardi
 used so the compiled program provably contains no accidental cross-device traffic in the
 hot loop; the only cross-device movement is the host-side gather in `summarize`, which
 pulls the small per-cluster RunMetrics off device for the fleet rollup.
+
+Every `jax.shard_map` here and in nodeshard.py passes `check_vma=False`: the scan
+carry mixes axis-invariant constants (init_metrics zeros) with per-cluster varying
+state, which the varying-manual-axes check would reject.
 """
 
 from __future__ import annotations
@@ -29,24 +33,6 @@ from raft_sim_tpu.types import init_state
 from raft_sim_tpu.utils.config import RaftConfig
 
 AXIS = "clusters"
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map: jax >= 0.6 exposes it top-level with the
-    varying-manual-axes check named `check_vma`; jax 0.4/0.5 (this image) has it
-    in jax.experimental with the same check named `check_rep`. The check is
-    disabled either way: the scan carry mixes axis-invariant constants
-    (init_metrics zeros) with per-cluster varying state, and the body has no
-    cross-device communication to validate."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 def init_distributed(
@@ -118,11 +104,12 @@ def simulate_sharded(cfg: RaftConfig, seed, batch: int, n_ticks: int, mesh: Mesh
     keys_init = jax.random.split(k_init, batch)
     keys_run = jax.random.split(k_run, batch)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         functools.partial(_run_shard, cfg, n_ticks),
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS)),
         out_specs=P(AXIS),
+        check_vma=False,
     )
     keys_init = _constrain_keys(keys_init, mesh)
     keys_run = _constrain_keys(keys_run, mesh)
@@ -198,8 +185,9 @@ def simulate_windowed_sharded(
             lambda s: P(*([None] * (s.ndim - 1)), AXIS), t
         )
         out_specs += [minor(shapes[3]), minor(shapes[4])]
-    sharded = _shard_map(
-        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=tuple(out_specs)
+    sharded = jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(in_specs), out_specs=tuple(out_specs),
+        check_vma=False,
     )
     out = sharded(*args)
     if trace is None:
@@ -208,16 +196,9 @@ def simulate_windowed_sharded(
 
 
 def _constrain_keys(keys, mesh: Mesh):
-    """Batch-shard a typed PRNG key array. The constraint is applied to the raw
-    key DATA ([B, 2] uint32) and the keys re-wrapped: older jax (0.4.x) fails
-    to extend a rank-1 sharding spec over the key dtype's hidden trailing dim
-    ("tile assignment dimensions different than input rank" at compile time),
-    while the data route lowers identically on every supported version. Values
+    """Batch-shard a typed PRNG key array over the mesh's cluster axis. Values
     are untouched -- only placement metadata is attached."""
-    kd = jax.random.key_data(keys)
-    spec = P(AXIS, *([None] * (kd.ndim - 1)))
-    kd = jax.lax.with_sharding_constraint(kd, NamedSharding(mesh, spec))
-    return jax.random.wrap_key_data(kd)
+    return jax.lax.with_sharding_constraint(keys, NamedSharding(mesh, P(AXIS)))
 
 
 class FleetSummary(NamedTuple):

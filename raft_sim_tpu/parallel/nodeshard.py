@@ -372,11 +372,12 @@ def simulate_node_sharded(
     state = pad_state(cfg, init_batch(cfg, k_init, batch), n_pad)
     keys = mesh_mod._constrain_keys(jax.random.split(k_run, batch), mesh)
 
-    sharded = mesh_mod._shard_map(
+    sharded = jax.shard_map(
         functools.partial(_run_shard, cfg, n_ticks, nl, n_pad),
         mesh=mesh,
         in_specs=(state_specs(), P(AXIS)),
         out_specs=(state_specs(), metrics_specs()),
+        check_vma=False,
     )
     return sharded(state, keys)
 
@@ -452,10 +453,11 @@ def simulate_node_sharded_windowed(
     rec_specs = WindowRecord(
         start=P(AXIS), first_viol_tick=P(AXIS), metrics=metrics_specs()
     )
-    sharded = mesh_mod._shard_map(
+    sharded = jax.shard_map(
         functools.partial(_run_shard_windowed, cfg, n_ticks, window, nl, n_pad),
         mesh=mesh,
         in_specs=(state_specs(), P(AXIS)),
         out_specs=(state_specs(), metrics_specs(), rec_specs),
+        check_vma=False,
     )
     return sharded(state, keys)

@@ -11,19 +11,7 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Environment gate, not a flake: the two-process proof needs cross-process
-# collectives on the CPU backend, which jaxlib only implements from the 0.5
-# line on -- on this image's jax 0.4.x the child processes die with
-# "INVALID_ARGUMENT: Multiprocess computations aren't implemented on the CPU
-# backend". Single-process multi-device sharding (tests/test_parallel.py)
-# covers the mesh path everywhere; this proof re-arms automatically once the
-# environment can run it.
-_JAX_TOO_OLD = tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5)
 
 
 def test_multichip_artifact_schema():
@@ -37,10 +25,6 @@ def test_multichip_artifact_schema():
     assert errs and "legacy" in errs[0], errs
 
 
-@pytest.mark.skipif(
-    _JAX_TOO_OLD,
-    reason="jax<0.5 CPU backend: 'Multiprocess computations aren't implemented'",
-)
 def test_two_process_cluster_matches_single_process():
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "multihost_check.py")],

@@ -132,29 +132,6 @@ def local() -> None:
     print(json.dumps(_run_and_dump()), flush=True)
 
 
-def single() -> None:
-    """Single-process fallback for images whose CPU backend lacks cross-process
-    collectives (jax < 0.5: "Multiprocess computations aren't implemented").
-    The parity claim degrades from cross-PROCESS to cross-PROGRAM but stays
-    bit-exact: the 8-device sharded run against the dense unsharded kernel,
-    same (cfg, seed, batch, ticks). Re-arms to the two-process proof
-    automatically once the environment supports it (orchestrate)."""
-    import jax
-    import numpy as np
-
-    jax.config.update("jax_platforms", "cpu")
-    assert jax.device_count() == 8, jax.device_count()
-    out = _run_and_dump()
-    from raft_sim_tpu import RaftConfig
-    from raft_sim_tpu.sim import scan
-
-    _, md = scan.simulate(RaftConfig(**CFG_KW), SEED, BATCH, TICKS)
-    out["dense_metrics"] = {
-        f: np.asarray(v).tolist() for f, v in zip(md._fields, md)
-    }
-    print(json.dumps(out), flush=True)
-
-
 def _emit_artifact(out_path: str, verdict: dict, parity_hash: str,
                    throughput: float, reference: float, n_processes: int) -> None:
     doc = {
@@ -186,53 +163,7 @@ def _spawn(env, *, me: str):
     )
 
 
-def orchestrate_single(out_path: str | None = None) -> int:
-    """The jax<0.5 fallback orchestration: one 8-device process, sharded vs
-    dense bit-exactness (see `single`)."""
-    me = os.path.abspath(__file__)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["_MH_MODE"] = "single"
-    p = _spawn(env, me=me)
-    try:
-        out, err = p.communicate(timeout=480)
-    except subprocess.TimeoutExpired:
-        p.kill()
-        print(json.dumps({"match": False, "error": "single-process run timed out"}))
-        return 1
-    if p.returncode != 0:
-        print(json.dumps({"match": False, "error": f"rc={p.returncode}",
-                          "stderr_tail": err[-2000:]}))
-        return 1
-    got = json.loads(out.strip().splitlines()[-1])
-    h_got = _parity_hash(got)
-    h_want = _parity_hash({"metrics": got["dense_metrics"]})
-    match = h_got == h_want
-    verdict = {
-        "match": match,
-        "n_processes": 1,
-        "global_devices": 8,
-        "batch": BATCH,
-        "ticks": TICKS,
-        "violations": sum(got["metrics"]["violations"]),
-        "summary": got["summary"],
-        "note": "single-process fallback (jax<0.5 CPU backend): sharded vs "
-                "dense parity; two-process proof re-arms on newer jax",
-    }
-    print(json.dumps(verdict))
-    if out_path is not None:
-        _emit_artifact(out_path, verdict, h_got,
-                       got["throughput_ticks_per_s"],
-                       got["throughput_ticks_per_s"], n_processes=1)
-    return 0 if match else 1
-
-
 def orchestrate(out_path: str | None = None) -> int:
-    import jax
-
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        return orchestrate_single(out_path)
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
     port = str(s.getsockname()[1])
@@ -315,9 +246,6 @@ def main() -> int:
         return 0
     if mode == "local":
         local()
-        return 0
-    if mode == "single":
-        single()
         return 0
     import argparse
 
