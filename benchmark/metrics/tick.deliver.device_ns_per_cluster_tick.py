@@ -1,0 +1,10 @@
+"""tick.deliver.device_ns_per_cluster_tick: device self-time of the fleet chunk program's ops under
+the `tick.deliver` named scope (phases -1 (restart), 0 (delivery) and 1 (term adoption) of models/raft_batched._step_b), in the traced window, per cluster-tick
+it ran. Ops are mapped to phases by the optimized HLO's op_name metadata
+(benchmark/programtrace.py). Moves cluster_ticks_per_s."""
+
+from benchmark import programtrace
+
+
+def read(run, out):
+    return programtrace.tick_phase_ns_per_cluster_tick(run, out, "deliver")

@@ -1,0 +1,10 @@
+"""tick.elect.device_ns_per_cluster_tick: device self-time of the fleet chunk program's ops under
+the `tick.elect` named scope (phases 2 (RequestVote), 3.5 (PreVote), 3.7 (TimeoutNow), 4.5 (promotion) and 7 (timers)), in the traced window, per cluster-tick
+it ran. Ops are mapped to phases by the optimized HLO's op_name metadata
+(benchmark/programtrace.py). Moves cluster_ticks_per_s."""
+
+from benchmark import programtrace
+
+
+def read(run, out):
+    return programtrace.tick_phase_ns_per_cluster_tick(run, out, "elect")

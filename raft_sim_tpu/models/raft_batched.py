@@ -24,6 +24,7 @@ import numpy as np
 from jax import lax
 
 from raft_sim_tpu.models import cfglog
+from raft_sim_tpu.obs.scopes import PhaseScopes
 from raft_sim_tpu.ops import bitplane, log_ops
 from raft_sim_tpu.storage import plane as storage_plane
 from raft_sim_tpu.types import (
@@ -157,22 +158,33 @@ def step_b(
     compacted carry (ops/tile.py; trailing batch axes ride along), run the
     identical dense tick, repack with gated-off legs passed through
     verbatim.
-    """
-    if not cfg.compact_planes:
-        return _step_b(cfg, s, inp, sh)
-    assert sh is None  # sharded carries run dense (parallel/nodeshard.py)
-    from raft_sim_tpu.ops import tile
 
-    s2, info = _step_b(
-        cfg, tile.unpack_state(cfg, s), tile.unpack_inputs(cfg, inp)
-    )
+    The dense tick's phases run under the `tick.<phase>` named scopes of
+    obs/scopes.py (op metadata only: the lowered program is unchanged).
+    """
+    with PhaseScopes() as phase:
+        if not cfg.compact_planes:
+            return _step_b(cfg, s, inp, sh, phase)
+        assert sh is None  # sharded carries run dense (parallel/nodeshard.py)
+        from raft_sim_tpu.ops import tile
+
+        s2, info = _step_b(
+            cfg, tile.unpack_state(cfg, s), tile.unpack_inputs(cfg, inp), None, phase
+        )
     return tile.pack_state(cfg, s2, reuse=s), info
 
 
 def _step_b(
-    cfg: RaftConfig, s: ClusterState, inp: StepInputs, sh: NodeShardCtx | None = None
+    cfg: RaftConfig,
+    s: ClusterState,
+    inp: StepInputs,
+    sh: NodeShardCtx | None,
+    phase: PhaseScopes,
 ) -> tuple[ClusterState, StepInfo]:
     """The dense batch-minor tick body (layout-independent semantics).
+
+    `phase(name)` opens the named scope of each phase group as the body
+    reaches it (obs/scopes.py has the table); the body closes the last.
 
     `sh` (NodeShardCtx) switches to node-sharded execution inside a shard_map
     over sh.axis: `s` carries this device's nl node rows (peer axes padded to
@@ -242,6 +254,7 @@ def _step_b(
         )
     zw = jnp.uint32(0)
 
+    phase("deliver")
     # ---- phase -1: restart (crash fault) -----------------------------------------
     # The snapshot triple is persistent: commit resumes at log_base (raft.py).
     rs = inp.restarted  # [N, B]
@@ -394,6 +407,7 @@ def _step_b(
     else:
         my_last_idx, my_last_term = log_ops.last_index_term_b(s.log_term, s.log_len)
 
+    phase("elect")
     # ---- phase 2: RequestVote requests -------------------------------------------
     is_rv = req_in & (mb.req_type == REQ_VOTE)[:, None, :]  # [candidate, voter, B]
     cur_rv = is_rv & (mb.req_term[:, None, :] == term[None, :, :])
@@ -419,6 +433,7 @@ def _step_b(
     # Grant target = post-update voted_for (raft.py phase 2: no reduction needed).
     grant_to = jnp.where(granted_any, voted_for, NIL).astype(node_dtype(cfg))  # [N, B]
 
+    phase("replicate")
     # ---- phase 3: AppendEntries requests ------------------------------------------
     is_ae = req_in & (mb.req_type == REQ_APPEND)[:, None, :]  # [leader, follower, B]
     cur_ae = is_ae & (mb.req_term[:, None, :] == term[None, :, :])
@@ -617,6 +632,7 @@ def _step_b(
     out_a_match = out_a_match.astype(idt)  # bounded by the responder's log length
     out_a_hint = log_len.astype(idt)  # post-append, pre-injection (phase 6 rebinds)
 
+    phase("elect")
     # ---- phase 3.5: PreVote requests (thesis 9.6; raft.py) -----------------------
     if cfg.pre_vote or rdl or rcf:
         # heard_clock serves the pre-vote quiet rule, the lease vote denial,
@@ -659,6 +675,7 @@ def _step_b(
         else:
             coup = jnp.zeros_like(xfer_elect)
 
+    phase("replicate")
     # ---- phase 4: responses ------------------------------------------------------
     vresp = resp_in & (mb.resp_kind == RESP_VOTE)
     new_votes = (
@@ -686,6 +703,7 @@ def _step_b(
     next_index = jnp.where(win[:, None, :], (len_i + 1)[:, None, :], s.next_index)
     match_index = jnp.where(win[:, None, :], 0, s.match_index)
 
+    phase("elect")
     # ---- phase 4.5: PreVote responses + promotion (thesis 9.6; raft.py) ----------
     if cfg.pre_vote:
         # Grant bits ride the packed pv_grant plane (raft.py phase 4.5).
@@ -708,6 +726,7 @@ def _step_b(
     else:
         pre_win = jnp.zeros_like(win)
 
+    phase("replicate")  # phase 4's AppendEntries responses
     aresp = (
         resp_in
         & (mb.resp_kind == RESP_APPEND)
@@ -729,6 +748,7 @@ def _step_b(
     ack_age = jnp.minimum(s.ack_age + 1, cfg.ack_age_sat)
     ack_age = jnp.where(win[:, None, :] | aresp, 0, ack_age)
 
+    phase("commit")
     # ---- phase 5: leader commit advancement --------------------------------------
     is_leader = role == LEADER
     if dur and cfg.durable_acks:
@@ -938,6 +958,7 @@ def _step_b(
         lat_excluded = jnp.zeros_like(s.now)
         lat_frontier = s.lat_frontier
 
+    phase("compact")
     # ---- phase 5.5: log compaction (raft.py) -------------------------------------
     base_mid, bchk_mid = base, bchk  # post-install, pre-advance (checksum anchor)
     if comp:
@@ -973,6 +994,7 @@ def _step_b(
         bchk = bchk_mid + s_bf
         chk_new = bchk_mid + s_cn
 
+    phase("client")
     # ---- phase 6: client command injection, redirect routing, election-win
     # no-op (raft.py phase 6) --------------------------------------------------------
     if comp:
@@ -1100,6 +1122,7 @@ def _step_b(
         )
     log_len = log_len + do_write
 
+    phase("elect")
     # ---- phase 7: timers ---------------------------------------------------------
     clock = s.clock + inp.skew
     reset_election = granted_any | has_ae | saw_higher
@@ -1155,6 +1178,7 @@ def _step_b(
         votes = jnp.where(start_election[:, None, :], eye_p3, votes)
         deadline = jnp.where(start_election, clock + inp.timeout_draw, deadline)
 
+    phase("outbox")
     # ---- phase 7.5: fsync flush + durability gates (raft.py phase 7.5) -----------
     if dur:
         fs_fire = inp.fsync_fire & inp.alive  # dead disks never flush
@@ -1373,6 +1397,7 @@ def _step_b(
 
     # Committed-prefix checksum, non-compaction form (log_ops module comment).
     if not comp:
+        phase("compact")
         if cfg.check_invariants:
             chk_old, chk_new = log_ops.prefix_chk2_b(
                 log_term_arr, log_val_arr, s.commit_index, commit
@@ -1381,6 +1406,7 @@ def _step_b(
         else:
             chk_new = s.commit_chk
             chk_ok = jnp.ones_like(s.commit_index, dtype=bool)
+        phase("outbox")
 
     # ---- end-of-tick config derivation (log-carried membership; raft.py) ---------
     if rcf:
@@ -1449,6 +1475,7 @@ def _step_b(
         mailbox=new_mb,
     )
 
+    phase("check")
     # Durability-lag reductions (host-constant zeros when the plane is off).
     if dur:
         lag = log_len - dur2_len  # [N, B] >= 0 (flush snaps to log_len)
@@ -1464,6 +1491,7 @@ def _step_b(
         reads_served, read_lat_sum, read_hist, viol_read_stale,
         fsync_lag_sum, fsync_lag_max, sh,
     )
+    phase.close()
     return new_state, info
 
 

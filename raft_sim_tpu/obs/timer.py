@@ -37,12 +37,104 @@ Rows stream to the telemetry sink as schema'd perf.jsonl
 (utils/telemetry_sink.py validates them); everything here is host-side
 stdlib + an optional jax device query, so the timer itself can never change a
 trajectory, a lowering, or a compile count.
+
+Beside the timer, always on and with no sync: `span(name, count=None)`, the
+program's host spans. Each is a `jax.profiler.TraceAnnotation` (so it lands
+on the profiler's clock in any trace, an operator's `--profile DIR` trace
+included) and a `Span(name, start, end, count)` of `time.perf_counter`
+seconds appended to the process-wide ring `SPANS` (bounded; the oldest
+drop). The loops open them around their host steps (`fleet.*` in
+sim/chunked.py and sim/telemetry.py, `serve.*` in serve/loop.py), and from
+the first span on JAX's own compile work is recorded as `jax.trace`,
+`jax.lower`, `jax.compile` and `jax.cache_load` spans. docs/OBSERVABILITY.md
+lists every name and the count it carries.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import sys
 import time
+from typing import NamedTuple
+
+SPAN_RING = 65536  # spans kept: 4 to 9 a chunk, so the last ~7,000 chunks or more
+
+
+class Span(NamedTuple):
+    """One host span: perf_counter seconds, and the work it carried (ticks
+    dispatched, delta rows exported), where it has a count."""
+
+    name: str
+    start: float
+    end: float
+    count: int | None
+
+
+class OpenSpan:
+    """The span being recorded: its count, and its perf_counter readings
+    (`end` once closed), for callers that report the duration elsewhere
+    too (ChunkTimer.dispatch_span, the serve row's pack_s/export_s)."""
+
+    __slots__ = ("count", "start", "end")
+
+    def __init__(self, count):
+        self.count = count
+        self.start = time.perf_counter()
+        self.end = None
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class SpanRing:
+    """The program's host spans, newest last, in a bounded ring. Appends are
+    atomic under the interpreter lock, so JAX's compile threads may record
+    into it too. `listen` makes the ring record JAX's compile work from its
+    first span on; only the process ring `SPANS` listens, since JAX keeps a
+    listener for the rest of the process."""
+
+    # JAX monitoring duration events -> span names (_on_duration).
+    COMPILE_EVENTS = {
+        "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+        "/jax/core/compile/backend_compile_duration": "jax.compile",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+    }
+
+    def __init__(self, capacity: int = SPAN_RING, listen: bool = False):
+        self.rows: collections.deque[Span] = collections.deque(maxlen=capacity)
+        self._listen = listen
+
+    @contextlib.contextmanager
+    def span(self, name: str, count: int | None = None):
+        """Time the enclosed host step as `name`; never syncs the device.
+        A count rides the profiler event too, as its `count` argument."""
+        import jax
+
+        if self._listen:
+            self._listen = False
+            jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+        box = OpenSpan(count)
+        args = {} if count is None else {"count": count}
+        try:
+            with jax.profiler.TraceAnnotation(name, **args):
+                yield box
+        finally:
+            box.end = time.perf_counter()
+            self.rows.append(Span(name, box.start, box.end, count))
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        """Record one of JAX's compile steps as a span [now - duration, now]."""
+        name = self.COMPILE_EVENTS.get(event)
+        if name is not None:
+            now = time.perf_counter()
+            self.rows.append(Span(name, now - duration, now, None))
+
+
+SPANS = SpanRing(listen=True)
+span = SPANS.span
 
 
 def device_live_bytes(device=None) -> int | None:
@@ -68,6 +160,8 @@ class ChunkTimer:
     >>> t = ChunkTimer(label="run", batch=batch, sink=sink)
     >>> # inside the loop, per chunk:
     >>> t.begin(n_ticks); out = jitted_chunk(...); t.dispatched()
+    >>> # (or, where the loop times its dispatch as a span: with
+    >>> # span("x.dispatch", n_ticks) as sp: out = ...; t.dispatch_span(sp))
     >>> ...host work...; t.end(sync=lambda: np.asarray(out.ticks))
     >>> t.finish()  # summary dict; prints the recompile finding if it fired
 
@@ -133,8 +227,8 @@ class ChunkTimer:
 
     # --------------------------------------------------------------- phases
 
-    def begin(self, ticks: int) -> None:
-        t = time.perf_counter()
+    def begin(self, ticks: int, at: float | None = None) -> None:
+        t = time.perf_counter() if at is None else at
         self._gap = 0.0 if self._t_prev_end is None else t - self._t_prev_end
         self._ticks = int(ticks)
         self._t_begin = t
@@ -143,6 +237,13 @@ class ChunkTimer:
     def dispatched(self) -> None:
         """Call right after the jitted chunk call returns (async dispatch)."""
         self._t_disp = time.perf_counter()
+
+    def dispatch_span(self, sp) -> None:
+        """`begin` + `dispatched` from the loop's closed dispatch span
+        (obs.span: its start, its end, its tick count), so the row's
+        dispatch_s and the span are one reading."""
+        self.begin(sp.count, at=sp.start)
+        self._t_disp = sp.end
 
     def annotate(self, **extra) -> None:
         """Attach loop-measured sub-phase fields (JSON-able values) to the
@@ -154,7 +255,8 @@ class ChunkTimer:
 
     def end(self, sync=None) -> dict:
         """Close the chunk: `sync` forces a host copy of a small chunk output
-        (its duration is the device wait); sample memory + jit caches, append
+        (its duration is the device wait, also recorded as the host span
+        `<label>.sync`); sample memory + jit caches, append
         the row (and stream it to the sink). `end(sync=...)` is also the
         sync point Pass D's overlap audit recognizes: it CLOSES the
         dispatch->sync window a donating chunk dispatch opened, so host
@@ -162,10 +264,12 @@ class ChunkTimer:
         findings (analysis/race_audit.py)."""
         if self._t_begin is None:
             raise RuntimeError("ChunkTimer.end() without begin()")
-        t_host = time.perf_counter()
         if sync is not None:
-            sync()
-        t = time.perf_counter()
+            with span(f"{self.label}.sync") as sp:
+                sync()
+            t_host, t = sp.start, sp.end
+        else:
+            t_host = t = time.perf_counter()
         t_disp = self._t_disp if self._t_disp is not None else t_host
         caches = self._cache_sizes()
         warmup = self._chunk < self.warmup_chunks

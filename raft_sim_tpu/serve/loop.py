@@ -34,6 +34,7 @@ import numpy as np
 from jax import lax
 
 from raft_sim_tpu.models import raft_batched
+from raft_sim_tpu.obs.timer import span
 from raft_sim_tpu.serve import deltas as deltas_mod
 from raft_sim_tpu.serve.ingest import CommandSource
 from raft_sim_tpu.sim import scan
@@ -321,17 +322,17 @@ class ServeSession:
     def _dispatch(self, cmds_np: np.ndarray, reads_np=None):
         """Issue one chunk (async under jax dispatch); the caller's host
         window (export + packing) runs while this one computes."""
+        ticks = int(cmds_np.shape[0])
+        with span("serve.dispatch", ticks) as sp:
+            cmds = jnp.asarray(cmds_np, jnp.int32)
+            reads = None if reads_np is None else jnp.asarray(reads_np, jnp.int32)
+            self.state, self._m_pending, self._recs_pending = _serve_chunk(
+                self.cfg, self.state, self.keys, cmds, reads, self.window
+            )
         if self.perf is not None:
-            self.perf.begin(int(cmds_np.shape[0]))
-        cmds = jnp.asarray(cmds_np, jnp.int32)
-        reads = None if reads_np is None else jnp.asarray(reads_np, jnp.int32)
-        self.state, self._m_pending, self._recs_pending = _serve_chunk(
-            self.cfg, self.state, self.keys, cmds, reads, self.window
-        )
-        if self.perf is not None:
-            self.perf.dispatched()
+            self.perf.dispatch_span(sp)
         self.chunks_done += 1
-        self.ticks_done += int(cmds_np.shape[0])
+        self.ticks_done += ticks
         self._last_offered = int(np.sum(cmds_np != NIL)) + (
             0 if reads_np is None else int(np.sum(reads_np != NIL))
         )
@@ -412,6 +413,13 @@ class ServeSession:
         `progress(stats_dict)` is called after each chunk. Returns the serve
         stats dict.
 
+        Host spans (obs.span, always on, no sync), per chunk in loop order:
+        `serve.export` (chunk k-1's delta rows), `serve.pack`,
+        `serve.rounds`, `serve.merge`, the timer's sync, `serve.drain`,
+        `serve.progress`, then the next chunk's `serve.dispatch` (its tick
+        count). The perf row's `export_s`, `pack_s` and `dispatch_s` are the
+        export, pack and dispatch spans' durations.
+
         `stall_chunks` guards the open-ended form against an UNSERVABLE
         demand: if no tenant ledger (acks, served reads) advances for that
         many consecutive chunks while demands remain, the loop raises naming
@@ -446,7 +454,9 @@ class ServeSession:
         stall = 0
         last_ledger = None
         pending = None  # chunk k-1's (records, delta rows), exported under k
-        self._dispatch(*router.pack(self.chunk))
+        with span("serve.pack"):
+            planes = router.pack(self.chunk)
+        self._dispatch(*planes)
         while True:
             # ---- host window: runs while the dispatched chunk computes ----
             # Everything until perf.end/finish_rounds below touches ONLY
@@ -456,10 +466,11 @@ class ServeSession:
             # window's writes (race_audit.overlap_write_sets) and gates any
             # carry touch as `race-window-mutation`; the donation-poison
             # sanitizer (--sanitize) re-proves it at runtime.
-            e0 = time.perf_counter()
+            export_s = pack_s = 0.0
             if pending is not None:
-                self._export(*pending)
-            e1 = time.perf_counter()
+                with span("serve.export", len(pending[1])) as sp:
+                    self._export(*pending)
+                export_s = sp.seconds
             if chunks is not None:
                 stop = self.chunks_done >= chunks
             else:
@@ -485,23 +496,30 @@ class ServeSession:
                             "(e.g. read-only tenants need elections that "
                             "append no-ops -- docs/SERVE.md)"
                         )
-            next_planes = None if stop else router.pack(self.chunk)
-            e2 = time.perf_counter()
+            next_planes = None
+            if not stop:
+                with span("serve.pack") as sp:
+                    next_planes = router.pack(self.chunk)
+                pack_s = sp.seconds
             # Enqueue this chunk's extraction rounds BEHIND it on the device
             # stream; fetched after the sync below, so the next dispatch's
             # donation never races a pending read of this chunk's state.
-            futs = self.deltas.begin_rounds(self.state, self._drain_rounds)
+            with span("serve.rounds"):
+                futs = self.deltas.begin_rounds(self.state, self._drain_rounds)
             if self.perf is not None:
                 self.perf.annotate(
-                    export_s=round(e1 - e0, 6), pack_s=round(e2 - e1, 6)
+                    export_s=round(export_s, 6), pack_s=round(pack_s, 6)
                 )
             # ---- sync: the only serial points are this wait + dispatch ----
-            self.metrics = merge_metrics(self.metrics, self._m_pending)
+            with span("serve.merge"):
+                self.metrics = merge_metrics(self.metrics, self._m_pending)
             if self.perf is not None:
                 self.perf.end(sync=lambda: np.asarray(self._m_pending.ticks))
-            pending = (self._recs_pending, self.deltas.finish_rounds(futs))
+            with span("serve.drain"):
+                pending = (self._recs_pending, self.deltas.finish_rounds(futs))
             if progress is not None:
-                progress(self.stats())
+                with span("serve.progress"):
+                    progress(self.stats())
             if stop:
                 self._export(*pending)
                 # Final flush: drain to dryness (the fixed overlapped rounds

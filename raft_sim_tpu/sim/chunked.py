@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from raft_sim_tpu.obs.timer import span
 from raft_sim_tpu.sim import scan
 from raft_sim_tpu.types import ClusterState
 from raft_sim_tpu.utils.config import RaftConfig
@@ -123,26 +124,35 @@ def run_chunked(
     otherwise overlap -- docs/OBSERVABILITY.md "Runtime perf") and the chunk
     program's jit cache is sampled as the recompile watchdog. None (the
     default) leaves the loop byte-identical to pre-perf behaviour.
+
+    Host spans (obs.span, always on, no sync): `fleet.init` (the zeroed
+    metrics) and `fleet.copy` (the up-front copy), then per chunk
+    `fleet.dispatch` (its tick count), `fleet.merge` and `fleet.callback`.
     """
     batch = state.role.shape[0]
-    metrics = scan.init_metrics_batch(batch)
+    with span("fleet.init"):
+        metrics = scan.init_metrics_batch(batch)
     done = 0
-    state = _own_copy(state)
+    with span("fleet.copy"):
+        state = _own_copy(state)
     if perf is not None:
         perf.add_probe("chunked._chunk_donate", _chunk_donate)
     while done < n_ticks:
         n = min(chunk, n_ticks - done)
+        with span("fleet.dispatch", n) as sp:
+            state, m = _chunk_donate(cfg, state, keys, n, genome, seg_len)
         if perf is not None:
-            perf.begin(n)
-        state, m = _chunk_donate(cfg, state, keys, n, genome, seg_len)
-        if perf is not None:
-            perf.dispatched()
-        metrics = merge_metrics(metrics, m)
+            perf.dispatch_span(sp)
+        with span("fleet.merge"):
+            metrics = merge_metrics(metrics, m)
         done += n
         # Callback host work (export, checkpointing) is part of the chunk's
         # host gap; the timer closes AFTER it, syncing on this chunk's own
         # metric leaf.
-        stop = callback is not None and callback(done, state, metrics)
+        stop = False
+        if callback is not None:
+            with span("fleet.callback"):
+                stop = callback(done, state, metrics)
         if perf is not None:
             perf.end(sync=lambda: np.asarray(m.ticks))
         if stop:

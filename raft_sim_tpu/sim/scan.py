@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from raft_sim_tpu.models import raft
+from raft_sim_tpu.obs.scopes import tick_scope
 from raft_sim_tpu.sim import faults
 from raft_sim_tpu.types import NIL, ClusterState, StepInfo
 from raft_sim_tpu.utils.config import RaftConfig
@@ -320,41 +321,43 @@ def tick_batch_minor(
 
     if step_fn is None:
         step_fn = raft_batched.step_b
-    if genome is None:
-        inp = jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now))(keys, s.now)
-    else:
-        # [B, S] genome rows vmap alongside the keys: cluster b's inputs come
-        # from ITS fault setting (sim/faults.py scenario path).
-        inp = jax.vmap(
-            lambda k, now, g: faults.make_inputs(
-                cfg, k, now, genome=g, seg_len=seg_len
+    with tick_scope("faults"):
+        if genome is None:
+            inp = jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now))(keys, s.now)
+        else:
+            # [B, S] genome rows vmap alongside the keys: cluster b's inputs come
+            # from ITS fault setting (sim/faults.py scenario path).
+            inp = jax.vmap(
+                lambda k, now, g: faults.make_inputs(
+                    cfg, k, now, genome=g, seg_len=seg_len
+                )
+            )(keys, s.now, genome)
+        if client_cmd is not None:
+            # Scalar (one offer broadcast fleet-wide: Session.offer) or [B]
+            # (per-cluster offer plane: the tenancy serve loop, where the batch
+            # axis IS the tenancy axis and each cluster gets its tenant's own
+            # command this tick).
+            inp = inp._replace(
+                client_cmd=jnp.broadcast_to(
+                    jnp.asarray(client_cmd, inp.client_cmd.dtype),
+                    inp.client_cmd.shape,
+                )
             )
-        )(keys, s.now, genome)
-    if client_cmd is not None:
-        # Scalar (one offer broadcast fleet-wide: Session.offer) or [B]
-        # (per-cluster offer plane: the tenancy serve loop, where the batch
-        # axis IS the tenancy axis and each cluster gets its tenant's own
-        # command this tick).
-        inp = inp._replace(
-            client_cmd=jnp.broadcast_to(
-                jnp.asarray(client_cmd, inp.client_cmd.dtype),
-                inp.client_cmd.shape,
+        if read_cmd is not None:
+            # External ReadIndex ingest (the read-only traffic class riding the
+            # serve path beside offered writes): overrides the scheduled
+            # read cadence for this tick, exactly like client_cmd above --
+            # scalar or per-cluster [B]. The config must carry the structural
+            # gate (cfg.read_index).
+            inp = inp._replace(
+                read_cmd=jnp.broadcast_to(
+                    jnp.asarray(read_cmd, inp.read_cmd.dtype), inp.read_cmd.shape
+                )
             )
-        )
-    if read_cmd is not None:
-        # External ReadIndex ingest (the read-only traffic class riding the
-        # serve path beside offered writes): overrides the scheduled
-        # read cadence for this tick, exactly like client_cmd above --
-        # scalar or per-cluster [B]. The config must carry the structural
-        # gate (cfg.read_index).
-        inp = inp._replace(
-            read_cmd=jnp.broadcast_to(
-                jnp.asarray(read_cmd, inp.read_cmd.dtype), inp.read_cmd.shape
-            )
-        )
-    inp_t = raft_batched.to_batch_minor(inp)
+        inp_t = raft_batched.to_batch_minor(inp)
     s2, info = step_fn(cfg, s, inp_t)
-    m2 = _accumulate(metrics, info, s.now)  # all fields [B]: elementwise
+    with tick_scope("check"):
+        m2 = _accumulate(metrics, info, s.now)  # all fields [B]: elementwise
     if not events:
         return (s2, m2, info)
     from raft_sim_tpu.trace import events as tev

@@ -46,6 +46,7 @@ import numpy as np
 from jax import lax
 
 from raft_sim_tpu.models import raft_batched
+from raft_sim_tpu.obs.timer import span
 from raft_sim_tpu.sim import scan
 from raft_sim_tpu.sim.chunked import _own_copy, merge_metrics
 from raft_sim_tpu.types import StepInfo
@@ -420,14 +421,21 @@ def run_chunked_telemetry(
     run under the donation-poison sanitizer (`tools/check.py --race
     --dynamic`), so a hook that retains the live carry past its return is a
     gated finding, not a latent chip-session bug.
+
+    Host spans as in `chunked.run_chunked`: `fleet.init`, `fleet.copy`,
+    then per chunk `fleet.dispatch` (its tick count), `fleet.merge` and
+    `fleet.callback` (the trace callback, the chunk hook and the callback
+    together).
     """
     batch = state.role.shape[0]
     ring_k = 0 if recorder is None else recorder.tick.shape[0]
     need_events = trace_spec is not None or trigger_kind is not None
     win_per_chunk = max(1, chunk // window)
-    metrics = scan.init_metrics_batch(batch)
+    with span("fleet.init"):
+        metrics = scan.init_metrics_batch(batch)
     done = 0
-    state = _own_copy(state)
+    with span("fleet.copy"):
+        state = _own_copy(state)
     if trace_spec is not None and trace_persist is None:
         from raft_sim_tpu.trace import ring as tring
 
@@ -442,34 +450,35 @@ def run_chunked_telemetry(
             w = window
         else:
             n = w = left  # remainder: one final short window
-        if perf is not None:
-            perf.begin(n)
-        if need_events:
-            out = _chunk_t_donate_trace(
-                cfg, state, keys, recorder, n, w, ring_k, genome, seg_len,
-                trace_spec, trace_persist, trigger_kind,
-            )
-            if trace_spec is not None:
-                state, m, recs, recorder, traws, trace_persist = out
+        with span("fleet.dispatch", n) as sp:
+            if need_events:
+                out = _chunk_t_donate_trace(
+                    cfg, state, keys, recorder, n, w, ring_k, genome, seg_len,
+                    trace_spec, trace_persist, trigger_kind,
+                )
+                if trace_spec is not None:
+                    state, m, recs, recorder, traws, trace_persist = out
+                else:
+                    state, m, recs, recorder = out
+                    traws = None
             else:
-                state, m, recs, recorder = out
+                state, m, recs, recorder = _chunk_t_donate(
+                    cfg, state, keys, recorder, n, w, ring_k, genome, seg_len
+                )
                 traws = None
-        else:
-            state, m, recs, recorder = _chunk_t_donate(
-                cfg, state, keys, recorder, n, w, ring_k, genome, seg_len
-            )
-            traws = None
         if perf is not None:
-            perf.dispatched()
-        metrics = merge_metrics(metrics, m)
+            perf.dispatch_span(sp)
+        with span("fleet.merge"):
+            metrics = merge_metrics(metrics, m)
         done += n
         # The callback's window export (sink append, apply-log update) is
         # this chunk's host gap; close after it, synced on the chunk metrics.
-        if traws is not None and trace_callback is not None:
-            trace_callback(done, traws)
-        if chunk_hook is not None:
-            chunk_hook(done, recorder)
-        stop = callback is not None and callback(done, state, metrics, recs)
+        with span("fleet.callback"):
+            if traws is not None and trace_callback is not None:
+                trace_callback(done, traws)
+            if chunk_hook is not None:
+                chunk_hook(done, recorder)
+            stop = callback is not None and callback(done, state, metrics, recs)
         if perf is not None:
             perf.end(sync=lambda: np.asarray(m.ticks))
         if stop:
