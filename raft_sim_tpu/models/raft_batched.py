@@ -142,6 +142,62 @@ def from_batch_minor(tree):
     return jax.tree.map(lambda x: jnp.moveaxis(x, -1, 0), tree)
 
 
+def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
+                    gsum, gmax, gmin):
+    """The tick's offer->commit latency accounting (raft.py phase 5's `track`
+    block, batch-minor): StepInfo's lat_sum, lat_cnt, lat_hist [BINS, B] and
+    lat_excluded, and the carried lat_frontier.
+
+    log_tick [N, CAP, B] offer stamps; lead [N, B] live leaders; commit [N, B];
+    base [N, B] log_base, or None without compaction; frontier, now [B];
+    ids2 [N, 1] global node ids of the rows (n is the no-node sentinel); the
+    g* folds reduce over the node axis across shards (identity unsharded,
+    as in _step_b).
+
+    One pass over the ring, equal to raft.py's per-slot form (which
+    tests/test_latency_accounting.py keeps as the reference): no per-slot
+    modulo, and the histogram comes from threshold counts, not from a
+    per-slot bin index."""
+    cap = log_tick.shape[1]
+    # A slot's ring offset from the base slot, without a per-slot modulo
+    # (base >= 0): its absolute index is abs0 + off + 1.
+    sl = log_ops.iota((1, cap, 1), 1)
+    if base is None:
+        off, abs0 = sl, 0
+    else:
+        off = sl - (base % cap)[:, None, :]
+        off = jnp.where(off < 0, off + cap, off)
+        abs0 = base
+    # Carried-frontier dedup, compared in offset space: abs1 > frontier <=>
+    # off > frontier - abs0 - 1. Stamps read from the offer-tick plane, never
+    # from values (raft.py).
+    lo = frontier[None, :] - abs0 - 1  # [N or 1, B]
+    newly = (off > lo[:, None, :]) & (off <= (commit - abs0 - 1)[:, None, :])
+    cli = (log_tick >= 1) & (log_tick <= now[None, None, :])
+    lm = lead[:, None, :] & newly & cli
+    lats = jnp.where(lm, (now + 1)[None, None, :] - log_tick, 0)  # [N, CAP, B]
+    lat_sum = gsum(jnp.sum(lats, axis=(0, 1)).astype(jnp.int32))
+    # Histogram by threshold counts: floor(log2(l)) >= k <=> l >= 2^k, and
+    # l >= 1 exactly on lm, so ge[k] counts the entries of bin k or above
+    # (ge[0] = lat_cnt) and the last bin keeps log2_bin's clamp.
+    ge = [
+        jnp.sum(lats >= (1 << k), axis=(0, 1)).astype(jnp.int32)  # [B]
+        for k in range(LAT_HIST_BINS)
+    ]
+    lat_cnt = gsum(ge[0])
+    lat_hist = gsum(jnp.stack([a - b for a, b in zip(ge, ge[1:])] + [ge[-1]]))
+    # Coverage gap counter: crossed-but-unattributed client entries, read on
+    # the lowest-id max-commit node (raft.py for the full rationale).
+    is_maxc = commit == gmax(jnp.max(commit, axis=0))[None, :]
+    hnode = gmin(jnp.min(jnp.where(is_maxc, ids2, n), axis=0))  # [B]
+    crossed = (ids2 == hnode[None, :])[:, None, :] & newly & cli
+    lat_excluded = jnp.maximum(
+        gsum(jnp.sum(crossed, axis=(0, 1)).astype(jnp.int32)) - lat_cnt, 0
+    )
+    lat_frontier = jnp.maximum(frontier, gmax(jnp.max(commit, axis=0)))
+    return lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier
+
+
 def step_b(
     cfg: RaftConfig, s: ClusterState, inp: StepInputs, sh: NodeShardCtx | None = None
 ) -> tuple[ClusterState, StepInfo]:
@@ -925,32 +981,10 @@ def _step_b(
 
     # ---- offer->commit latency (client workloads only; raft.py) ------------------
     if track:
-        sl = iota((1, cap, 1), 1)
-        if comp:
-            abs1 = base[:, None, :] + (sl - base[:, None, :]) % cap + 1
-        else:
-            abs1 = sl + 1
-        # Carried-frontier dedup; stamps read from the offer-tick plane, never
-        # from values (raft.py).
-        newly = (abs1 > s.lat_frontier[None, None, :]) & (abs1 <= commit[:, None, :])
-        cli = (log_tick_arr >= 1) & (log_tick_arr <= s.now[None, None, :])
-        lm = (is_leader & inp.alive)[:, None, :] & newly & cli
-        lats = jnp.where(lm, s.now[None, None, :] - log_tick_arr + 1, 0)  # [N, CAP, B]
-        lat_sum = gsum(jnp.sum(lats, axis=(0, 1)).astype(jnp.int32))
-        lat_cnt = gsum(jnp.sum(lm, axis=(0, 1)).astype(jnp.int32))
-        # Coverage gap counter: crossed-but-unattributed client entries, read
-        # on the lowest-id max-commit node (raft.py for the full rationale).
-        is_maxc = commit == gmax(jnp.max(commit, axis=0))[None, :]
-        hnode = gmin(jnp.min(jnp.where(is_maxc, ids2, n), axis=0))  # [B]
-        crossed = (ids2 == hnode[None, :])[:, None, :] & newly & cli
-        lat_excluded = jnp.maximum(
-            gsum(jnp.sum(crossed, axis=(0, 1)).astype(jnp.int32)) - lat_cnt, 0
+        lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier = offer_latency_b(
+            log_tick_arr, is_leader & inp.alive, commit, base if comp else None,
+            s.lat_frontier, s.now, ids2, n, gsum, gmax, gmin,
         )
-        # Histogram bin = floor(log2(l)) (log_ops.log2_bin; raft.py).
-        bin_ = log_ops.log2_bin(lats, LAT_HIST_BINS)
-        oh_b = (iota((1, 1, LAT_HIST_BINS, 1), 2) == bin_[:, :, None, :]) & lm[:, :, None, :]
-        lat_hist = gsum(jnp.sum(oh_b, axis=(0, 1)).astype(jnp.int32))  # [BINS, B]
-        lat_frontier = jnp.maximum(s.lat_frontier, gmax(jnp.max(commit, axis=0)))
     else:
         lat_sum = jnp.zeros_like(s.now)
         lat_cnt = jnp.zeros_like(s.now)

@@ -242,10 +242,12 @@ def ring_chk(log_term, log_val, base, uptos: tuple):
 def log2_bin(v: jax.Array, n_bins: int) -> jax.Array:
     """Elementwise floor(log2(v)) clamped to [0, n_bins): the latency
     histogram's bin index (types.LAT_HIST_BINS semantics), via an unrolled
-    binary bit-length reduction -- no float log in any hot loop. The ONE
-    copy both kernels' commit-latency AND read-latency histograms bin with
-    (four call sites; a binning change is one edit). v must be >= 0; v in
-    {0, 1} lands in bin 0."""
+    binary bit-length reduction -- no float log in any hot loop. Three call
+    sites: raft.py's commit-latency and read-latency histograms and
+    raft_batched.py's read-latency histogram. raft_batched.offer_latency_b
+    counts the same bins by thresholds (v >= 2^k) without calling it, held
+    equal by tests/test_latency_accounting.py: a binning change edits both.
+    v must be >= 0; v in {0, 1} lands in bin 0."""
     bl = jnp.zeros_like(v)
     for sft in (16, 8, 4, 2, 1):
         m_ = v >= (1 << sft)
