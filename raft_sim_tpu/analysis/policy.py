@@ -358,7 +358,7 @@ def invariant_leaves(cfg: RaftConfig) -> set[str]:
     if not cfg.reconfig:
         inv |= {
             "member_old", "member_new", "cfg_epoch", "cfg_pend",
-            "log_cfg", "mb.ent_cfg",
+            "log_cfg", "mb.ent_cfg", "metric.joint_ticks",
         }
     if not (cfg.reconfig and cfg.compaction):
         # The snapshot config context travels (and advances) only when both
@@ -372,7 +372,7 @@ def invariant_leaves(cfg: RaftConfig) -> set[str]:
         # gate exists to read it (transfer x [reconfig | lease]).
         inv |= {"mb.req_disrupt"}
     if not cfg.leader_transfer:
-        inv |= {"xfer_to", "mb.xfer_tgt"}
+        inv |= {"xfer_to", "mb.xfer_tgt", "metric.xfer_fired"}
     if not cfg.read_index:
         # The read slot AND its RunMetrics accumulators: scan._add_gated
         # skips the fold when the kernel emits host-constant zeros, so the
@@ -383,8 +383,9 @@ def invariant_leaves(cfg: RaftConfig) -> set[str]:
         }
     if not cfg.read_lease:
         # The lease staleness anchor is dead weight on plain ReadIndex
-        # configs too -- only the lease gate maintains it.
-        inv |= {"read_fr"}
+        # configs too -- only the lease gate maintains it (and the
+        # lease-read counter with it).
+        inv |= {"read_fr", "metric.lease_reads"}
     if not cfg.durable_storage:
         # Durable storage plane off (raft_sim_tpu/storage): the watermark
         # triple AND its RunMetrics lag accumulators are dead weight --

@@ -843,7 +843,10 @@ def _step(cfg: RaftConfig, s: ClusterState, inp: StepInputs) -> tuple[ClusterSta
                 # bound leans on, so only reads served BEFORE the handoff
                 # may lean on it (docs/PROTOCOL.md staleness argument).
                 lease_ok = lease_ok & ~xfer_pend
-            serve = serve | (keep_r & inp.alive & lease_ok)
+            # Reads the lease serves that no confirmed round did this tick.
+            by_lease = keep_r & inp.alive & lease_ok & ~serve
+            lease_reads = jnp.sum(by_lease).astype(jnp.int32)
+            serve = serve | by_lease
         lat_r = jnp.maximum(s.now + 1 - s.read_tick, 1)  # [N]
         reads_served = jnp.sum(serve).astype(jnp.int32)
         read_lat_sum = jnp.sum(jnp.where(serve, lat_r, 0)).astype(jnp.int32)
@@ -891,6 +894,7 @@ def _step(cfg: RaftConfig, s: ClusterState, inp: StepInputs) -> tuple[ClusterSta
                 viol_read_stale = np.zeros((), np.bool_)
         else:
             viol_read_stale = np.zeros((), np.bool_)
+            lease_reads = np.int32(0)
     else:
         # Constants, not jnp.zeros: a zeros op would land in the lowered
         # step program and break the zero-cost-when-off golden (byte-
@@ -899,6 +903,7 @@ def _step(cfg: RaftConfig, s: ClusterState, inp: StepInputs) -> tuple[ClusterSta
         read_lat_sum = np.int32(0)
         read_hist = np.zeros((LAT_HIST_BINS,), np.int32)
         viol_read_stale = np.zeros((), np.bool_)
+        lease_reads = np.int32(0)
 
     # ---- offer->commit latency (client workloads only) ---------------------------
     # Each client entry's offer stamp rides the log_tick plane (phase 6 writes
@@ -1558,7 +1563,7 @@ def _step(cfg: RaftConfig, s: ClusterState, inp: StepInputs) -> tuple[ClusterSta
         cfg, s, new_state, req_in, resp_in, inp.alive, cmds_cnt, chk_ok,
         lat_sum, lat_cnt, lat_hist, lat_excluded, noop_blocked,
         reads_served, read_lat_sum, read_hist, viol_read_stale,
-        fsync_lag_sum, fsync_lag_max,
+        fsync_lag_sum, fsync_lag_max, lease_reads,
     )
     return new_state, info
 
@@ -1583,6 +1588,7 @@ def _step_info(
     viol_read_stale: jax.Array,
     fsync_lag_sum: jax.Array,
     fsync_lag_max: jax.Array,
+    lease_reads: jax.Array,
 ) -> StepInfo:
     """Phase 9: on-device safety invariants + observability reductions (per cluster)."""
     n = cfg.n_nodes
@@ -1689,6 +1695,17 @@ def _step_info(
         viol_match, lm_skipped = f, jnp.int32(0)
 
     leader = jnp.min(jnp.where(live_leader, jnp.arange(n, dtype=jnp.int32), n))
+    # Plane counters, host zeros while their gate is off (the read metrics'
+    # zero-cost contract): a node in a joint configuration at end of tick,
+    # TimeoutNow requests sent this tick.
+    if cfg.reconfig:
+        joint_ticks = jnp.any(new.cfg_pend > 0).astype(jnp.int32)
+    else:
+        joint_ticks = np.int32(0)
+    if cfg.leader_transfer:
+        xfer_fired = jnp.sum(new.mailbox.xfer_tgt != NIL).astype(jnp.int32)
+    else:
+        xfer_fired = np.int32(0)
     return StepInfo(
         viol_election_safety=viol_election,
         viol_commit=viol_commit,
@@ -1715,4 +1732,7 @@ def _step_info(
         viol_read_stale=viol_read_stale,
         fsync_lag_sum=fsync_lag_sum,
         fsync_lag_max=fsync_lag_max,
+        joint_ticks=joint_ticks,
+        xfer_fired=xfer_fired,
+        lease_reads=lease_reads,
     )

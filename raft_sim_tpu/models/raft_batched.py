@@ -929,7 +929,9 @@ def _step_b(
             if xfr:
                 # Transfer handoff covers the read path (raft.py phase 5).
                 lease_ok = lease_ok & ~xfer_pend
-            serve = serve | (keep_r & inp.alive & lease_ok)
+            by_lease = keep_r & inp.alive & lease_ok & ~serve
+            lease_reads = jnp.sum(by_lease, axis=0).astype(jnp.int32)
+            serve = serve | by_lease
         lat_r = jnp.maximum(s.now[None, :] + 1 - s.read_tick, 1)  # [N, B]
         reads_served = jnp.sum(serve, axis=0).astype(jnp.int32)
         read_lat_sum = jnp.sum(jnp.where(serve, lat_r, 0), axis=0).astype(jnp.int32)
@@ -971,6 +973,7 @@ def _step_b(
                 viol_read_stale = np.zeros((b,), np.bool_)
         else:
             viol_read_stale = np.zeros((b,), np.bool_)
+            lease_reads = np.zeros((b,), np.int32)
     else:
         # Constants, not jnp.zeros: keep the disabled-mode lowered program
         # byte-identical (see raft.py).
@@ -978,6 +981,7 @@ def _step_b(
         read_lat_sum = np.zeros((b,), np.int32)
         read_hist = np.zeros((LAT_HIST_BINS, b), np.int32)
         viol_read_stale = np.zeros((b,), np.bool_)
+        lease_reads = np.zeros((b,), np.int32)
 
     # ---- offer->commit latency (client workloads only; raft.py) ------------------
     if track:
@@ -1003,10 +1007,12 @@ def _step_b(
             # Fold the compacted span's config entries into the snapshot
             # context (cfglog.fold_span; anchored at the PRE-advance base,
             # same aliasing rule as the checksum pass -- raft.py phase 5.5).
+            phase("membership")
             bmold, bpend, bepoch = cfglog.fold_span(
                 cfg, log_cfg_arr, base, base2, bmold, bpend, bepoch,
                 batched=True,
             )
+            phase("compact")
         base = base2
 
     # ---- committed-prefix checksum, compaction form (raft.py: anchored at
@@ -1444,6 +1450,7 @@ def _step_b(
 
     # ---- end-of-tick config derivation (log-carried membership; raft.py) ---------
     if rcf:
+        phase("membership")
         d_mold, d_mnew, d_pend, d_epoch, d_hi = cfglog.derive(
             cfg, log_cfg_arr, log_len, commit, base, bmold, bpend, bepoch,
             batched=True,
@@ -1523,7 +1530,7 @@ def _step_b(
         cfg, s, new_state, req_in, resp_in, inp.alive, cmds_cnt, chk_ok,
         lat_sum, lat_cnt, lat_hist, lat_excluded, noop_blocked,
         reads_served, read_lat_sum, read_hist, viol_read_stale,
-        fsync_lag_sum, fsync_lag_max, sh,
+        fsync_lag_sum, fsync_lag_max, lease_reads, sh,
     )
     phase.close()
     return new_state, info
@@ -1549,6 +1556,7 @@ def _step_info_b(
     viol_read_stale: jax.Array,
     fsync_lag_sum: jax.Array,
     fsync_lag_max: jax.Array,
+    lease_reads: jax.Array,
     sh: NodeShardCtx | None = None,
 ) -> StepInfo:
     """Batched phase 9; see raft._step_info. All outputs [B]."""
@@ -1677,6 +1685,17 @@ def _step_info_b(
         viol_match, lm_skipped = f, jnp.zeros_like(new.now)
 
     leader = gmin(jnp.min(jnp.where(live_leader, ids1, n), axis=0))  # [B]
+    # Plane counters (raft._step_info): host zeros while their gate is off.
+    if cfg.reconfig:
+        joint_ticks = gany(jnp.any(new.cfg_pend > 0, axis=0)).astype(jnp.int32)
+    else:
+        joint_ticks = np.zeros((b,), np.int32)
+    if cfg.leader_transfer:
+        xfer_fired = gsum(
+            jnp.sum(new.mailbox.xfer_tgt != NIL, axis=0).astype(jnp.int32)
+        )
+    else:
+        xfer_fired = np.zeros((b,), np.int32)
     if sh is None:
         min_commit = jnp.min(new.commit_index, axis=0)
     else:
@@ -1714,4 +1733,7 @@ def _step_info_b(
         viol_read_stale=viol_read_stale,
         fsync_lag_sum=fsync_lag_sum,
         fsync_lag_max=fsync_lag_max,
+        joint_ticks=joint_ticks,
+        xfer_fired=xfer_fired,
+        lease_reads=lease_reads,
     )

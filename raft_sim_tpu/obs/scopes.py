@@ -18,7 +18,8 @@ readers all import them.
 | `tick.commit` | phases 5 and 5.2 (quorum commit, reconfiguration), offer->commit latency |
 | `tick.compact` | phase 5.5 (compaction) and the committed-prefix checksum |
 | `tick.client` | phase 6 (client injection, redirect, election no-op), config origination |
-| `tick.outbox` | phases 7.5 (fsync gates) and 8 (outbox), end-of-tick config derivation |
+| `tick.outbox` | phases 7.5 (fsync gates) and 8 (outbox) |
+| `tick.membership` | the config derivation from each node's log and the removed-server stepdown (end of tick), the compaction fold of config entries (phase 5.5); reconfiguration only |
 | `tick.check` | the invariants and StepInfo (`_step_info_b`), metric accumulation |
 """
 
@@ -26,7 +27,7 @@ from __future__ import annotations
 
 TICK_PHASES = (
     "faults", "deliver", "elect", "replicate", "commit", "compact", "client",
-    "outbox", "check",
+    "outbox", "membership", "check",
 )  # each runs under the named scope `tick.<phase>`
 
 

@@ -262,6 +262,13 @@ class FleetSummary(NamedTuple):
     fsync_lag_max: int
     fsync_lag_p50: float | None
     fsync_lag_p95: float | None
+    # Plane counters (RunMetrics.joint_ticks/xfer_fired/lease_reads; zeros
+    # unless cfg.reconfig / leader_transfer / read_lease): fleet-total
+    # cluster-ticks with a node in a joint configuration, TimeoutNow requests
+    # sent, and reads served by the lease alone (part of reads_served).
+    joint_ticks: int
+    xfer_fired: int
+    lease_reads: int
 
 
 def gather_metrics(metrics):
@@ -363,6 +370,9 @@ def summarize(metrics) -> FleetSummary:
         noop_blocked=int(np.sum(m.noop_blocked, dtype=np.int64)),
         lm_skipped_pairs=int(np.sum(m.lm_skipped_pairs, dtype=np.int64)),
         multi_leader=int(np.sum(m.multi_leader, dtype=np.int64)),
+        joint_ticks=int(np.sum(m.joint_ticks, dtype=np.int64)),
+        xfer_fired=int(np.sum(m.xfer_fired, dtype=np.int64)),
+        lease_reads=int(np.sum(m.lease_reads, dtype=np.int64)),
         **_fsync_lag_rollup(m),
         **_latency_rollup(m),
     )

@@ -48,9 +48,19 @@ def merge_metrics(a: scan.RunMetrics, b: scan.RunMetrics) -> scan.RunMetrics:
         read_hist=a.read_hist + b.read_hist,
         fsync_lag_sum=a.fsync_lag_sum + b.fsync_lag_sum,
         fsync_lag_max=jnp.maximum(a.fsync_lag_max, b.fsync_lag_max),
+        joint_ticks=a.joint_ticks + b.joint_ticks,
+        xfer_fired=a.xfer_fired + b.xfer_fired,
+        lease_reads=a.lease_reads + b.lease_reads,
         multi_leader=a.multi_leader + b.multi_leader,
         ticks=a.ticks + b.ticks,
     )
+
+
+# The loops' per-call zeroed metrics and per-chunk merge, each one compiled
+# program: run eagerly they dispatch one op per metric leg (and the vmap
+# re-traces every call), host time the device waits through between chunks.
+_zero_metrics = jax.jit(scan.init_metrics_batch, static_argnums=0)
+_merge = jax.jit(merge_metrics)
 
 
 @jax.jit
@@ -131,7 +141,7 @@ def run_chunked(
     """
     batch = state.role.shape[0]
     with span("fleet.init"):
-        metrics = scan.init_metrics_batch(batch)
+        metrics = _zero_metrics(batch)
     done = 0
     with span("fleet.copy"):
         state = _own_copy(state)
@@ -144,7 +154,7 @@ def run_chunked(
         if perf is not None:
             perf.dispatch_span(sp)
         with span("fleet.merge"):
-            metrics = merge_metrics(metrics, m)
+            metrics = _merge(metrics, m)
         done += n
         # Callback host work (export, checkpointing) is part of the chunk's
         # host gap; the timer closes AFTER it, syncing on this chunk's own

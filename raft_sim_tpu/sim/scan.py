@@ -83,6 +83,13 @@ class RunMetrics(NamedTuple):
     # percentiles of those means and the max-of-max.
     fsync_lag_sum: jax.Array  # int32
     fsync_lag_max: jax.Array  # int32
+    # Plane counters (StepInfo.joint_ticks/xfer_fired/lease_reads; each zero
+    # and loop-invariant unless its gate -- cfg.reconfig, leader_transfer,
+    # read_lease -- is on): ticks with any node in a joint configuration,
+    # TimeoutNow requests sent, and reads served by the lease alone.
+    joint_ticks: jax.Array  # int32
+    xfer_fired: jax.Array  # int32
+    lease_reads: jax.Array  # int32
     # Split-brain exposure: ticks with >= 2 concurrent LEADER roles
     # (StepInfo.n_leaders). LEGAL under partitions (a deposed leader has not
     # heard the news yet) -- only SAME-term double leadership violates
@@ -124,6 +131,9 @@ def init_metrics() -> RunMetrics:
         read_hist=jnp.zeros((LAT_HIST_BINS,), jnp.int32),
         fsync_lag_sum=z,
         fsync_lag_max=z,
+        joint_ticks=z,
+        xfer_fired=z,
+        lease_reads=z,
         multi_leader=z,
         ticks=z,
     )
@@ -193,6 +203,9 @@ def _accumulate(m: RunMetrics, info: StepInfo, tick: jax.Array) -> RunMetrics:
         read_hist=_add_gated(m.read_hist, info.read_hist),
         fsync_lag_sum=_add_gated(m.fsync_lag_sum, info.fsync_lag_sum),
         fsync_lag_max=_max_gated(m.fsync_lag_max, info.fsync_lag_max),
+        joint_ticks=_add_gated(m.joint_ticks, info.joint_ticks),
+        xfer_fired=_add_gated(m.xfer_fired, info.xfer_fired),
+        lease_reads=_add_gated(m.lease_reads, info.lease_reads),
         multi_leader=m.multi_leader + (info.n_leaders >= 2),
         ticks=m.ticks + 1,
     )

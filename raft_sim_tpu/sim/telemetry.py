@@ -48,7 +48,7 @@ from jax import lax
 from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.obs.timer import span
 from raft_sim_tpu.sim import scan
-from raft_sim_tpu.sim.chunked import _own_copy, merge_metrics
+from raft_sim_tpu.sim.chunked import _merge, _own_copy, _zero_metrics, merge_metrics
 from raft_sim_tpu.types import StepInfo
 from raft_sim_tpu.utils.config import RaftConfig
 
@@ -111,6 +111,9 @@ def init_recorder(cfg: RaftConfig, k: int, batch: int) -> FlightRecorder:
         viol_read_stale=leaf(bool),
         fsync_lag_sum=leaf(jnp.int32),
         fsync_lag_max=leaf(jnp.int32),
+        joint_ticks=leaf(jnp.int32),
+        xfer_fired=leaf(jnp.int32),
+        lease_reads=leaf(jnp.int32),
     )
     return FlightRecorder(
         ring=ring,
@@ -432,7 +435,7 @@ def run_chunked_telemetry(
     need_events = trace_spec is not None or trigger_kind is not None
     win_per_chunk = max(1, chunk // window)
     with span("fleet.init"):
-        metrics = scan.init_metrics_batch(batch)
+        metrics = _zero_metrics(batch)
     done = 0
     with span("fleet.copy"):
         state = _own_copy(state)
@@ -469,7 +472,7 @@ def run_chunked_telemetry(
         if perf is not None:
             perf.dispatch_span(sp)
         with span("fleet.merge"):
-            metrics = merge_metrics(metrics, m)
+            metrics = _merge(metrics, m)
         done += n
         # The callback's window export (sink append, apply-log update) is
         # this chunk's host gap; close after it, synced on the chunk metrics.

@@ -584,6 +584,15 @@ class StepInfo(NamedTuple):
     # storage plane stay valid.
     fsync_lag_sum: jax.Array = 0  # int32: sum over nodes of log_len - dur_len
     fsync_lag_max: jax.Array = 0  # int32: max over nodes of log_len - dur_len
+    # Plane counters, each a host-constant zero while its gate is off (folds
+    # gated like the read metrics): whether any node ends the tick in a joint
+    # configuration (cfg.reconfig), the TimeoutNow requests sent this tick
+    # (cfg.leader_transfer), and the reads the lease served this tick that no
+    # confirmed ReadIndex round did (cfg.read_lease; a subset of
+    # reads_served). Defaulted like the storage counters.
+    joint_ticks: jax.Array = 0  # int32: 1 when any node's cfg_pend > 0
+    xfer_fired: jax.Array = 0  # int32: TimeoutNow requests sent
+    lease_reads: jax.Array = 0  # int32: reads served by the lease alone
 
 
 def empty_mailbox(cfg: RaftConfig) -> Mailbox:

@@ -116,7 +116,11 @@ from raft_sim_tpu.utils.config import RaftConfig
 #      fsync lag accumulators (fsync_lag_sum/fsync_lag_max, telemetry
 #      schema v4). All new leaves are loop-invariant unless
 #      cfg.durable_storage (fsync_interval > 0). Mailbox unchanged.
-_FORMAT_VERSION = 25
+# v26: plane counters -- RunMetrics gained joint_ticks, xfer_fired and
+#      lease_reads (int32 per cluster), each zero and loop-invariant unless
+#      its gate (cfg.reconfig, leader_transfer, read_lease) is on.
+#      ClusterState and Mailbox unchanged.
+_FORMAT_VERSION = 26
 
 # The single exported source of truth for the on-disk format version
 # (re-exported as raft_sim_tpu.CHECKPOINT_FORMAT_VERSION). Everything that
@@ -132,7 +136,7 @@ FORMAT_VERSION = _FORMAT_VERSION
 # refreshing this pin -- the convention the v2..v19 log always relied on,
 # now machine-checked. Refresh with:
 #     python -c "from raft_sim_tpu.analysis import policy; print(policy.schema_fingerprint())"
-_SCHEMA_FINGERPRINT = (25, "541dcec1cfa9709e")
+_SCHEMA_FINGERPRINT = (26, "e696d29533a07475")
 
 
 def _normalize(path: str) -> str:

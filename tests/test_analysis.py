@@ -172,8 +172,9 @@ def test_types_comments_parse_and_hold():
     assert len(specs["Mailbox"]) == 28  # v22: +xfer_tgt
     # v25: +fsync_fire/torn_drop (disk-fault lattice draws)
     assert len(specs["StepInputs"]) == 13  # v22: +reconfig/transfer/read cmds
-    # v25: +fsync_lag_sum/fsync_lag_max (durability-lag SLI counters)
-    assert len(specs["StepInfo"]) == 22  # v23: +viol_read_stale
+    # v25: +fsync_lag_sum/fsync_lag_max (durability-lag SLI counters);
+    # v26: +joint_ticks/xfer_fired/lease_reads (plane counters)
+    assert len(specs["StepInfo"]) == 25  # v23: +viol_read_stale
     assert ast_lint.check_dtype_comments() == []
 
 

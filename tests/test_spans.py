@@ -2,7 +2,7 @@
 
 - Every `tick.<phase>` named scope reaches the op metadata of the compiled
   chunk program, at a config that turns on compaction, client redirect,
-  ReadIndex and PreVote (so no phase is compiled away).
+  ReadIndex, PreVote and reconfiguration (so no phase is compiled away).
 - The span ring is bounded, and the loops record their host steps in loop
   order: `run_chunked` (and the telemetry soak) `fleet.init`, `fleet.copy`, then
   dispatch/merge/callback per chunk; `ServeSession.serve` its serve steps.
@@ -18,6 +18,7 @@ on, so every parity test runs with them), and scopes adding no jaxpr
 equation is tests/test_golden_jaxpr.py's.
 """
 
+import dataclasses
 import glob
 
 import jax
@@ -33,6 +34,8 @@ CFG = RaftConfig(
     n_nodes=3, log_capacity=16, compact_margin=4, client_interval=2,
     client_redirect=True, read_interval=2, pre_vote=True, drop_prob=0.05,
 )
+# The membership phase exists only with the reconfiguration plane on.
+SCOPED = dataclasses.replace(CFG, reconfig_interval=5)
 BATCH, CHUNK = 4, 8
 
 
@@ -44,9 +47,10 @@ def _setup(cfg=CFG, batch=BATCH, seed=0):
 @pytest.fixture(scope="module")
 def chunk_hlo():
     """The optimized HLO text of the donating chunk program, compiled on
-    the CPU at the test config."""
-    state, keys = _setup()
-    return chunked._chunk_donate.lower(CFG, state, keys, CHUNK, None, 1).compile().as_text()
+    the CPU at the scoped test config."""
+    state, keys = _setup(SCOPED)
+    return chunked._chunk_donate.lower(
+        SCOPED, state, keys, CHUNK, None, 1).compile().as_text()
 
 
 @pytest.mark.parametrize("phase", TICK_PHASES)
