@@ -232,10 +232,12 @@ def ring_chk(log_term, log_val, base, uptos: tuple):
 #
 # No gather/scatter anywhere: with the batch minor, dynamic indices would vary along
 # the lane dimension, which TPU gathers serialize (measured ~5x slower than the whole
-# rest of the tick). CAP/E are small static dims, so every indexed access is instead a
+# rest of the tick). CAP/E are small static dims, so every indexed read is instead a
 # one-hot compare-and-reduce over the indexed axis -- pure elementwise work that
-# vectorizes across lanes. Equality with the gather forms is pinned by
-# tests/test_batched_parity.py.
+# vectorizes across lanes -- and every window write is a select on each slot's window
+# offset (_at_offset). Equality with the gather forms is pinned by
+# tests/test_batched_parity.py; tests/test_window_write.py holds the writes to the
+# one-hot forms they replaced.
 # --------------------------------------------------------------------------------------
 
 
@@ -264,7 +266,22 @@ def iota(shape, d):
     return jax.lax.broadcasted_iota(jnp.int32, shape, d)
 
 
-
+def _at_offset(vals: jax.Array, rel: jax.Array) -> jax.Array:
+    """out[n, s, b] = vals[n, rel[n, s, b], b] for rel in [0, E), elementwise: a
+    select tree on rel's low bits (E - 1 selects), with no reduction over E and
+    no [N, E, CAP, B] one-hot, so the window writes fuse into their consumers
+    instead of materializing a ring-sized plane. Where rel >= E the result is
+    one of vals' entries; the write forms mask those slots off.
+    vals: [N, E, B]; rel: [N, CAP, B] -> [N, CAP, B]."""
+    level = [vals[:, k : k + 1] for k in range(vals.shape[1])]
+    bit = 1
+    while len(level) > 1:
+        if len(level) % 2:
+            level.append(level[-1])  # reached only by rel >= E
+        odd = (rel & bit) != 0
+        level = [jnp.where(odd, v1, v0) for v0, v1 in zip(level[::2], level[1::2])]
+        bit <<= 1
+    return jnp.broadcast_to(level[0], rel.shape)
 
 
 def term_at_b(log_term: jax.Array, index1: jax.Array) -> jax.Array:
@@ -317,23 +334,16 @@ def write_window_b(
 
     Taking (gate, count) instead of a free-form [N, E, B] mask makes the old
     implicit precondition (mask must be a contiguous prefix along E) structural:
-    the written-slot test below is two compares against [start0, start0 + count)
-    instead of an E-way any-reduce, and no caller can pass a mask shape it would
-    silently mis-handle. Window positions are strictly increasing in k, so each
-    capacity slot is hit by at most one written entry; out-of-range entries are
-    routed to position `cap`, which matches no slot (the scatter form's
+    the written-slot test is two compares of the slot's window offset against
+    [0, count), and no caller can pass a mask shape it would silently
+    mis-handle. Offsets past the last slot match no slot (the scatter form's
     mode='drop')."""
     cap = arr.shape[1]
     e = vals.shape[1]
     count = jnp.minimum(jnp.where(gate, count, 0), e).astype(jnp.int32)  # [N, B]
-    mask = iota((1, e, 1), 1) < count[:, None, :]  # [N, E, B]; count is 0 where ~gate
-    pos = start0[:, None, :] + iota((1, e, 1), 1)  # [N, E, B]
-    pos = jnp.where(mask, pos, cap)
-    oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
-    cs = iota((1, cap, 1), 1)
-    hit = (cs >= start0[:, None, :]) & (cs < (start0 + count)[:, None, :])
-    val = jnp.sum(jnp.where(oh, vals[:, :, None, :], 0), axis=1)
-    return jnp.where(hit, val, arr)
+    rel = iota((1, cap, 1), 1) - start0[:, None, :]  # [N, CAP, B] window offset
+    hit = (rel >= 0) & (rel < count[:, None, :])
+    return jnp.where(hit, _at_offset(vals, rel), arr)
 
 
 # ---- batch-minor ring forms (compaction; see the ring section above) -----------------
@@ -361,19 +371,19 @@ def write_window_rb(arr, start0, vals, gate, lo, count):
     The extra `lo` bound (vs write_window_b) is the compaction skip: shipped
     entries at or below the receiver's log_base are already committed and
     compacted, so the write starts partway into the window. Written positions are
-    distinct mod CAP (retained window <= CAP)."""
+    distinct mod CAP (retained window <= CAP).
+
+    The start is reduced mod CAP once per [N, B]; a slot's window offset is
+    then its distance from that start plus CAP where negative -- no per-slot
+    modulo."""
     cap = arr.shape[1]
     e = vals.shape[1]
     count = jnp.minimum(jnp.where(gate, count, 0), e).astype(jnp.int32)  # [N, B]
     lo = jnp.clip(lo, 0, e).astype(jnp.int32)
-    ks = iota((1, e, 1), 1)
-    mask = (ks >= lo[:, None, :]) & (ks < count[:, None, :])  # [N, E, B]
-    pos = jnp.where(mask, (start0[:, None, :] + ks) % cap, cap)
-    oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
-    rel = (iota((1, cap, 1), 1) - start0[:, None, :]) % cap  # slot's window offset
+    rel = iota((1, cap, 1), 1) - (start0 % cap)[:, None, :]  # [N, CAP, B]
+    rel = jnp.where(rel < 0, rel + cap, rel)  # slot's window offset, in [0, CAP)
     hit = (rel >= lo[:, None, :]) & (rel < count[:, None, :])
-    val = jnp.sum(jnp.where(oh, vals[:, :, None, :], 0), axis=1)
-    return jnp.where(hit, val, arr)
+    return jnp.where(hit, _at_offset(vals, rel), arr)
 
 
 def ring_chk_b(log_term, log_val, base, uptos: tuple):

@@ -13,17 +13,19 @@ one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from raft_sim_tpu import PRESETS, init_batch
+from raft_sim_tpu import PRESETS, RaftConfig, init_batch
 from raft_sim_tpu.parallel import nodeshard
 from raft_sim_tpu.serve import loop
-from raft_sim_tpu.sim import scan
+from raft_sim_tpu.sim import chunked, scan
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V5E_HBM_BYTES = 16 * 1024**3
 TICKS = 256
 
@@ -117,3 +119,92 @@ def test_node_sharded_config7_compiles_for_four_v5e(topo):
     ).compile()
     assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
     assert "all-gather" in compiled.as_text()
+
+
+# ------------------------------------------- the fleet tick loop's ring planes
+
+_COMP = re.compile(r"^(?:ENTRY\s+)?(%[^\s(]+)\s*\(")
+_INSTR = re.compile(r"^\s+(?:ROOT\s+)?(%\S+)\s*=\s*(.*?)\s([a-z][a-z0-9\-]*)\(")
+_SHAPE = re.compile(r"\b[a-z]+[0-9]*\[([0-9,]*)\]")
+_CONTROL = ("while", "conditional", "call")
+
+
+def _shapes(hlo_type: str) -> list:
+    return [tuple(int(d) for d in g.split(",") if d) for g in _SHAPE.findall(hlo_type)]
+
+
+def _tick_loop(text: str):
+    """The optimized HLO's tick loop: every instruction's output type by name,
+    and (name, kind, output type, operand names) for each instruction of the
+    entry's widest-carry `while` body and of the control flow it calls. Fused
+    computations are not entered: a fusion is one kernel, and its own operands
+    and output are what cross HBM."""
+    comps, types, cur, entry = {}, {}, None, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            m = _COMP.match(line)
+            cur = m.group(1) if m else None
+            if cur is not None:
+                comps[cur] = []
+                if line.startswith("ENTRY"):
+                    entry = cur
+        elif cur is not None and (m := _INSTR.match(line)):
+            comps[cur].append((m, line))
+            types[m.group(1)] = m.group(2)
+    loops = [
+        (m.group(2).count("["), re.search(r"body=(%[\w.\-]+)", line).group(1))
+        for m, line in comps[entry]
+        if m.group(3) == "while"
+    ]
+    body, seen, todo = [], set(), [max(loops)[1]]
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for m, line in comps[c]:
+            i, depth = m.end(), 1
+            while depth and i < len(line):
+                depth += {"(": 1, ")": -1}.get(line[i], 0)
+                i += 1
+            body.append((m.group(1), m.group(3), m.group(2),
+                         re.findall(r"%[\w.\-]+", line[m.end():i - 1])))
+            if m.group(3) in _CONTROL:
+                todo.extend(re.findall(
+                    r"(?:body|condition|to_apply|calls|true_computation|"
+                    r"false_computation)=(%[\w.\-]+)", line))
+                todo.extend(re.findall(r"%[\w.\-]+", "".join(
+                    re.findall(r"branch_computations=\{([^}]*)\}", line))))
+    return types, body
+
+
+def test_fleet_tick_writes_no_ring_plane_from_window_operands(one_chip):
+    """fleet-etcd3-b1000's chunk program (B 1,000, N 3, a 4,095-slot ring; 8
+    ticks keep the loop body of the cell's 256): no instruction of the tick
+    loop outputs an [N, CAP, B] ring plane when none of its operands holds
+    ring slots ([..., CAP, B]). Such an output is a plane built from window-
+    sized operands alone, stored to HBM and read back by the select that
+    writes it into the ring: what the one-hot AppendEntries write made, once
+    for each written plane (term, value, offer stamp)."""
+    from benchmark.cell import Cell
+
+    cell = Cell(ROOT, "fleet-etcd3-b1000")
+    cfg = RaftConfig(**cell.raft_fields)
+    batch = cell.traffic["batch"]
+    state = jax.eval_shape(lambda k: init_batch(cfg, k, batch), jax.random.key(0))
+    keys = jax.eval_shape(lambda k: jax.random.split(k, batch), jax.random.key(0))
+    compiled = chunked._chunk_donate.lower(
+        cfg, _placed(state, one_chip), _placed(keys, one_chip), 8, None, 1
+    ).compile()
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    types, body = _tick_loop(compiled.as_text())
+    assert any(ring in _shapes(out) for _, _, out, _ in body)  # the ring is there
+    built = [
+        name
+        for name, kind, out, operands in body
+        if kind != "parameter"
+        and ring in _shapes(out)
+        and not any(s[-2:] == ring[-2:] for op in operands
+                    for s in _shapes(types.get(op, "")))
+    ]
+    assert not built, f"ring planes built from window-sized operands: {built}"
