@@ -6,7 +6,7 @@ Source rules (pure `ast`, no execution):
 
   traced-branch    no Python `if`/`while` on traced values in `models/` and
                    `sim/`. The kernels are `jnp.where` lattices by design
-                   (models/raft.py docstring); a Python branch on a tracer
+                   (models/raft_batched.py docstring); a Python branch on a tracer
                    either crashes under jit or -- worse -- silently bakes one
                    trace-time path. Taint heuristic: parameters annotated with
                    traced types (ClusterState, StepInputs, Mailbox, StepInfo,
@@ -25,7 +25,7 @@ Contract rules (cheap execution -- eval_shape and one tiny npz round trip):
   dtype-comment            the `# [shape] dtype` field comments in types.py
                            parse (policy.parse_types_comments) and match the
                            ACTUAL dtypes/ndims `init_state`/`make_inputs`/
-                           `raft.step` produce, across the policy tiers
+                           `raft_batched.step_one` produce, across the policy tiers
                            (int8/int16 index planes, compaction's int32).
   checkpoint-version       the serialized-pytree field sets hash to the pin in
                            `checkpoint._SCHEMA_FINGERPRINT`, and the pin's
@@ -154,7 +154,7 @@ class _FunctionLint:
                         f"Python `{kind}` on traced value(s) {names} in "
                         f"{self.fn.name}(): kernels must use jnp.where/"
                         "lax.cond lattices, never Python control flow on "
-                        "array values (models/raft.py docstring)"
+                        "array values (models/raft_batched.py docstring)"
                     ),
                 ))
 

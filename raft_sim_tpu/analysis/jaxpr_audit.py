@@ -1,8 +1,8 @@
 """Pass A: lower the real step/scan programs and audit their jaxprs.
 
 Lowering is tracing only -- no XLA compile, so the whole pass runs in seconds
-on CPU even for the N=51 tier (`jax.make_jaxpr` over `raft.step`,
-`raft_batched.step_b`, and the jitted `scan.simulate`). The rules encode the
+on CPU even for the N=51 tier (`jax.make_jaxpr` over `raft_batched.step_b`
+and the jitted `scan.simulate`). The rules encode the
 invariants docs/PERF.md shows being lost silently:
 
   float-op             no floating-point primitive anywhere in an audited
@@ -139,30 +139,17 @@ FORK_PAIRS: tuple[tuple[str, dict], ...] = (
 # ---------------------------------------------------------------- program zoo
 
 
-def _step_avals(cfg: RaftConfig, batch: int | None):
-    state, inputs, _ = policy.state_avals(cfg)
-    if batch is not None:
-        addb = lambda x: jax.ShapeDtypeStruct(tuple(x.shape) + (batch,), x.dtype)
-        state = jax.tree.map(addb, state)
-        inputs = jax.tree.map(addb, inputs)
-    return state, inputs
-
-
 @functools.lru_cache(maxsize=None)
-def step_jaxpr(cfg: RaftConfig, batched: bool = False):
-    """ClosedJaxpr of one tick: `raft.step` (vmap form, per-cluster shapes) or
-    `raft_batched.step_b` (batch-minor, trailing batch axis). Cached per
-    (cfg, form): tracing dominates the gate's runtime and the rules, the fork
-    guard, and the golden tests all want the same programs."""
-    from raft_sim_tpu.models import raft, raft_batched
+def step_jaxpr(cfg: RaftConfig):
+    """ClosedJaxpr of one tick: `raft_batched.step_b` (batch-minor, trailing
+    batch axis). Cached per cfg: tracing dominates the gate's runtime and the
+    rules, the fork guard, and the golden tests all want the same programs."""
+    from raft_sim_tpu.models import raft_batched
 
-    if batched:
-        state, inputs = _step_avals(cfg, _AUDIT_BATCH)
-        fn = functools.partial(raft_batched.step_b, cfg)
-    else:
-        state, inputs = _step_avals(cfg, None)
-        fn = functools.partial(raft.step, cfg)
-    return jax.make_jaxpr(fn)(state, inputs)
+    state, inputs, _ = policy.state_avals(cfg)
+    addb = lambda x: jax.ShapeDtypeStruct(tuple(x.shape) + (_AUDIT_BATCH,), x.dtype)
+    state, inputs = jax.tree.map(addb, (state, inputs))
+    return jax.make_jaxpr(functools.partial(raft_batched.step_b, cfg))(state, inputs)
 
 
 @functools.lru_cache(maxsize=None)
@@ -292,15 +279,14 @@ def trace_extra_legs() -> int:
 
 
 def programs(name: str, cfg: RaftConfig):
-    """The audited programs for one config tier: both step kernels, the full
+    """The audited programs for one config tier: the step kernel, the full
     scan, the scenario (genome-path) scan, the standing-fleet serve scan, and
     the protocol-trace scan. Yields (program_name, closed_jaxpr, kind,
     rule_cfg) -- `rule_cfg` is the config the per-program rules (carry
     passthrough/dtype, input pricing) run under: the tier's own config,
     except for the serve/trace programs, which are audited under their
     serve-mode / trace-mode variants (offer-tick plane / trace legs live)."""
-    yield f"jaxpr:{name}/step", step_jaxpr(cfg, batched=False), "step", cfg
-    yield f"jaxpr:{name}/step_b", step_jaxpr(cfg, batched=True), "step", cfg
+    yield f"jaxpr:{name}/step_b", step_jaxpr(cfg), "step", cfg
     yield f"jaxpr:{name}/simulate", scan_jaxpr(cfg), "scan", cfg
     yield f"jaxpr:{name}/scenario_simulate", scenario_scan_jaxpr(cfg), "scan", cfg
     scfg = serve_variant(cfg)
@@ -433,7 +419,7 @@ def check_plane_widening(program: str, closed, cfg: RaftConfig) -> list[Finding]
     """Rule plane-widening: top-level convert_element_type eqns that widen an
     [N, N]-shaped int8/int16 plane, unless every consumer is a reduction (the
     widen-into-accumulator form XLA fuses away). Top level is where the
-    kernels' own `.astype` discipline lives; jnp-internal promotions in nested
+    kernel's own `.astype` discipline lives; jnp-internal promotions in nested
     bodies feed reductions by construction."""
     n = cfg.n_nodes
     consumers: dict = {}

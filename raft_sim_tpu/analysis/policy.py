@@ -302,13 +302,15 @@ def resolve_dtypes(spec: FieldSpec, cfg: RaftConfig) -> set[jnp.dtype]:
 def state_avals(cfg: RaftConfig):
     """eval_shape'd (ClusterState, StepInputs, StepInfo) for one cluster --
     the actual shapes/dtypes the comment contracts are checked against."""
-    from raft_sim_tpu.models import raft
+    from raft_sim_tpu.models import raft_batched
     from raft_sim_tpu.sim import faults
 
     key = jax.eval_shape(lambda: jax.random.key(0))
     state = jax.eval_shape(lambda k: rst_types.init_state(cfg, k), key)
     inputs = jax.eval_shape(lambda k: faults.make_inputs(cfg, k, jnp.int32(0)), key)
-    _, info = jax.eval_shape(lambda s, i: raft.step(cfg, s, i), state, inputs)
+    _, info = jax.eval_shape(
+        lambda s, i: raft_batched.step_one(cfg, s, i), state, inputs
+    )
     return state, inputs, info
 
 

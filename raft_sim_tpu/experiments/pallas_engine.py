@@ -9,7 +9,7 @@ possible HBM traffic per tick.
 
 Because `step_b` is pure jnp on batch-minor arrays, the kernel body simply *calls it*
 on values read from the block refs: there is no duplicated protocol logic, so the
-bit-parity chain (oracle -> raft.py -> raft_batched.py) extends to this engine for
+bit-parity chain (oracle -> raft_batched.step_b) extends to this engine for
 free, and tests/test_pallas.py pins it (interpret mode on CPU; the compiled TPU
 path is toolchain-blocked, see STATUS below).
 

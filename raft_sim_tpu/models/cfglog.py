@@ -4,7 +4,7 @@ The dissertation (ch. 4) requires configuration entries to be *acted on when
 appended, not when committed*: each server uses the latest configuration in
 its own log, and a truncation that removes a config entry must roll the
 server back to the previous one. This module is the SINGLE statement of that
-derivation for both kernels: given a node's config-entry plane
+derivation: given a node's config-entry plane
 (ClusterState.log_cfg), its log bounds, and its snapshot config context
 (base_mold/base_pend/base_epoch), recompute the node's effective
 (member_old, member_new, cfg_pend, cfg_epoch) -- executed at the end of
@@ -35,7 +35,7 @@ TEST-ONLY mutant hooks (scenario/mutation.py) weaken exactly one rule each:
                      change: the known-unsafe interleaving);
   cfg.truncation_rollback False -> applied where the epoch count DROPPED,
                      i.e. the caller keeps the stale carried config after a
-                     truncation (models/raft.py end-of-tick block).
+                     truncation (models/raft_batched.py end-of-tick block).
 """
 
 from __future__ import annotations
@@ -96,7 +96,7 @@ def _fold_core(cfg: RaftConfig, log_cfg, anchor, lo, hi, batched: bool):
     vfold = jnp.abs(code) - 1  # toggle target (garbage where ~fold_mask)
     # Parity fold of the toggle bits: count hits per (node, target) and keep
     # the low bit -- XOR of one-hot rows without an XOR reduction primitive
-    # (sum/compare/pack only: the op vocabulary both kernels already use).
+    # (sum/compare/pack only: the op vocabulary the kernel already uses).
     if batched:
         tgt = log_ops.iota((1, 1, n, 1), 2)
         hits = fold_mask[:, :, None, :] & (vfold[:, :, None, :] == tgt)

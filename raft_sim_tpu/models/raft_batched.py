@@ -1,17 +1,92 @@
-"""Batch-minor Raft tick kernel: the hot path for TPU execution.
+"""The Raft tick kernel: one pure state transition per simulated tick, for B
+clusters at once.
 
-Semantics are EXACTLY models/raft.py (same nine phases, same citations) -- this module
-exists purely for memory layout. The vmap form puts the cluster batch LEADING
-([B, N, ...]), which leaves each array's two minor dims at (N, N) or (N, CAP); TPU
-tiles the two minor dims to (8, 128), so a [B, 5, 5] int32 array physically occupies
-~40x its logical bytes and every tick is HBM-bound on padding (measured ~700KB moved
-per cluster-tick vs ~3KB of logical state). Here the batch axis B is MINOR on every
-array ([N, B], [N, N, B], [N, CAP, B]), so B rides the 128-wide lane tile and padding
-is bounded by the second-minor dim (N or E or CAP -> at most 8/5).
+This is the TPU-native re-expression of the reference's `wait` event loop
+(core.clj:176-195): deliver -> handle -> collect. Where the reference blocks on
+`alts!!` over [inbound requests, rpc responses, timeout] and dispatches ONE message per
+loop iteration, the array kernel delivers the whole [N, N] mailbox at once and folds
+every node's inbound edges through vectorized handler logic -- `jnp.where` lattices
+instead of `cond` cascades, no Python control flow, static shapes throughout.
 
-Parity with the vmap form is enforced bit-for-bit by tests/test_batched_parity.py;
-parity with the scalar oracle therefore transfers. Keep the two kernels in sync: any
-semantic change lands in raft.py first (with its unit tests), then here.
+Handler provenance (all spec-correct; the reference's deviations are catalogued in
+SURVEY.md section 2.3 and deliberately NOT carried):
+
+  phase 1  term adoption         <- scattered `(> term current-term)` checks
+                                    (core.clj:97, 129-130, 144-145); unlike the
+                                    reference, RequestVote also adopts terms (bug 2.3.2)
+  phase 2  vote requests         <- request-vote-handler (core.clj:91-103), with the
+                                    spec up-to-date check instead of compare-prev?
+  phase 3  append requests       <- append-entries-handler (core.clj:105-123), with
+                                    spec conflict-truncate-then-append instead of the
+                                    remove-from! bug (2.3.7) and real leader-commit
+                                    handling instead of apply-everything (2.3.6);
+                                    under compaction also the InstallSnapshot
+                                    analogue (req_off == -1 edges install the
+                                    sender's base/base_term/base_chk)
+  phase 5.5 log compaction       <- absent in the reference (its log vector is
+                                    unbounded, log.clj:33); the ring must free
+                                    committed slots so client workloads never
+                                    exhaust the fixed-capacity arrays
+  phase 4  responses             <- vote-response-handler (core.clj:125-139) and
+                                    append-response-handler (core.clj:141-149), with
+                                    next-index = match+1 (bug 2.3.10)
+  phase 5  leader commit         <- absent in the reference (bug 2.3.8): quorum-th
+                                    largest match index, current-term restriction
+  phase 6  client injection      <- client-set-handler's leader branch (core.clj:156-160)
+  phase 7  timers                <- generate-timeout + the nil dispatch arm
+                                    (core.clj:162-174, 193-195); election timers reset
+                                    only on vote grant / valid AppendEntries, not on
+                                    every message (bug 2.3.11)
+  phase 8  outbox                <- request-vote-rpc / append-entries-rpc
+                                    (core.clj:48-67) writing the next tick's mailbox
+  phase 9  invariants + metrics  <- absent in the reference; north-star requirement
+  phase -1 restart wipe          <- the reference's process-death model (only committed
+                                    values are durable, log.clj:16-18); here restart
+                                    keeps the Raft persistent triple up to the DURABLE
+                                    watermarks (raft_sim_tpu/storage; with
+                                    cfg.durable_storage off the disk is perfect and the
+                                    full triple survives), wipes volatile state, and
+                                    down nodes are gated out of delivery, timers,
+                                    leadership, and commit
+  phase 7.5 fsync flush          <- absent in the reference (its file-backed atom has
+                                    no fsync discipline, log.clj:16-18): the durable
+                                    watermarks advance on the device-side fsync model's
+                                    completed flushes, and the section-3.8 gates hold
+                                    AE acks and vote grants to durable state
+
+Layout: the batch axis B is MINOR on every array ([N, B], [N, N, B], [N, CAP, B]),
+so B rides the 128-wide lane tile and padding is bounded by the second-minor dim
+(N or E or CAP -> at most 8/5). With the batch LEADING ([B, N, ...]) each array's
+two minor dims would be (N, N) or (N, CAP); TPU tiles the two minor dims to
+(8, 128), so a [B, 5, 5] int32 array would physically occupy ~40x its logical bytes
+and every tick would be HBM-bound on padding (measured ~700KB moved per
+cluster-tick vs ~3KB of logical state). `to_batch_minor`/`from_batch_minor`
+transpose once per run; `step_one` steps one cluster in the public layout.
+tests/test_oracle_parity.py and tests/test_batched_parity.py hold this kernel to
+the scalar oracle (tests/oracle.py) tick by tick.
+
+TRACE DELTA CONTRACT (raft_sim_tpu/trace, cfg.track_trace): the protocol
+trace plane derives discrete events from this kernel's state DELTAS --
+role, term, voted_for, commit_index, log_len, dur_len, and (reconfiguration
+plane) cfg_epoch, log_cfg, xfer_to, read_idx -- outside the kernel (one
+extractor serves any step_fn override too; zero step lowerings added).
+Phase-order properties load-bearing for the whole-history checker, which must
+survive refactors: (1) a node that loses leadership and accepts entries in
+one tick changes `role` in the SAME tick as `log_len` (phase 1 adoption
+precedes phase 3 append -- the checker replays role changes before log
+changes); (2) a win (phase 4) can never co-occur with an AE-accept
+truncation on the same node (a candidate that accepted a current-term AE
+stepped down in phase 3 and cannot win); (3) elections precede the
+end-of-tick config derivation, so EV_LEADER events belong to the TICK-START
+per-node configuration (EV_CFG_APPLY/ROLLBACK replay after the role
+kinds); (4) a read slot dropped while its holder stays a same-term
+un-restarted leader was SERVED -- every cancel path changes role/term or
+sets `restarted` (phase 5.2's clear rules); (5) a `dur_len` ADVANCE is
+always a completed flush (EV_FSYNC: the only writer besides recovery is
+phase 7.5, and recovery never raises it), and a `log_len` DROP on a
+`restarted` node is always the recovery truncation (EV_RECOVER_TRUNC:
+restarted nodes receive nothing, so the AE conflict truncation cannot
+co-occur on them). See trace/events.py.
 """
 
 from __future__ import annotations
@@ -142,11 +217,32 @@ def from_batch_minor(tree):
     return jax.tree.map(lambda x: jnp.moveaxis(x, -1, 0), tree)
 
 
+def step_one(
+    cfg: RaftConfig, s: ClusterState, inp: StepInputs
+) -> tuple[ClusterState, StepInfo]:
+    """`step_b` for ONE cluster in the public layout: state and inputs with
+    no batch axis in, (state, StepInfo) with none out. A trailing batch axis
+    of 1 is put on and dropped again (`x[..., 0]` keeps host-constant
+    StepInfo leaves numpy)."""
+    s2, info = step_b(cfg, *jax.tree.map(lambda x: x[..., None], (s, inp)))
+    return jax.tree.map(lambda x: x[..., 0], (s2, info))
+
+
 def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
                     gsum, gmax, gmin):
-    """The tick's offer->commit latency accounting (raft.py phase 5's `track`
-    block, batch-minor): StepInfo's lat_sum, lat_cnt, lat_hist [BINS, B] and
-    lat_excluded, and the carried lat_frontier.
+    """The tick's offer->commit latency accounting (client workloads only):
+    StepInfo's lat_sum, lat_cnt, lat_hist [BINS, B] and lat_excluded, and the
+    carried lat_frontier.
+
+    Each client entry's offer stamp rides the log_tick plane (phase 6 writes
+    it at injection; AE replication carries it via Mailbox.ent_tick), so the
+    live leader's commit advancement this tick contributes (now - offer_tick)
+    per newly committed client entry -- the measurement the reference's
+    commit watch was meant to feed (log.clj:83-87, never fired, bug 2.3.9).
+    VALUES are never read here: payloads are arbitrary int32, so a value
+    colliding with a tick cannot corrupt the histogram. Called after phase 5
+    and before compaction/injection can touch slots (the checksum pass's
+    aliasing rule).
 
     log_tick [N, CAP, B] offer stamps; lead [N, B] live leaders; commit [N, B];
     base [N, B] log_base, or None without compaction; frontier, now [B];
@@ -154,8 +250,8 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
     g* folds reduce over the node axis across shards (identity unsharded,
     as in _step_b).
 
-    One pass over the ring, equal to raft.py's per-slot form (which
-    tests/test_latency_accounting.py keeps as the reference): no per-slot
+    One pass over the ring, equal to the per-slot form
+    tests/test_latency_accounting.py keeps as the reference: no per-slot
     modulo, and the histogram comes from threshold counts, not from a
     per-slot bin index."""
     cap = log_tick.shape[1]
@@ -168,9 +264,14 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
         off = sl - (base % cap)[:, None, :]
         off = jnp.where(off < 0, off + cap, off)
         abs0 = base
-    # Carried-frontier dedup, compared in offset space: abs1 > frontier <=>
-    # off > frontier - abs0 - 1. Stamps read from the offer-tick plane, never
-    # from values (raft.py).
+    # Dedup across leader changes AND restarts: a freshly elected leader's
+    # own commit trails the cluster's prior frontier and would re-count
+    # entries its predecessor already reported, so only entries above the
+    # CARRIED monotone frontier contribute (the per-node commit vector is
+    # restart-mutable -- ClusterState.lat_frontier). Compared in offset
+    # space: abs1 > frontier <=> off > frontier - abs0 - 1. Stamps are offer
+    # tick + 1, always in (0, now] at commit time; slots holding no client
+    # entry (no-ops, unwritten) carry stamp 0 and fall out of `cli`.
     lo = frontier[None, :] - abs0 - 1  # [N or 1, B]
     newly = (off > lo[:, None, :]) & (off <= (commit - abs0 - 1)[:, None, :])
     cli = (log_tick >= 1) & (log_tick <= now[None, None, :])
@@ -186,8 +287,14 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
     ]
     lat_cnt = gsum(ge[0])
     lat_hist = gsum(jnp.stack([a - b for a, b in zip(ge, ge[1:])] + [ge[-1]]))
-    # Coverage gap counter: crossed-but-unattributed client entries, read on
-    # the lowest-id max-commit node (raft.py for the full rationale).
+    # Coverage gap counter (StepInfo.lat_excluded): client entries the
+    # frontier advance crosses without attribution. The frontier advances
+    # to max(commit) regardless of leadership; count the crossed client
+    # entries on the (lowest-id) node HOLDING that max -- its log carries
+    # everything in (frontier, max commit] by log matching -- and subtract
+    # what lat_cnt attributed. Clamped at zero: under compaction the
+    # max-commit node may have compacted a crossed slot the leader still
+    # counted, and split-brain double-counts inflate lat_cnt.
     is_maxc = commit == gmax(jnp.max(commit, axis=0))[None, :]
     hnode = gmin(jnp.min(jnp.where(is_maxc, ids2, n), axis=0))  # [B]
     crossed = (ids2 == hnode[None, :])[:, None, :] & newly & cli
@@ -202,18 +309,18 @@ def step_b(
     cfg: RaftConfig, s: ClusterState, inp: StepInputs, sh: NodeShardCtx | None = None
 ) -> tuple[ClusterState, StepInfo]:
     """One tick for B clusters at once; every array carries a trailing batch axis.
+    Pure; jit/scan-safe. The module docstring has the phase table and the
+    TRACE DELTA CONTRACT, which tests/test_trace.py pins by re-deriving the
+    chunked path's device events from `scan.run`'s stacked states.
 
-    Mirrors raft.step phase by phase; see that function for the reference
-    citations -- and for the TRACE DELTA CONTRACT (raft_sim_tpu/trace reads
-    role/term/voted_for/commit_index/log_len deltas of this kernel too; the
-    phase-order properties documented there bind both kernels, which
-    tests/test_trace.py pins by re-deriving the batched path's device events
-    from the unbatched kernel's stacked states).
-
-    Under cfg.compact_planes this boundary mirrors raft.step's: unpack the
-    compacted carry (ops/tile.py; trailing batch axes ride along), run the
-    identical dense tick, repack with gated-off legs passed through
-    verbatim.
+    Under cfg.compact_planes the carry arrives in the compacted layout
+    (ops/tile.py: per-edge value planes bit-packed into flat uint32 legs,
+    word/window planes flattened; trailing batch axes ride along); this
+    boundary unpacks to the dense working view, runs the identical dense
+    tick, and repacks -- gated-off mailbox legs are passed through verbatim
+    (`reuse`) so the carry-passthrough contract holds exactly as in the
+    dense layout. Trajectories are bit-identical either way
+    (tests/test_tile.py).
 
     The dense tick's phases run under the `tick.<phase>` named scopes of
     obs/scopes.py (op metadata only: the lowered program is unchanged).
@@ -312,7 +419,20 @@ def _step_b(
 
     phase("deliver")
     # ---- phase -1: restart (crash fault) -----------------------------------------
-    # The snapshot triple is persistent: commit resumes at log_base (raft.py).
+    # A node restarting this tick rejoins as a fresh follower: the Raft persistent
+    # triple (currentTerm, votedFor, log[]) survives -- including the snapshot
+    # (log_base/base_term/base_chk), so commitIndex resumes at log_base, the
+    # durable applied prefix -- everything else is volatile and wiped (Raft fig. 2
+    # state table). The reference instead persists only committed values
+    # (log.clj:16-18), so its restarted process forgets term/vote -- bug 2.3.12,
+    # deliberately not carried. HOW MUCH of the triple survives is the durable
+    # storage plane's gate (raft_sim_tpu/storage, cfg.durable_storage): with the
+    # gate off the disk is perfect and the full triple survives instantly; with
+    # it on, the recovery block below rewinds term/vote to the durable snapshot
+    # and truncates the log tail the disk never confirmed (dissertation section
+    # 3.8 -- the failure class the plane exists to express). Wiping commitIndex
+    # here (before `s` is handed to phase 9 as the old state) keeps the
+    # monotonic-commit invariant meaningful.
     rs = inp.restarted  # [N, B]
     rs2 = rs[:, None, :]
     s = s._replace(
@@ -327,8 +447,12 @@ def _step_b(
         deadline=jnp.where(rs, s.clock + inp.timeout_draw, s.deadline),
     )
     if dur:
-        # Crash recovery (raft.py phase -1; storage/plane.recover is
-        # elementwise, so the [N, B] orientation broadcasts through).
+        # Crash recovery (storage/plane.recover, elementwise, so the [N, B]
+        # orientation broadcasts through): the disk holds the fsynced prefix
+        # for sure plus whatever un-fsynced tail the in-flight writes
+        # reached, minus the torn tail the recovery checksum rejects
+        # (inp.torn_drop, drawn every tick, consumed only here);
+        # term/votedFor rewind to the durable snapshot.
         r_term, r_vote, r_len = storage_plane.recover(
             cfg, rs, inp.torn_drop,
             s.dur_len, s.dur_term, s.dur_vote,
@@ -338,17 +462,18 @@ def _step_b(
     if cfg.pre_vote or rdl or rcf:
         # A restarted node remembers no leader contact: "quiet" immediately
         # (pre-votes grantable, and -- under the lease or log-carried-config
-        # denial gates -- real votes too: raft.py phase -1).
+        # denial gates -- real votes too: a restarted voter holds no
+        # obligation toward a leader it no longer remembers).
         s = s._replace(
             heard_clock=jnp.where(
                 rs, s.clock - cfg.election_min_ticks, s.heard_clock
             )
         )
     if xfr:
-        # A pending transfer is volatile leader state (raft.py phase -1).
+        # A pending transfer is volatile leader state: lost with the process.
         s = s._replace(xfer_to=jnp.where(rs, NIL, s.xfer_to))
     if rdx:
-        # Pending reads die with the process too (raft.py phase -1).
+        # Pending reads die with the process too (the client retries).
         s = s._replace(
             read_idx=jnp.where(rs, 0, s.read_idx),
             read_tick=jnp.where(rs, 0, s.read_tick),
@@ -363,20 +488,28 @@ def _step_b(
     mb = s.mailbox if sh is None else _gather_mailbox(cfg, s.mailbox, sh)
     base, bterm, bchk = s.log_base, s.base_term, s.base_chk  # [N, B]
     if rcf:
-        # Snapshot config context (raft.py): carried untouched without comp.
+        # Snapshot config context (compaction x reconfig; constant full-row /
+        # zero legs otherwise -- carried untouched when comp is off).
         bmold, bpend, bepoch = s.base_mold, s.base_pend, s.base_epoch
 
-    # Reconfiguration plane: log-carried, PER-NODE configuration masking
-    # (raft.py): member rows are each node's derived view of its own log
-    # prefix, [N, W, B]; every quorum test masks by the TESTING node's rows,
-    # dual while that node's cfg_pend marks an open joint entry.
+    # Reconfiguration plane (cfg.reconfig): log-carried, PER-NODE
+    # configuration masking. member_old/member_new/cfg_pend are each node's
+    # DERIVED view of its own log prefix ([N, W, B]; ClusterState docstring;
+    # the end-of-tick block recomputes them via models/cfglog.py), so every
+    # quorum test below masks by the TESTING NODE's own rows -- dual
+    # (majorities of BOTH configurations) while that node's prefix holds an
+    # uncompleted joint entry. Quorum tests read the TICK-START derivation;
+    # entries appended this tick govern the next (apply-on-append at tick
+    # granularity, the same one-tick rule every phase transition follows).
     if rcf:
         m_old, m_new = s.member_old, s.member_new  # [N, W, B]
         joint = s.cfg_pend > 0  # [N, B]
         maj_old = bitplane.count(m_old, axis=1) // 2 + 1  # [N, B]
         maj_new = bitplane.count(m_new, axis=1) // 2 + 1
-        # Node i's own-membership bit (raft.py: the removed-server
-        # disruption surface when a log misses its removal entry).
+        # Node i's own-membership bit: is i a voter of ITS OWN config union?
+        # A node whose log carries its removal quiesces (never campaigns);
+        # one whose log MISSES the removal still thinks it votes -- the
+        # removed-server disruption the 4.2.3 denial below defends against.
         member_b = jnp.any(((m_old | m_new) & eye_p3) != 0, axis=1)  # [N, B]
 
         def packed_quorum(rows):
@@ -391,11 +524,19 @@ def _step_b(
             return bitplane.count(rows, axis=1) >= cfg.quorum
 
     # ---- phase 0: delivery -------------------------------------------------------
-    # Input mask is per physical edge [to, from]; requests ([sender, receiver]) read
-    # it transposed, responses ([receiver, responder]) directly (raft.py phase 0).
-    # The mask arrives bit-packed over the source axis (raft.py phase 0): the
-    # response orientation runs its AND-chain on the packed words and unpacks
-    # once; the request orientation unpacks and transposes in bool space.
+    # The fault mask is the TPU-native form of the reference's silently-dropped HTTP
+    # call (client.clj:38-40): a zeroed entry in the delivery mask. A down node is
+    # silent in both directions: it receives nothing, and anything it had in flight
+    # dies with it (the crashed process's sockets). Mailbox slots hold messages sent
+    # last tick, so a node that just restarted must also not see them -- they were
+    # addressed to a dead process (alive now & alive at send time = alive & ~restarted).
+    # The input mask is indexed by physical directed edge [to, from] and arrives
+    # BIT-PACKED over the source axis (StepInputs docstring): the response-side
+    # chain ([receiver, responder] = [to, from], same orientation) runs on the
+    # packed words -- per-source gates AND as packed rows, per-receiver gates as
+    # row selects -- and unpacks once; request fields are stored
+    # [sender, receiver] (= [from, to], Mailbox docstring), so the request
+    # orientation unpacks the mask and transposes in bool space.
     dst_up = inp.alive & ~inp.restarted  # alive now AND at send time (last tick)
     # Receiver-row slices of the (full, redundantly drawn) delivery mask; the
     # packed source words cover all n_pad senders either way (pad bits are
@@ -420,9 +561,17 @@ def _step_b(
     req_in = deliver_req & (mb.req_type != 0)[:, None, :]
     resp_in = deliver_resp & (mb.resp_kind != 0)
 
-    # Heard-a-leader denial window (thesis 4.2.3; raft.py for the full
-    # argument): shared by the log-carried membership defense (rcf) and the
-    # lease vote denial (rdl), bypassed by the transfer override flag.
+    # Heard-a-leader denial window (thesis 4.2.3), shared by the log-carried
+    # membership defense (rcf: a removed server whose log misses its removal
+    # still campaigns -- voters that heard a current leader recently must
+    # neither adopt its inflated term nor grant it votes) and the lease vote
+    # denial (rdl). Judged on the voter's LOCAL clock against the TICK-START
+    # heard_clock -- this tick's AppendEntries land in phase 3, after votes
+    # -- which only SHORTENS the window by one tick (the lease validator's
+    # +4 slack covers it; docs/PROTOCOL.md). The disruptive-RequestVote
+    # override (req_disrupt, set on transfer-triggered elections) bypasses
+    # the denial: the leader being replaced sanctioned that election, so
+    # denying it would deadlock every TimeoutNow transfer.
     if rcf or rdl:
         heard_recent = (
             (s.clock + inp.skew) - s.heard_clock < cfg.election_min_ticks
@@ -434,15 +583,21 @@ def _step_b(
         else:
             rv_denied = jnp.broadcast_to(heard_recent[None, :, :], (n, n, b))
 
-    # ---- phase 1: term adoption (PreVote probes carry a PROSPECTIVE term:
-    # never adopted -- raft.py phase 1) -------------------------------------------
+    # ---- phase 1: term adoption --------------------------------------------------
+    # Spec: any RPC (request or response) with term T > currentTerm -> set
+    # currentTerm = T, convert to follower. The reference does this for responses
+    # (core.clj:129-130, 144-145) but not vote requests (bug 2.3.2). A PreVote
+    # request's term is PROSPECTIVE (thesis 9.6) -- it must never be adopted.
     if cfg.pre_vote:
         term_req = req_in & (mb.req_type != REQ_PREVOTE)[:, None, :]
     else:
         term_req = req_in
     if rcf:
-        # 4.2.3 in full: denied RequestVotes are not PROCESSED -- no term
-        # adoption either (the removed-server disruption defense; raft.py).
+        # 4.2.3 in full: a denied RequestVote is not PROCESSED -- its term is
+        # not adopted either, so a removed server's inflated term cannot
+        # depose a live leader through its own voters (the disruption
+        # defense; under rdl alone the grant-only denial is kept -- adoption
+        # stays legal there).
         term_req = term_req & ~(
             (mb.req_type == REQ_VOTE)[:, None, :] & rv_denied
         )
@@ -464,18 +619,29 @@ def _step_b(
         my_last_idx, my_last_term = log_ops.last_index_term_b(s.log_term, s.log_len)
 
     phase("elect")
-    # ---- phase 2: RequestVote requests -------------------------------------------
+    # ---- phase 2: RequestVote requests (request-vote-handler, core.clj:91-103) ----
     is_rv = req_in & (mb.req_type == REQ_VOTE)[:, None, :]  # [candidate, voter, B]
-    cur_rv = is_rv & (mb.req_term[:, None, :] == term[None, :, :])
+    cur_rv = is_rv & (mb.req_term[:, None, :] == term[None, :, :])  # stale terms denied
+    # Spec 5.4.1 up-to-date check (the reference's compare-prev? log.clj:55-59 compares
+    # against the commit index and whole entry maps -- bugs 2.3.3/2.3.4).
     up_to_date = (mb.req_last_term[:, None, :] > my_last_term[None, :, :]) | (
         (mb.req_last_term[:, None, :] == my_last_term[None, :, :])
         & (mb.req_last_index[:, None, :] >= my_last_idx[None, :, :])
     )
     can_grant = cur_rv & up_to_date
     if rcf or rdl:
-        # Heard-a-leader vote denial (thesis 4.2.3; raft.py phase 2), with
-        # the transfer override folded into rv_denied.
+        # Heard-a-leader vote denial (thesis 4.2.3; the shared window above):
+        # under the lease gate this is the rule 6.4.1 leans on -- a leader
+        # whose heartbeats a quorum acked L ticks ago KNOWS no election can
+        # complete for election_min_ticks/2 more global ticks (local clocks
+        # advance at most 2/tick under skew; the config validator pins the
+        # lease term under that bound). Under the log-carried membership
+        # plane it is the removed-server disruption defense. The transfer
+        # override (rv_denied folds in req_disrupt) lets TimeoutNow
+        # elections through either way.
         can_grant = can_grant & ~rv_denied
+    # At most one grant per node per tick: the lowest eligible candidate id wins the
+    # race (the reference serializes naturally, one message per wait iteration).
     lowest = jnp.min(jnp.where(can_grant, snd_ids, n), axis=0)  # [N, B]
     # Boolean arithmetic instead of where-on-bools: Mosaic cannot lower vector
     # selects with i1 operands.
@@ -485,25 +651,38 @@ def _step_b(
     )
     granted_any = jnp.any(grant, axis=0)  # [N, B]
     voted_for = jnp.where((voted_for == NIL) & granted_any, lowest, voted_for)
-    vr_out = is_rv  # [candidate, voter] = response orientation [receiver, responder]
-    # Grant target = post-update voted_for (raft.py phase 2: no reduction needed).
+    # Every delivered RV gets a response carrying our (possibly just-adopted) term;
+    # [candidate, voter] is already the response orientation [receiver, responder].
+    # The grant itself is per RESPONDER: at most one candidate per tick (Mailbox),
+    # and a grant always targets the post-update voted_for (re-grants re-name it,
+    # fresh grants just set it) -- no reduction over the grant plane needed. Safe
+    # to read here: phase 7 cannot rebind voted_for for a granter this tick (a
+    # grant resets the election deadline to clock + draw > clock, so the granter
+    # cannot also expire).
+    vr_out = is_rv
     grant_to = jnp.where(granted_any, voted_for, NIL).astype(node_dtype(cfg))  # [N, B]
 
     phase("replicate")
-    # ---- phase 3: AppendEntries requests ------------------------------------------
+    # ---- phase 3: AppendEntries requests (append-entries-handler, core.clj:105-123) --
     is_ae = req_in & (mb.req_type == REQ_APPEND)[:, None, :]  # [leader, follower, B]
     cur_ae = is_ae & (mb.req_term[:, None, :] == term[None, :, :])
+    # Election safety gives at most one leader per term, so at most one current-term AE
+    # sender exists; pick the lowest id defensively (ties indicate a safety violation,
+    # which phase 9 flags).
     ae_src = jnp.min(jnp.where(cur_ae, snd_ids, n), axis=0)  # [N, B]
     has_ae = ae_src < n
     sel = cur_ae & (snd_ids == ae_src[None, :, :])  # one-hot [sender, receiver, B]
 
     # Reconstruct the per-edge AE header from the selected sender's broadcast record
-    # plus this edge's window offset j (Mailbox docstring; raft.py phase 3). All
-    # selections are one-hot sums (no gather); when no sender is selected everything
-    # is zeros and gated by has_ae/ae_ok downstream.
+    # plus this edge's window offset j (Mailbox docstring). All selections are
+    # one-hot sums (no gather); when no sender is selected everything is zeros and
+    # gated by has_ae/ae_ok downstream.
     pick_h = lambda h: jnp.sum(jnp.where(sel, h[:, None, :], 0), axis=0)  # [N, B]
     j_in = jnp.sum(jnp.where(sel, mb.req_off, 0), axis=0).astype(jnp.int32)  # [N, B] in 0..E
-    # InstallSnapshot analogue: offset sentinel -1 (raft.py phase 3).
+    # InstallSnapshot analogue (compaction only): offset sentinel -1 means "install
+    # my compaction base instead of entries" -- sent when this peer's next_index
+    # fell below the leader's log_base (phase 8), the array form of Raft fig. 13.
+    # The reference can never need this (its log is unbounded, core.clj:59-67).
     if comp:
         snap = has_ae & (j_in < 0)
         ae_norm = has_ae & ~snap
@@ -511,8 +690,8 @@ def _step_b(
         snap = jnp.zeros_like(has_ae)
         ae_norm = has_ae
     # Well-formed mailboxes keep the one-hot sum in [-1, E]; the clip bounds the
-    # fully-masked garbage lane (and routes snap's -1 to 0, gated by ae_norm), as
-    # in raft.py. Keeps prev_i provably within the idx dtype on wide-N tiers.
+    # fully-masked garbage lane (and routes snap's -1 to 0, gated by ae_norm).
+    # Keeps prev_i provably within the idx dtype on wide-N tiers.
     j_nn = jnp.clip(j_in, 0, e)
     ws_in = pick_h(mb.ent_start)
     lcommit = pick_h(mb.req_commit)
@@ -551,6 +730,9 @@ def _step_b(
     ent_tick_in = log_ops.window_b(w_tick_in, off, e) if track else None
     ent_cfg_in = log_ops.window_b(w_cfg_in, off, e) if rcf else None
 
+    # A valid AE from the current term makes candidates (and pre-candidates)
+    # step down and identifies the leader (core.clj:121-123, minus the :follwer
+    # typo, bug 2.3.1).
     if cfg.pre_vote:
         stepdown = (role == CANDIDATE) | (role == PRECANDIDATE)
     else:
@@ -558,10 +740,13 @@ def _step_b(
     role = jnp.where(has_ae & stepdown, FOLLOWER, role)
     leader_id = jnp.where(has_ae, ae_src, leader_id)
 
+    # Consistency check (spec 5.3; reference compare-prev? has bugs 2.3.4/2.3.5).
     if comp:
         prev_stored_term = log_ops.term_at_rb(s.log_term, base, bterm, prev_i)
-        # prev below the local base is committed-and-compacted: consistent by
-        # leader completeness; at prev == base the check is against base_term.
+        # prev below the local base is committed-and-compacted: it matches by
+        # leader completeness (a current-term leader's log holds every committed
+        # entry); at prev == base, term_at_rb yields base_term -- the snapshot
+        # boundary check.
         consistent = (
             (prev_i == 0)
             | (prev_i < base)
@@ -574,11 +759,16 @@ def _step_b(
         )
     ae_ok = ae_norm & consistent
 
+    # Conflict scan over the shipped window: first mismatching entry truncates the rest
+    # of the log; matching prefixes are never truncated (spec 5.3 "delete the existing
+    # entry and all that follow it").
     ks_e = iota((1, e, 1), 1)  # [1, E, 1]
     gidx0 = prev_i[:, None, :] + ks_e  # [N, E, B] 0-based entry indices
     if comp:
-        # Skip already-compacted entries, accept only what the ring can hold
-        # (raft.py phase 3).
+        # Skip entries the ring already compacted (abs index <= base) and accept
+        # only what it can hold (entries past base + CAP would evict live,
+        # un-compacted slots; the partial ack makes the leader retry the rest
+        # after this node's own commit+compaction frees room).
         lo = jnp.clip(base - prev_i, 0, e)  # [N, B]
         n_acc = jnp.minimum(n_ent, jnp.maximum(base + cap - prev_i, 0))
         in_ent = (ks_e >= lo[:, None, :]) & (ks_e < n_acc[:, None, :])
@@ -595,8 +785,18 @@ def _step_b(
     new_len = jnp.where(any_mismatch, appended_len, jnp.maximum(s.log_len, appended_len))
     log_len = jnp.where(ae_ok, new_len, s.log_len)
     if dur:
-        # Durable watermark after the AE conflict truncation (raft.py phase 3).
+        # Truncation makes the removed suffix non-durable AS LOG CONTENT: the
+        # watermark clamps down with the log (the bytes may sit on disk, but
+        # the durable-log contract is about the entries the recovery would
+        # reconstruct, and those are gone). Appends do NOT advance it -- only
+        # a completed flush does (phase 7.5).
         dur_mid = jnp.minimum(s.dur_len, log_len)
+    # The offer-stamp plane replicates with the entries it tags, and the
+    # config-entry plane under the SAME masks (non-config entries ship 0, so
+    # an accepted window scrubs any stale config command off the slots it
+    # overwrites -- the rollback hazard the derivation depends on,
+    # ClusterState.log_cfg docstring): neither can diverge from the value
+    # plane's slot occupancy.
     if comp:
         log_term_arr = log_ops.write_window_rb(
             s.log_term, prev_i, ent_term_in, ae_ok, lo, n_acc
@@ -609,8 +809,6 @@ def _step_b(
                 s.log_tick, prev_i, ent_tick_in, ae_ok, lo, n_acc
             )
         if rcf:
-            # Same masks as the value plane: non-config entries ship 0 and
-            # scrub stale config commands off reused slots (raft.py).
             log_cfg_arr = log_ops.write_window_rb(
                 s.log_cfg, prev_i, ent_cfg_in, ae_ok, lo, n_acc
             )
@@ -630,6 +828,8 @@ def _step_b(
     if not rcf:
         log_cfg_arr = s.log_cfg  # untouched: loop-invariant carry leg
 
+    # Follower commit: min(leaderCommit, index of last new entry), monotonic
+    # (the reference's apply-entries! commits everything unconditionally, bug 2.3.6).
     # The floor at 0 is a no-op on the ae_ok path (prev_i/n_acc are
     # non-negative for a real AE) but bounds the masked-garbage lane so the
     # int8/int16 a_match narrowing below is provably in range (Pass E).
@@ -640,8 +840,11 @@ def _step_b(
         s.commit_index,
     )
 
-    # Snapshot install (raft.py phase 3): adopt the sender's compaction state,
-    # retaining our suffix when it extends through L with the snapshot's term.
+    # Snapshot install (compaction only). L <= base needs nothing (we already hold
+    # that prefix -- plain ack); otherwise, if our log extends through L with the
+    # snapshot's term, retain the suffix (Raft fig. 13 rule 6), else discard the
+    # whole log. Either way our compaction state becomes the leader's and commit
+    # advances to at least L (everything below a snapshot is committed).
     if comp:
         L = jnp.where(snap, pick_h(mb.req_base), 0)
         Lt = pick_h(mb.req_base_term)
@@ -659,7 +862,9 @@ def _step_b(
         log_len = jnp.where(wipe, L, log_len)
         commit = jnp.where(apply_snap, jnp.maximum(commit, L), commit)
         if rcf:
-            # Snapshot config context installs with the snapshot (raft.py).
+            # The snapshot carries its configuration context: the sender's
+            # C_old/pending-toggle/entry-count at L, so the receiver's
+            # derivation stays exact over config entries it never saw.
             Lmold = jnp.sum(
                 jnp.where(
                     sel[:, :, None, :], mb.req_base_mold[:, None], jnp.uint32(0)
@@ -672,10 +877,18 @@ def _step_b(
     else:
         apply_snap = snap
 
-    # [leader, follower] is already the response orientation [receiver, responder]
-    # (snapshot installs always ack, with match = the snapshot index); the payload
-    # is per responder -- at most one success target, one shared nack hint
-    # (raft.py phase 3, Mailbox docstring).
+    # Respond to every delivered AE; success only for the selected, consistent one
+    # (snapshot installs always ack, with match = the snapshot index). A NACK
+    # carries the responder's log length as a catch-up hint: the leader jumps
+    # next_index straight to hint+1 instead of decrementing once per heartbeat --
+    # the standard conflict-index optimization (Raft paper section 5.3 "the
+    # protocol can be optimized"). Without it a freshly elected leader walks next
+    # down 1 per nack while client traffic grows its log ~1 per tick, and under
+    # recurring crash churn no current-term entry ever reaches quorum (measured
+    # livelock: commit frozen for thousands of ticks).
+    # [leader, follower] is already the response orientation [receiver, responder];
+    # the payload is per responder -- at most one success target, one shared nack
+    # hint (Mailbox docstring).
     ar_out = is_ae
     if comp:
         a_ok = ae_ok | snap
@@ -689,11 +902,16 @@ def _step_b(
     out_a_hint = log_len.astype(idt)  # post-append, pre-injection (phase 6 rebinds)
 
     phase("elect")
-    # ---- phase 3.5: PreVote requests (thesis 9.6; raft.py) -----------------------
+    # ---- phase 3.5: PreVote requests (thesis 9.6; cfg.pre_vote) ------------------
+    # Grant iff the probe's prospective term is not behind us, the probing log is
+    # up to date (the phase-2 check -- probes fill the same req_last_* header),
+    # and we are QUIET: not a leader ourselves and no valid AppendEntries
+    # accepted within the minimum election timeout (including this tick's).
+    # Grants are non-binding: no votedFor, no term change, no timer reset.
     if cfg.pre_vote or rdl or rcf:
         # heard_clock serves the pre-vote quiet rule, the lease vote denial,
         # and the log-carried-config removed-server denial (phase 2) -- any
-        # gate keeps the leg live (raft.py).
+        # gate keeps the leg live.
         clock_pv = s.clock + inp.skew  # phase 7's clock; duplicated, CSE'd
         heard = jnp.where(has_ae, clock_pv, s.heard_clock)  # [N, B]
     else:
@@ -709,7 +927,14 @@ def _step_b(
         )
         pv_out = is_pv
 
-    # ---- phase 3.7: TimeoutNow receipt (thesis 3.10; raft.py) --------------------
+    # ---- phase 3.7: TimeoutNow receipt (thesis 3.10; cfg.leader_transfer) --------
+    # The transfer target starts a REAL election IMMEDIATELY: no timer, no
+    # pre-vote probe (the thesis's explicit bypass -- the target is known
+    # caught up, and the transferring leader's lease would make every voter
+    # deny a probe). Gated on the request carrying the receiver's CURRENT
+    # term, so a stale TimeoutNow from a deposed leader (or one that already
+    # succeeded: the new leader's term moved past it) is inert. The election
+    # itself fires in phase 7 alongside timer-driven starts.
     if xfr:
         rcv_ids = iota((1, n, 1), 1)  # [1, N(receiver), 1]
         is_tn = req_in & (mb.req_type == REQ_TIMEOUT_NOW)[:, None, :]
@@ -722,7 +947,10 @@ def _step_b(
         if rcf:
             xfer_elect = xfer_elect & member_b  # non-voters never campaign
         if not cfg.xfer_election:
-            # TEST-ONLY mutant: transfer as a coup (raft.py phase 3.7).
+            # TEST-ONLY mutant (cfg.xfer_election False): transfer as a coup.
+            # The target assumes leadership DIRECTLY -- no vote round, no
+            # up-to-date check -- so a behind target replicates its short log
+            # over committed entries (the violation the hunt must re-find).
             coup = xfer_elect
             term = term + coup
             role = jnp.where(coup, LEADER, role)
@@ -733,6 +961,9 @@ def _step_b(
 
     phase("replicate")
     # ---- phase 4: responses ------------------------------------------------------
+    # Vote tally (vote-response-handler core.clj:125-139; dedup via bitmap mirrors the
+    # reference's set, core.clj:133-134). Granted = this responder's one grant
+    # (v_to) names me (Mailbox response decode).
     vresp = resp_in & (mb.resp_kind == RESP_VOTE)
     new_votes = (
         vresp
@@ -742,8 +973,10 @@ def _step_b(
     )
     votes = votes | bitplane.pack(new_votes, axis=1)
     # Packed-quorum test: word popcount over [N, W, B] instead of a bool-plane
-    # sum over [N, N, B] (raft.py phase 4); configuration-masked (dual during
-    # joint phases) when the reconfiguration plane is live.
+    # sum over [N, N, B] (the bitplane module's reason to exist);
+    # configuration-masked (dual during joint phases) when the reconfiguration
+    # plane is live. A down candidate cannot assume leadership from votes
+    # banked before it crashed.
     win = (role == CANDIDATE) & packed_quorum(votes) & inp.alive
     if rcf:
         win = win & member_b  # a removed node cannot win on banked votes
@@ -751,6 +984,8 @@ def _step_b(
         win = win | coup  # mutant coups ride the fresh-leader bookkeeping
     role = jnp.where(win, LEADER, role)
     leader_id = jnp.where(win, ids2, leader_id)
+    # Fresh leader bookkeeping (leader-state core.clj:40-42): nextIndex = last log
+    # index + 1, matchIndex = 0.
     # Log indices are capacity-bounded (config caps log_capacity): the [N, N, B]
     # bookkeeping planes and their intermediates ride int8/int16, cutting their
     # HBM cost 4x/2x vs int32. Compaction carries absolute indices: int32
@@ -760,9 +995,15 @@ def _step_b(
     match_index = jnp.where(win[:, None, :], 0, s.match_index)
 
     phase("elect")
-    # ---- phase 4.5: PreVote responses + promotion (thesis 9.6; raft.py) ----------
+    # ---- phase 4.5: PreVote responses + promotion (thesis 9.6; cfg.pre_vote) -----
+    # A pre-candidate banks grant bits in the votes bitmap (it is never a real
+    # candidate at the same time, so the bitmap is free); a pre-quorum promotes
+    # it to a REAL candidate: only now does the term bump, the self-vote land,
+    # and a real RequestVote broadcast go out (phase 8 via start_election).
     if cfg.pre_vote:
-        # Grant bits ride the packed pv_grant plane (raft.py phase 4.5).
+        # The grant bit rides the packed pv_grant plane (Mailbox docstring):
+        # AND the packed response-validity rows against it -- word algebra, no
+        # per-edge byte plane.
         pvresp = resp_in & (mb.resp_kind == RESP_PREVOTE)
         new_pv = jnp.where(
             (role == PRECANDIDATE)[:, None, :],
@@ -783,6 +1024,9 @@ def _step_b(
         pre_win = jnp.zeros_like(win)
 
     phase("replicate")  # phase 4's AppendEntries responses
+    # Append responses (append-response-handler core.clj:141-149), leaders only, same
+    # term. Success: match = acked index, next = match+1 (the reference sets next =
+    # log-index, bug 2.3.10); failure: decrement next-index and retry (core.clj:146).
     aresp = (
         resp_in
         & (mb.resp_kind == RESP_APPEND)
@@ -796,20 +1040,28 @@ def _step_b(
     ah = mb.a_hint[None, :, :]
     match_index = jnp.where(a_succ, jnp.maximum(match_index, am), match_index)
     next_index = jnp.where(a_succ, jnp.maximum(next_index, am + 1), next_index)
-    # Failure: back off to min(next-1, hint+1) (conflict-index hint; raft.py).
+    # Failure: back off to min(next-1, hint+1) -- the nack hint is the responder's
+    # log length (phase 3), so a far-behind or just-elected leader's probe
+    # converges in one round trip instead of one slot per nack.
     next_index = jnp.where(
         a_fail, jnp.maximum(jnp.minimum(next_index - 1, ah + 1), 1), next_index
     )
-    # Responsiveness ages for the shared-window filter (phase 8; see raft.py).
+    # Responsiveness ages for the shared-window filter (phase 8): everyone ages one
+    # tick (saturating); any AE response (success or failure) proves the peer is up
+    # and zeroes its age, and a fresh win grace-zeroes every peer so the first
+    # window covers all of them.
     ack_age = jnp.minimum(s.ack_age + 1, cfg.ack_age_sat)
     ack_age = jnp.where(win[:, None, :] | aresp, 0, ack_age)
 
     phase("commit")
-    # ---- phase 5: leader commit advancement --------------------------------------
+    # ---- phase 5: leader commit advancement (absent in reference, bug 2.3.8) ------
     is_leader = role == LEADER
     if dur and cfg.durable_acks:
-        # A leader's own vote for a replication quorum is its DURABLE length
-        # (raft.py phase 5: the leader's disk is a follower too).
+        # Section-3.8 gate, leader self-match side: the leader's own log
+        # counts toward commit only up to ITS durable watermark -- it is a
+        # replica like any other, and commit means "on stable storage at a
+        # quorum". Uses the pre-flush watermark (this tick's flush lands in
+        # phase 7.5): one tick of lag, never a lie.
         dmi = dur_mid.astype(len_i.dtype)
         match_with_self = jnp.where(eye_ls, dmi[:, None, :], match_index)
     else:
@@ -826,10 +1078,15 @@ def _step_b(
     #     (the CAP-threshold form would do ~6x the work at N=5, CAP=32 and ~400x at
     #     config1's CAP=2048).
     if rcf:
-        # Per-leader configuration-masked quorum match (raft.py phase 5):
-        # candidates range over the members' own match values under EACH
-        # leader's OWN derived member rows; dual (min of both configs)
-        # while that leader's prefix is joint.
+        # Configuration-masked quorum match under EACH LEADER's OWN derived
+        # configuration: the largest replicated index v such that a majority
+        # of that leader's member rows have match >= v. The quorum-th order
+        # statistic of a multiset is an element of it, so candidates range
+        # over the members' own match values (count form -- the member
+        # majority is traced data, so a static sort-and-index form cannot
+        # apply). While the leader's prefix is joint: the min over both its
+        # configs (an index commits only when replicated to majorities of
+        # BOTH).
         mws = match_with_self
         ge_m = mws[:, None, :, :] >= mws[:, :, None, :]  # [i, j(cand), k, B]
 
@@ -863,6 +1120,7 @@ def _step_b(
             ge = ge & (iota((1, 1, npd, 1), 2) < n)
         ok = jnp.sum(ge, axis=2) >= cfg.quorum  # [N, N, B]
         quorum_match = jnp.max(jnp.where(ok, match_with_self, 0), axis=1)  # [N, B]
+    # Spec 5.4.2: only commit entries from the current term by counting replicas.
     if comp:
         quorum_term = log_ops.term_at_rb(log_term_arr, base, bterm, quorum_match)
     else:
@@ -874,10 +1132,17 @@ def _step_b(
     )
 
     # ---- phase 5.2: reconfiguration transitions moved INTO the log --------------
-    # (Log-carried membership: no admin transition block. Joint entry/exit
-    # are LOG APPENDS -- phase 6 originates them, phase 3 replicates them --
-    # and each node's configuration re-derives from its own prefix at end of
-    # tick; raft.py for the full rationale.)
+    # (Log-carried membership: there is no admin transition block. Joint
+    # entry/exit are LOG APPENDS -- phase 6 originates them on the leader,
+    # phase 3 replicates them -- and each node's effective configuration is
+    # re-derived from its own prefix at end of tick (models/cfglog.py), which
+    # is also where removed-leader stepdown and the truncation rollback live.)
+    # Leadership-transfer bookkeeping (cfg.leader_transfer): abort a pending
+    # transfer whose holder lost leadership or whose target went unresponsive
+    # (ack_age horizon -- a dead target must not freeze the write path), then
+    # accept a fresh transfer command at the lowest-id live leader. The
+    # TimeoutNow itself fires from phase 8, re-fired each heartbeat while the
+    # target stays caught up (a dropped fire retries).
     if xfr:
         tgt_oh_x = iota((1, n, 1), 1) == jnp.clip(s.xfer_to, 0, n - 1)[:, None, :]
         age_t = jnp.sum(jnp.where(tgt_oh_x, ack_age, 0), axis=1)  # one-hot gather
@@ -905,20 +1170,40 @@ def _step_b(
         )
         xfer_to = jnp.where(can_x, t_x[None, :], xfer_to)
         xfer_pend = xfer_to != NIL
+    # ReadIndex lifecycle (cfg.read_index): bank this tick's AppendEntries
+    # responses into the pending read's confirmation set (responses received
+    # now were sent at or after the capture tick, so each proves the
+    # responder was in the leader's term no earlier than capture -- the
+    # staleness argument docs/PROTOCOL.md spells out), serve once a
+    # configuration-aware majority confirms, then capture a fresh offer into
+    # a free slot.
     if rdx:
-        pend0 = s.read_idx > 0  # [N, B]
-        keep_r = is_leader & pend0
+        pend0 = s.read_idx > 0  # [N, B] pending at tick start
+        keep_r = is_leader & pend0  # role loss / term adoption cancels
         read_acks = jnp.where(
             keep_r[:, None, :], s.read_acks | bitplane.pack(aresp, axis=1), zw
         )
         if cfg.read_confirm:
             serve = keep_r & inp.alive & packed_quorum(read_acks | eye_p3)
         else:
-            serve = keep_r & inp.alive  # TEST-ONLY mutant: no confirmation
+            # TEST-ONLY mutant (cfg.read_confirm False): serve with NO
+            # leadership confirmation -- a deposed leader in a minority
+            # partition serves reads from its stale commit state (the
+            # below-the-committed-frontier read the checker must reject).
+            serve = keep_r & inp.alive
         if rdl:
-            # Lease fast path on the global-tick ack_age plane; the
-            # lease_skew_safe mutant widens the window to the no-skew bound
-            # election_min_ticks + 2 (raft.py phase 5 for the argument).
+            # Lease fast path (thesis 6.4.1): a leader holding a fresh
+            # configuration quorum of AppendEntries acks -- every member
+            # acked within the lease window on the GLOBAL tick clock (the
+            # ack_age plane ages 1/tick regardless of skew; the leader's
+            # own skewable clock is never consulted) -- serves immediately,
+            # no confirmation round. The TEST-ONLY lease_skew_safe mutant
+            # widens the window to election_min_ticks + 2: the no-skew
+            # bound -- on 1:1 clocks a deposing election needs a full
+            # election_min of denial expiry plus the vote+commit round
+            # trips, and a capture must precede its serve by a tick, so the
+            # widened lease still cannot produce a stale serve; under clock
+            # skew the denial window halves in global time and it can.
             lease_w = (
                 cfg.read_lease_ticks
                 if cfg.lease_skew_safe
@@ -927,8 +1212,13 @@ def _step_b(
             fresh_p = bitplane.pack(ack_age <= lease_w, axis=1)  # [N, W, B]
             lease_ok = packed_quorum(fresh_p | eye_p3)
             if xfr:
-                # Transfer handoff covers the read path (raft.py phase 5).
+                # Transfer handoff covers the read path: once a transfer
+                # pends, the lease fast path stops -- the target's override
+                # election (req_disrupt) bypasses the 4.2.3 denial the lease
+                # bound leans on, so only reads served BEFORE the handoff
+                # may lean on it (docs/PROTOCOL.md staleness argument).
                 lease_ok = lease_ok & ~xfer_pend
+            # Reads the lease serves that no confirmed round did this tick.
             by_lease = keep_r & inp.alive & lease_ok & ~serve
             lease_reads = jnp.sum(by_lease, axis=0).astype(jnp.int32)
             serve = serve | by_lease
@@ -940,6 +1230,11 @@ def _step_b(
             iota((1, LAT_HIST_BINS, 1), 1) == bin_r[:, None, :]
         ) & serve[:, None, :]
         read_hist = jnp.sum(oh_r, axis=0).astype(jnp.int32)  # [BINS, B]
+        # Capture: gated on the leader having committed a current-term entry
+        # (thesis 6.4 -- a fresh leader's commit may trail the global
+        # committed frontier until its own no-op/first entry commits, and a
+        # read captured before that would legally miss committed writes).
+        # One offer per cluster per tick: the lowest-id eligible leader.
         if comp:
             cur_committed = (
                 log_ops.term_at_rb(log_term_arr, base, bterm, commit) == term
@@ -950,7 +1245,7 @@ def _step_b(
         if cfg.read_confirm:
             can_cap = can_cap & cur_committed
         if xfr:
-            can_cap = can_cap & ~xfer_pend
+            can_cap = can_cap & ~xfer_pend  # transferring leaders stop serving
         low_cap = jnp.min(jnp.where(can_cap, ids2, n), axis=0)  # [B]
         cap_r = can_cap & (ids2 == low_cap[None, :])
         cleared = serve | (pend0 & ~keep_r)
@@ -960,7 +1255,14 @@ def _step_b(
         )
         read_acks = jnp.where((cap_r | serve)[:, None, :], zw, read_acks)
         if rdl:
-            # Staleness anchor + device invariant (raft.py phase 5).
+            # Staleness anchor: bank the committed frontier (lat_frontier
+            # semantics, incl. this tick's phase-5 advance) at capture; a
+            # SERVE whose captured index sits below its banked frontier
+            # missed committed writes -- the checker's read_linearizability
+            # property as a device invariant, so the hunt's fitness sees
+            # lease violations. Exact, not conservative: a legitimate
+            # (confirmed or leased) leader's capture covers the frontier by
+            # the current-term-commit gate, so the real kernel never flags.
             fr_now = jnp.maximum(s.lat_frontier, jnp.max(commit, axis=0))  # [B]
             read_fr = jnp.where(
                 cap_r, fr_now[None, :], jnp.where(cleared, 0, s.read_fr)
@@ -975,15 +1277,16 @@ def _step_b(
             viol_read_stale = np.zeros((b,), np.bool_)
             lease_reads = np.zeros((b,), np.int32)
     else:
-        # Constants, not jnp.zeros: keep the disabled-mode lowered program
-        # byte-identical (see raft.py).
+        # Constants, not jnp.zeros: a zeros op would land in the lowered
+        # step program and break the zero-cost-when-off golden (byte-
+        # identical op histograms with every gate off).
         reads_served = np.zeros((b,), np.int32)
         read_lat_sum = np.zeros((b,), np.int32)
         read_hist = np.zeros((LAT_HIST_BINS, b), np.int32)
         viol_read_stale = np.zeros((b,), np.bool_)
         lease_reads = np.zeros((b,), np.int32)
 
-    # ---- offer->commit latency (client workloads only; raft.py) ------------------
+    # ---- offer->commit latency (client workloads only; offer_latency_b) ----------
     if track:
         lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier = offer_latency_b(
             log_tick_arr, is_leader & inp.alive, commit, base if comp else None,
@@ -997,7 +1300,13 @@ def _step_b(
         lat_frontier = s.lat_frontier
 
     phase("compact")
-    # ---- phase 5.5: log compaction (raft.py) -------------------------------------
+    # ---- phase 5.5: log compaction -------------------------------------------------
+    # The reference's unbounded log vector (log.clj:33) needs none; the ring must
+    # free committed slots or a long-horizon client workload would exhaust it
+    # (commands rejected forever once log_len - log_base == CAP). Policy: whenever
+    # fewer than compact_margin free slots remain, advance base toward commit so up
+    # to CAP - compact_margin entries stay retained for laggard catch-up. base_chk
+    # is extended over the newly compacted span in the checksum pass below.
     base_mid, bchk_mid = base, bchk  # post-install, pre-advance (checksum anchor)
     if comp:
         target = jnp.minimum(commit, log_len - (cap - cfg.compact_margin))
@@ -1006,7 +1315,8 @@ def _step_b(
         if rcf:
             # Fold the compacted span's config entries into the snapshot
             # context (cfglog.fold_span; anchored at the PRE-advance base,
-            # same aliasing rule as the checksum pass -- raft.py phase 5.5).
+            # same aliasing rule as the checksum pass below -- must run
+            # before phase 6 can reuse freed slots).
             phase("membership")
             bmold, bpend, bepoch = cfglog.fold_span(
                 cfg, log_cfg_arr, base, base2, bmold, bpend, bepoch,
@@ -1015,13 +1325,20 @@ def _step_b(
             phase("compact")
         base = base2
 
-    # ---- committed-prefix checksum, compaction form (raft.py: anchored at
-    # base_mid, MUST run before phase 6 -- an injection into a slot freed by this
-    # tick's rebase would alias under the anchored slot->index map; maintained
-    # even with invariant checking off, since base_chk is load-bearing wire
-    # state). The non-compaction form has no aliasing hazard and stays at its
-    # original post-outbox position (placement affects XLA fusion of the hot
-    # configs).
+    # ---- committed-prefix checksum, compaction form -------------------------------
+    # One masked pass over the post-append arrays yields the old-prefix sum
+    # (invariant: equals the carried checksum), the compacted-prefix extension, and
+    # the new-prefix sum (log_ops module comment). All sums anchor at base_mid, the
+    # base BEFORE this tick's compaction advance. This pass MUST run before phase 6:
+    # an injection into a slot freed by this very tick's rebase would otherwise be
+    # read back under the just-compacted entry's weight (base_mid-anchored slot ->
+    # absolute-index map), silently corrupting base_chk. AE writes cannot alias
+    # (they only touch entries <= base + CAP, whose anchored indices are exact).
+    # The sums are part of load-bearing snapshot state (shipped as req_base_chk,
+    # persisted in checkpoints), so under compaction they are maintained even with
+    # invariant CHECKING off -- only the chk_ok comparison is gated. The
+    # non-compaction form has no aliasing hazard and stays at its post-outbox
+    # position (placement affects XLA fusion of the hot configs).
     if comp:
         co = jnp.maximum(s.commit_index, base_mid)  # snap installs skip the check
         s_co, s_bf, s_cn = log_ops.ring_chk_b(
@@ -1035,13 +1352,23 @@ def _step_b(
         chk_new = bchk_mid + s_cn
 
     phase("client")
-    # ---- phase 6: client command injection, redirect routing, election-win
-    # no-op (raft.py phase 6) --------------------------------------------------------
+    # ---- phase 6: client command injection (client-set-handler core.clj:151-160) --
+    # Routing: with client_redirect the client POSTs one node and chases 302
+    # redirects at one tick per bounce (the reference's write path,
+    # core.clj:151-160, server.clj:62-63); otherwise the omniscient simulator
+    # client writes straight to every live leader. Under compaction, a fresh
+    # election win appends a leader NO-OP entry instead (spec 5.4.2 workaround:
+    # old-term entries only commit via a current-term entry at quorum, and a full
+    # ring of old-term entries would otherwise deadlock commit forever -- see
+    # docs/DESIGN.md); client injections keep `noop_reserve` slots free so a
+    # no-op slot survives commit-free election chains up to that depth.
     if comp:
         reserve = max(1, cfg.compact_margin // 2)
         noop = win & (log_len - base < cap)
         room = log_len - base < cap - reserve
-        # Win with no no-op room: surfaced as a liveness metric (raft.py).
+        # A win with NO room for its no-op: beyond the reserve's guarantee, the
+        # latent 5.4.2 commit-freeze the no-op exists to break -- surfaced as a
+        # liveness metric instead of stalling silently (StepInfo.noop_blocked).
         noop_blocked = gsum(
             jnp.sum(win & ~(log_len - base < cap), axis=0).astype(jnp.int32)
         )
@@ -1049,11 +1376,16 @@ def _step_b(
         noop = jnp.zeros_like(is_leader)
         room = log_len - base < cap
         noop_blocked = jnp.zeros_like(s.now)
-    # ---- config-entry origination (log-carried membership; raft.py phase 6
-    # for the full rationale: joint entry on the admin toggle, final entry
-    # once the governing joint entry commits on the leader, both judged on
-    # the leader's OWN tick-start derived configuration, sharing the
-    # one-append-per-node slot at priority no-op > config > client) ---------------
+    # ---- config-entry origination (log-carried membership, thesis 4.3) ----------
+    # Config changes are LOG WRITES sharing phase 6's one-append-per-node
+    # slot (priority: election no-op > config entry > client command), each
+    # judged on the leader's OWN tick-start derived configuration:
+    #   JOINT entry (+v+1): the admin's toggle, accepted by the lowest-id
+    #   live voter-leader, refused while that leader's prefix is already
+    #   joint or when the toggle would leave C_new below 2 voters.
+    #   FINAL entry (-v-1): appended automatically once the governing joint
+    #   entry commits on the leader (commit >= cfg_pend) -- the thesis's
+    #   "C_old,new committed -> append C_new" step.
     if rcf:
         t_r = inp.reconfig_cmd  # [B]
         tbit = bitplane.one_bit(t_r, n)  # [W, B]; all-zero column for NIL
@@ -1081,13 +1413,19 @@ def _step_b(
             ).astype(jnp.int32)
             cfg_write = accept_j | accept_f
         else:
-            # TEST-ONLY mutant (single-server change; raft.py phase 6).
+            # TEST-ONLY mutant (single-server change, cfg.joint_consensus
+            # False): one final-acting entry per change, no joint phase, no
+            # completing entry -- the known-unsafe variant.
             cfg_code = jnp.where(accept_j, t_r[None, :] + 1, 0).astype(jnp.int32)
             cfg_write = accept_j
     if cfg.client_redirect:
-        # K-deep in-flight pipeline: first free slot takes a fresh offer, at
-        # most one slot accepted per node per tick, lowest slot first
-        # (raft.py phase 6).
+        # K commands in flight (cfg.client_pipeline -- the reference's
+        # buffered(5) request channel, server.clj:37): a fresh offer takes the
+        # FIRST free slot (dropped only when all K are busy); each active slot
+        # independently chases redirects. Per node, at most ONE slot is
+        # accepted per tick -- the reference's loop dequeues one message per
+        # wait iteration -- lowest slot index first; slots targeting distinct
+        # leaders (split-brain windows) can accept in parallel.
         kdim = cfg.client_pipeline
         kk3 = iota((kdim, 1, 1), 0)  # [K, 1, 1]
         free = s.client_pend == NIL  # [K, B]
@@ -1095,7 +1433,8 @@ def _step_b(
         fresh = (inp.client_cmd != NIL)[None, :] & first_free
         pend = jnp.where(fresh, inp.client_cmd[None, :], s.client_pend)  # [K, B]
         tgt = jnp.where(fresh, inp.client_target[None, :], s.client_dst)
-        # Offer stamp rides the slot beside the payload (raft.py phase 6).
+        # Offer stamp rides the slot beside the payload: latency is measured
+        # from the OFFER tick, and the bounces happen after it.
         ptick = (
             jnp.where(fresh, (s.now + 1)[None, :], s.client_tick) if track else None
         )
@@ -1106,7 +1445,10 @@ def _step_b(
         if rcf:
             node_ok = node_ok & ~cfg_write  # the slot holds a config entry
         if xfr:
-            node_ok = node_ok & ~xfer_pend  # transfer lease handoff (raft.py)
+            # Transfer lease handoff (thesis 3.10): a transferring leader
+            # stops accepting client commands until the transfer completes
+            # or aborts.
+            node_ok = node_ok & ~xfer_pend
         client_ok = (low_k < kdim) & node_ok  # [N, B] nodes accepting a slot
         sel_k = tgt_oh & (kk3 == low_k[None, :, :]) & node_ok[None, :, :]  # [K, N, B]
         wval_cl = jnp.sum(jnp.where(sel_k, pend[:, None, :], 0), axis=0)  # [N, B]
@@ -1114,7 +1456,12 @@ def _step_b(
             jnp.sum(jnp.where(sel_k, ptick[:, None, :], 0), axis=0) if track else None
         )
         accepted_k = jnp.any(sel_k, axis=1)  # [K, B]
+        # Distinct slots hold distinct offers: the count is exact (the direct
+        # client's any() collapses split-brain double-accepts of ONE offer).
         cmds_cnt = jnp.sum(accepted_k, axis=0).astype(jnp.int32)  # [B]
+        # Redirect still-pending slots: to the target's known leader when the
+        # target is up and knows one, else to a random peer (core.clj:152-155).
+        # A rejected POST at a full leader retries there next tick.
         tgt_ld = jnp.max(jnp.where(tgt_oh, leader_id[None, :, :], NIL), axis=1)  # [K, B]
         tgt_up = jnp.any(tgt_oh & inp.alive[None, :, :], axis=1)
         pend_on = active & ~accepted_k
@@ -1130,11 +1477,15 @@ def _step_b(
         if xfr:
             client_ok = client_ok & ~xfer_pend  # transfer lease handoff
         wval_cl = jnp.broadcast_to(inp.client_cmd[None, :], (nl, b))
-        # Direct mode accepts on the offer tick: stamp = now + 1 (raft.py).
+        # Direct mode accepts on the offer tick itself: stamp = now + 1 (the
+        # same stamp the redirect pipeline records at slot entry).
         wtick_cl = (
             jnp.broadcast_to((s.now + 1)[None, :], (nl, b)) if track else None
         )
-        cmds_cnt = gany(jnp.any(client_ok, axis=0)).astype(jnp.int32)  # offers, not appends
+        # any(), not sum(): during a split-brain window two live leaders can
+        # both accept the same offered command; that is ONE offer accepted, and
+        # the offered-vs-committed audit counts offers.
+        cmds_cnt = gany(jnp.any(client_ok, axis=0)).astype(jnp.int32)
         client_pend = s.client_pend
         client_dst = s.client_dst
         client_tick = s.client_tick
@@ -1163,38 +1514,50 @@ def _step_b(
     log_len = log_len + do_write
 
     phase("elect")
-    # ---- phase 7: timers ---------------------------------------------------------
+    # ---- phase 7: timers (generate-timeout core.clj:171-174; dispatch :193-195) ----
     clock = s.clock + inp.skew
+    # Election timer resets ONLY on vote grant or valid current-term AppendEntries (or
+    # stepping down), not on every message (reference bug 2.3.11).
     reset_election = granted_any | has_ae | saw_higher
     deadline = jnp.where(reset_election, clock + inp.timeout_draw, s.deadline)
     deadline = jnp.where(win, clock + cfg.heartbeat_ticks, deadline)
     if cfg.pre_vote:
+        # A just-promoted candidate draws a fresh election timeout.
         deadline = jnp.where(pre_win, clock + inp.timeout_draw, deadline)
+    # A down node's timers cannot fire; its fresh deadline is set by the restart wipe.
     expired = (clock >= deadline) & inp.alive
 
+    # Leader heartbeat (heartbeat-handler core.clj:162-164).
     heartbeat = expired & is_leader
     deadline = jnp.where(heartbeat, clock + cfg.heartbeat_ticks, deadline)
 
+    # Follower/candidate timeout -> new election (timeout-handler core.clj:166-169,
+    # follower->candidate core.clj:69-73: term++, vote self).
     if cfg.pre_vote:
-        # Expiry starts a PRE-vote probe: no term bump, votedFor untouched
-        # (raft.py phase 7); real elections start at promotions (phase 4.5).
+        # Expiry starts a PRE-vote probe instead: no term bump, votedFor
+        # untouched (grants stay possible), the self pre-vote rides the bitmap.
+        # The REAL election start is this tick's promotions (phase 4.5).
         start_prevote = expired & ~is_leader
         if rcf:
-            # Non-voters never campaign, judged on the node's OWN derived
-            # config (raft.py phase 7: the disruption surface when a log
-            # misses its removal entry).
+            # Non-voters never campaign (the removed-node quiescence rule,
+            # judged on the node's OWN derived config: a node whose log
+            # carries its removal is a learner; one whose log misses it
+            # still campaigns -- the disruption the 4.2.3 denial absorbs).
             start_prevote = start_prevote & member_b
         if xfr:
-            start_prevote = start_prevote & ~xfer_elect  # thesis-3.10 bypass
+            # A TimeoutNow target skips the probe: its real election (below)
+            # is the thesis-3.10 pre-vote bypass.
+            start_prevote = start_prevote & ~xfer_elect
         role = jnp.where(start_prevote, PRECANDIDATE, role)
         leader_id = jnp.where(start_prevote, NIL, leader_id)
         votes = jnp.where(start_prevote[:, None, :], eye_p3, votes)
         deadline = jnp.where(start_prevote, clock + inp.timeout_draw, deadline)
         start_election = pre_win
         if xfr:
-            # TimeoutNow election (raft.py phase 7): the real-election start
-            # minus the pre-quorum; ~is_leader re-checked (a phase-4 win may
-            # have promoted the target this very tick).
+            # TimeoutNow election: real term bump + self-vote + RequestVote
+            # broadcast, exactly the promotion path minus the pre-quorum.
+            # ~is_leader re-checked: the target may have WON an ordinary
+            # election in phase 4 this very tick.
             xe = xfer_elect & ~pre_win & ~is_leader
             term = term + xe
             role = jnp.where(xe, CANDIDATE, role)
@@ -1209,6 +1572,8 @@ def _step_b(
         if rcf:
             start_election = start_election & member_b  # non-voters never campaign
         if xfr:
+            # TimeoutNow election (~is_leader re-checked: the target may have
+            # won an ordinary election in phase 4 this very tick).
             xe = xfer_elect & ~is_leader
             start_election = start_election | xe
         term = term + start_election
@@ -1219,21 +1584,37 @@ def _step_b(
         deadline = jnp.where(start_election, clock + inp.timeout_draw, deadline)
 
     phase("outbox")
-    # ---- phase 7.5: fsync flush + durability gates (raft.py phase 7.5) -----------
+    # ---- phase 7.5: fsync flush + section-3.8 durability gates -------------------
+    # The device-side fsync model (raft_sim_tpu/storage): a completed flush
+    # (inp.fsync_fire -- the cadence tick minus the per-node latency-jitter
+    # stall, sim/faults._storage_draws; a dead disk never flushes) snaps the
+    # durable snapshot to the node's FINAL live state this tick -- the
+    # post-injection log length and the post-election term/vote. Between
+    # flushes the watermark carries (clamped by truncation, dur_mid above).
     if dur:
-        fs_fire = inp.fsync_fire & inp.alive  # dead disks never flush
+        fs_fire = inp.fsync_fire & inp.alive
         dur2_len, dur2_term, dur2_vote = storage_plane.flush(
             fs_fire, dur_mid, s.dur_term, s.dur_vote, log_len, term, voted_for
         )
         if cfg.durable_acks:
-            # Gate 1 (ack durability): AE acks reflect only the fsynced
-            # prefix (raft.py phase 7.5).
+            # Gate 1 -- AE acks: the acked match index never exceeds the
+            # durable watermark. A follower behind a slow disk acks LESS
+            # than it appended (the leader's match/next simply lag; the
+            # idempotent consistency check absorbs the re-sends), so
+            # replication STALLS behind the disk instead of lying about it.
+            # The nack catch-up hint stays volatile: it is an optimization
+            # target, never counted toward commit.
             out_a_match = jnp.minimum(
                 out_a_match.astype(jnp.int32), dur2_len
             ).astype(idt)
-            # Gate 2 (vote durability): a grant is exposed only once the
-            # durable snapshot covers it; the covering flush emits the
-            # withheld response (late_grant -> outbox overlay below).
+            # Gate 2 -- vote grants: a grant is EXPOSED only once the
+            # (term, votedFor) pair it commits to is durable. covered0 vs
+            # covered2 splits "already exposed on an earlier tick" from
+            # "this tick's flush just made it durable": the latter emits a
+            # LATE vote-completion response below (phase 8) when the grant
+            # tick itself could not -- the array form of "respond after the
+            # fsync returns". A grant whose flush never lands before the
+            # candidate gives up is simply lost (like a dropped response).
             covered0 = storage_plane.covered(s.dur_term, s.dur_vote, term, voted_for)
             covered2 = storage_plane.covered(dur2_term, dur2_vote, term, voted_for)
             grant_to = jnp.where(covered2, voted_for, NIL).astype(
@@ -1242,15 +1623,16 @@ def _step_b(
             late_grant = covered2 & ~covered0 & ~granted_any
 
     # ---- phase 8: outbox ---------------------------------------------------------
-    send_append = win | heartbeat
+    send_append = win | heartbeat  # fresh leaders heartbeat immediately (core.clj:137-138)
     if comp:
         new_last_idx = log_len
         new_last_term = log_ops.term_at_rb(log_term_arr, base, bterm, log_len)
     else:
         new_last_idx, new_last_term = log_ops.last_index_term_b(log_term_arr, log_len)
 
-    # Request headers are per sender (both RPCs are broadcasts); only the AE window
-    # offset is per edge (Mailbox docstring; raft.py phase 8).
+    # Request headers are PER SENDER -- both RPCs are broadcasts (request-vote-rpc
+    # core.clj:48-54, append-entries-rpc core.clj:56-67); the only per-edge request
+    # datum is the AE window offset (Mailbox docstring).
     ae_edge = send_append[:, None, :] & ~eye_ls
     # A strong int32 0: a weak-typed req_type would flip the carry's type
     # after the first chunk and recompile every chunk program once more.
@@ -1264,11 +1646,17 @@ def _step_b(
         rv_like = start_election
     out_req_term = jnp.where(out_req_type != 0, term, 0)
     if cfg.pre_vote:
-        out_req_term = jnp.where(start_prevote, term + 1, out_req_term)  # prospective
+        # The probe carries the PROSPECTIVE term (term + 1, thesis 9.6); phase 1
+        # excludes it from adoption.
+        out_req_term = jnp.where(start_prevote, term + 1, out_req_term)
     if xfr:
-        # TimeoutNow fire (raft.py phase 8): replaces the heartbeat slot on
-        # catch-up; AE window fields stay populated (receivers gate on
-        # req_type == REQ_APPEND).
+        # TimeoutNow fire (thesis 3.10): on a heartbeat tick with a pending
+        # transfer whose target has fully matched the leader's log, the
+        # broadcast slot carries REQ_TIMEOUT_NOW instead of the heartbeat
+        # (re-fired each heartbeat while pending: a dropped fire retries; a
+        # successful one deposes this leader before the next). The AE window
+        # fields stay populated as the heartbeat would have left them --
+        # receivers gate every AE read on req_type == REQ_APPEND.
         tgt_oh8 = iota((1, n, 1), 1) == jnp.clip(xfer_to, 0, n - 1)[:, None, :]
         t_match = jnp.sum(
             jnp.where(tgt_oh8, match_index, 0), axis=1, dtype=jnp.int32
@@ -1276,21 +1664,32 @@ def _step_b(
         if cfg.xfer_election:
             caught = t_match >= log_len
         else:
-            caught = jnp.ones_like(log_len, bool)  # TEST-ONLY mutant: no wait
+            # TEST-ONLY mutant: fire without the catch-up wait (the coup
+            # receipt on the other side doesn't check the log either).
+            caught = jnp.ones_like(log_len, bool)
         fire = send_append & (xfer_to != NIL) & caught
         out_req_type = jnp.where(fire, REQ_TIMEOUT_NOW, out_req_type)
         out_xfer_tgt = jnp.where(fire, xfer_to, NIL).astype(node_dtype(cfg))
     else:
         out_xfer_tgt = mb.xfer_tgt  # NIL, loop-invariant carry component
     if xfr and (rcf or rdl):
-        # The disruptive-RequestVote override (thesis 3.10/4.2.3; raft.py
-        # phase 8): written only when a denial gate can read it.
+        # The disruptive-RequestVote override (thesis 3.10/4.2.3): a
+        # transfer-triggered election's broadcast carries the flag, so
+        # heard-recent voters still process it. Written only when a denial
+        # gate can read it; zeros and carried untouched otherwise.
         out_req_disrupt = jnp.where(xe, 1, 0).astype(jnp.int8)
     else:
         out_req_disrupt = mb.req_disrupt  # zeros, loop-invariant component
+    # AE: prev = nextIndex - 1 per edge, carried as the offset into the shared window.
     prev_out = jnp.clip(next_index - 1, 0, len_i[:, None, :])  # [src, dst, B]
-    # Shared window start: minimum prev over RESPONSIVE peers, falling back to all
-    # peers when none are (see raft.py phase 8 for the liveness argument).
+    # Shared window start: minimum prev over RESPONSIVE peers (acked an AE within
+    # ack_timeout_ticks). A peer that never acks -- crashed, partitioned away -- must
+    # not pin the window, or no live follower could ever receive entries past
+    # ws + E and commit would stall despite a live quorum. When no peer is
+    # responsive (nothing to replicate to anyway) fall back to the min over all
+    # peers. An unresponsive laggard's prev is clamped UP to ws below: spec-safe
+    # (the consistency check at the too-high prev fails, it nacks, and that nack
+    # both re-admits it to the responsive set and walks next_index back down).
     responsive = ack_age <= cfg.ack_timeout_ticks
     if comp:
         big = jnp.int32(2**31 - 1)
@@ -1317,13 +1716,20 @@ def _step_b(
         ws = jnp.maximum(jnp.where(m >= K, m - K, m), z)
     ws = jnp.minimum(ws, len_i)  # narrow dtype throughout; widened at header writes
     if comp:
-        # The window cannot start below the compaction base; peers whose prev fell
-        # below it get the InstallSnapshot sentinel (raft.py phase 8).
+        # Entries below the compaction base are gone: the window cannot start
+        # before it, and peers whose prev falls below it get the InstallSnapshot
+        # sentinel (req_off = -1) instead of a window offset.
         ws = jnp.maximum(ws, base)
         snap_edge = ae_edge & (prev_out < base[:, None, :])
-    # Clamp prev into [ws, ws+E] (see raft.py): the per-edge request payload then
-    # reduces to the offset j = prev - ws in 0..E; receivers reconstruct prev,
-    # prev_term, and n_entries from it and the per-sender header.
+    # Clamp each peer's prev into [ws, ws+E]: spec-safe in both directions (a peer
+    # ahead of the window gets a plain heartbeat over an older prefix it already
+    # has, its redundant ack absorbed by the monotone max() updates of match/next
+    # in phase 4; an unresponsive laggard's prev is lifted to ws, its nack walks
+    # next_index back down and re-admits it to the responsive set). The per-edge
+    # request payload then reduces to the offset j = prev - ws in 0..E; receivers
+    # reconstruct prev, prev_term, and n_entries from it and the per-sender header,
+    # reading prev terms from the shared window instead of a CAP-wide one-hot per
+    # edge.
     # j = clip(prev, ws, ws+E) - ws == clip(prev - ws, 0, E): the latter form
     # bounds the offset *syntactically* (Pass E), where the subtract-after-clip
     # form only bounds it relationally.
@@ -1337,6 +1743,9 @@ def _step_b(
     else:
         wt = log_ops.window_b(log_term_arr, ws, e)
         wv = log_ops.window_b(log_val_arr, ws, e)
+    # Zero unused window slots so the mailbox is canonical (receivers mask with
+    # the derived n_ent anyway, but a canonical wire format keeps trajectories
+    # bit-comparable).
     n_ship = jnp.clip(log_len - ws, 0, e)  # [N, B]
     ship_used = send_append[:, None, :] & (iota((1, e, 1), 1) < n_ship[:, None, :])
     out_ent_term = jnp.where(ship_used, wt, 0)
@@ -1352,16 +1761,20 @@ def _step_b(
     else:
         out_ent_cfg = mb.ent_cfg  # zeros, loop-invariant carry component
 
-    # Responses [receiver, responder]: the edge plane carries only the response
-    # TYPE; payloads (grant target, ack target, match, hint, term) are per
-    # responder (Mailbox response decode). The outbox is transpose-free and
+    # Responses: vr_out/ar_out are [request-sender, request-receiver], which IS the
+    # response orientation [response-receiver, responder] (the reference's resp-chan
+    # round trip, server.clj:59-60 -> client.clj:34-40); the edge plane carries only
+    # the response TYPE; payloads (grant target, ack target, match, hint, term) are
+    # per responder (Mailbox response decode). The outbox is transpose-free and
     # broadcast-free: nothing [N, N]-shaped is written beyond the offset and
     # response-kind planes, both int8.
     out_resp_kind = (
         jnp.where(vr_out, RESP_VOTE, 0) + jnp.where(ar_out, RESP_APPEND, 0)
     ).astype(jnp.int8)
     if cfg.pre_vote:
-        # The grant bit rides the packed pv_grant plane (raft.py phase 8).
+        # Pre-vote responses overlay the same plane; the grant BIT rides the
+        # packed pv_grant plane (one voter may grant several probes per tick,
+        # so it is genuinely per-edge -- Mailbox docstring).
         out_resp_kind = out_resp_kind + jnp.where(pv_out, RESP_PREVOTE, 0).astype(
             jnp.int8
         )
@@ -1374,8 +1787,12 @@ def _step_b(
     else:
         out_pv_grant = mb.pv_grant  # zeros, loop-invariant carry component
     if dur and cfg.durable_acks:
-        # Late vote-completion response (phase 7.5 gate 2; raft.py for the
-        # full argument and the AE-response collision guard).
+        # Late vote-completion response (phase 7.5 gate 2): the flush that
+        # just made this voter's grant durable emits the RESP_VOTE edge the
+        # grant tick withheld -- toward the recorded candidate, only where
+        # the edge carries no response already (a candidate that won
+        # meanwhile is heartbeating us; its AE response outranks the vote it
+        # no longer needs). v_to already names the candidate via covered2.
         vfc = jnp.clip(voted_for, 0, n - 1)
         late_edge = (ids2[:, :, None] == vfc[None, :, :]) & late_grant[None, :, :]
         out_resp_kind = jnp.where(
@@ -1401,7 +1818,7 @@ def _step_b(
         ent_val=out_ent_val,
         ent_tick=out_ent_tick,
         # Without compaction the snapshot header is dead weight: pass the zeros
-        # through untouched so XLA sees a loop-invariant carry component (raft.py).
+        # through untouched so XLA sees a loop-invariant carry component.
         req_base=jnp.where(send_append, base, 0) if comp else mb.req_base,
         req_base_term=jnp.where(send_append, bterm, 0) if comp else mb.req_base_term,
         req_base_chk=(
@@ -1448,7 +1865,14 @@ def _step_b(
             chk_ok = jnp.ones_like(s.commit_index, dtype=bool)
         phase("outbox")
 
-    # ---- end-of-tick config derivation (log-carried membership; raft.py) ---------
+    # ---- end-of-tick config derivation (log-carried membership) ------------------
+    # Each node's effective configuration recomputed from its post-append,
+    # post-compaction log prefix (models/cfglog.py): apply-on-append and
+    # roll-back-on-truncation are the SAME recomputation -- a truncated
+    # config entry simply stops existing for the next tick's quorums.
+    # base_mold/base_pend/base_epoch initialize to the boot config
+    # (types.init_state) and are carried untouched without compaction, so
+    # they are always the valid context at `base`.
     if rcf:
         phase("membership")
         d_mold, d_mnew, d_pend, d_epoch, d_hi = cfglog.derive(
@@ -1456,13 +1880,19 @@ def _step_b(
             batched=True,
         )
         if not cfg.truncation_rollback:
-            # TEST-ONLY mutant (ignore-truncation-rollback; raft.py).
+            # TEST-ONLY mutant (ignore-truncation-rollback): where the
+            # prefix LOST config entries, keep acting on the stale carried
+            # configuration -- the dissertation's rollback rule skipped.
             rolled = d_epoch < s.cfg_epoch
             d_mold = jnp.where(rolled[:, None, :], s.member_old, d_mold)
             d_mnew = jnp.where(rolled[:, None, :], s.member_new, d_mnew)
             d_pend = jnp.where(rolled, s.cfg_pend, d_pend)
             d_epoch = jnp.where(rolled, s.cfg_epoch, d_epoch)
-        # Removed-server stepdown + candidacy kill (raft.py end-of-tick).
+        # Removed-server stepdown (thesis 4.3): a LEADER whose own config
+        # union excludes it keeps leading -- replicating the very entry
+        # that removes it -- until that entry commits on it, then steps
+        # down (its log never counts toward masked quorums meanwhile: the
+        # caretaker role). Candidacies of removed nodes die immediately.
         self_in = jnp.any(((d_mold | d_mnew) & eye_p3) != 0, axis=1)  # [N, B]
         is_cand = (role == CANDIDATE) | (role == PRECANDIDATE)
         demote = ~self_in & (
@@ -1559,12 +1989,18 @@ def _step_info_b(
     lease_reads: jax.Array,
     sh: NodeShardCtx | None = None,
 ) -> StepInfo:
-    """Batched phase 9; see raft._step_info. All outputs [B]."""
+    """Phase 9: on-device safety invariants + observability reductions. All
+    outputs [B]."""
     n = cfg.n_nodes
     b = new.role.shape[-1]
     iota = log_ops.iota
     is_leader = new.role == LEADER
-    live_leader = is_leader & alive  # see raft._step_info: leadership metrics are live-only
+    # Observability counts only *live* leaders: a crashed node frozen in LEADER role
+    # provides no leadership (the cluster is leaderless until re-election), and the
+    # north-star ticks-to-stable-leader metric must reflect that. The safety checks
+    # below keep the unmasked roles: a frozen stale leader still participates in the
+    # at-most-one-leader-per-term invariant.
+    live_leader = is_leader & alive
     f = jnp.zeros((b,), bool)
     if sh is None:
         eye3 = iota((n, n, 1), 0) == iota((n, n, 1), 1)
@@ -1578,6 +2014,7 @@ def _step_info_b(
         gany = lambda x: lax.psum(x.astype(jnp.int32), sh.axis) > 0
 
     if cfg.check_invariants:
+        # Election safety: at most one leader per term (Raft fig. 3).
         if sh is None:
             pair_bad = (
                 is_leader[:, None, :]
@@ -1601,8 +2038,12 @@ def _step_info_b(
                 )
             )
         viol_election = jnp.any(pair_bad, axis=(0, 1))
-        # Committed-prefix immutability via the carried checksum (raft._step_info),
-        # plus the compaction bounds (base <= commit, retained window <= CAP).
+        # Commit sanity: monotonic, within the log, above the compaction base (with
+        # the retained window inside the ring), and the committed prefix is
+        # immutable -- entries below the old commit index never change term OR value
+        # (state-machine-safety analogue of the reference's apply-entries! writing
+        # committed values to an append-only file, log.clj:69-76). Immutability is
+        # checked via the carried prefix checksum (chk_ok; log_ops module comment).
         viol_commit = gany(
             jnp.any(
                 (new.commit_index < old.commit_index)
@@ -1620,6 +2061,10 @@ def _step_info_b(
     if cfg.check_log_matching:
 
         def _check(_):
+            # Log matching on committed prefixes: any two nodes agree on every
+            # entry (term AND value) up to m = min(commit_i, commit_j).
+            # O(N^2 * CAP) -- gated, and sampled every log_matching_interval
+            # ticks (below).
             minc = jnp.minimum(
                 new.commit_index[:, None, :], new.commit_index[None, :, :]
             )
@@ -1629,10 +2074,14 @@ def _step_info_b(
             if not cfg.compaction:
                 both = iota((1, 1, cfg.log_capacity, 1), 2) < minc[:, :, None, :]
                 return jnp.any(both & differ, axis=(0, 1, 2)), jnp.zeros_like(new.now)
-            # Ring form (see raft._step_info): slots live in BOTH rings over
-            # (max base, min commit] compare directly; the shared prefix below
-            # max(base_i, base_j) compares via checksums-at-mb; incomparable
-            # pairs are counted (lm_skipped_pairs).
+            # Ring form, in two parts per pair (i, j) with mb = max(base_i, base_j):
+            # entries in (mb, m] are live in BOTH rings at the same slot (same
+            # absolute index, same CAP) -> compare slots; the prefix up to mb is
+            # compared via checksums-at-mb (chk_at(i, p) = base_chk_i + live sum
+            # (base_i, p]), which is computable because mb >= base_i. Pairs where
+            # one node compacted past the other's commit (m < mb) are skipped --
+            # their agreement is pinned transitively through common peers -- and
+            # COUNTED (StepInfo.lm_skipped_pairs) so the coverage is measured.
             cap = cfg.log_capacity
             bb = new.log_base  # [N, B]
             sl = iota((1, cap, 1), 1)
@@ -1685,7 +2134,9 @@ def _step_info_b(
         viol_match, lm_skipped = f, jnp.zeros_like(new.now)
 
     leader = gmin(jnp.min(jnp.where(live_leader, ids1, n), axis=0))  # [B]
-    # Plane counters (raft._step_info): host zeros while their gate is off.
+    # Plane counters, host zeros while their gate is off (the read metrics'
+    # zero-cost contract): a node in a joint configuration at end of tick,
+    # TimeoutNow requests sent this tick.
     if cfg.reconfig:
         joint_ticks = gany(jnp.any(new.cfg_pend > 0, axis=0)).astype(jnp.int32)
     else:
@@ -1720,7 +2171,10 @@ def _step_info_b(
                 jnp.int32
             )
         ),
-        cmds_injected=cmds_cnt,  # offers accepted, not appends; see raft.py phase 6
+        # Offers accepted this tick, not appends: the direct client collapses
+        # split-brain double-accepts of one offer via any(); the redirect
+        # pipeline counts accepted slots (distinct offers) -- see phase 6.
+        cmds_injected=cmds_cnt,
         lat_sum=lat_sum,
         lat_cnt=lat_cnt,
         lat_hist=lat_hist,

@@ -153,7 +153,7 @@ def prefix_chk2_b(log_term, log_val, upto_a, upto_b):
 # (i - 1) mod CAP; live slots hold entries (log_base, log_len] with
 # log_len - log_base <= CAP (types.ClusterState). Entries at or below log_base exist
 # only as (log_base, base_term, base_chk). With log_base == 0 every ring form
-# degenerates to its prefix counterpart bit-for-bit; the kernels still call the
+# degenerates to its prefix counterpart bit-for-bit; the kernel still calls the
 # prefix forms for non-compaction configs so those stay mod-free.
 # --------------------------------------------------------------------------------------
 
@@ -244,8 +244,7 @@ def ring_chk(log_term, log_val, base, uptos: tuple):
 def log2_bin(v: jax.Array, n_bins: int) -> jax.Array:
     """Elementwise floor(log2(v)) clamped to [0, n_bins): the latency
     histogram's bin index (types.LAT_HIST_BINS semantics), via an unrolled
-    binary bit-length reduction -- no float log in any hot loop. Three call
-    sites: raft.py's commit-latency and read-latency histograms and
+    binary bit-length reduction -- no float log in any hot loop. Call site:
     raft_batched.py's read-latency histogram. raft_batched.offer_latency_b
     counts the same bins by thresholds (v >= 2^k) without calling it, held
     equal by tests/test_latency_accounting.py: a binning change edits both.
@@ -328,8 +327,8 @@ def write_window_b(
     gate: jax.Array,
     count: jax.Array,
 ) -> jax.Array:
-    """Batched write_window, restricted to the contiguous-prefix writes the kernels
-    actually do: where `gate[n, b]`, write vals[n, k, b] into arr[n, start0 + k, b]
+    """Batched write_window, restricted to the contiguous-prefix writes the kernel
+    actually does: where `gate[n, b]`, write vals[n, k, b] into arr[n, start0 + k, b]
     for k < count[n, b]. arr: [N, CAP, B]; start0/gate/count: [N, B]; vals: [N, E, B].
 
     Taking (gate, count) instead of a free-form [N, E, B] mask makes the old

@@ -39,8 +39,8 @@ tests/test_constants.py):
   req_off     -1 .. E  (bias +1) -> bits_for(E + 2)
   resp_kind    0 .. 3 (RESP_*)   -> 2
 
-The layout is PHYSICAL only: both kernels unpack to the dense planes at tick
-entry and repack at exit (models/raft.py / models/raft_batched.py), so the
+The layout is PHYSICAL only: the kernel unpacks to the dense planes at tick
+entry and repacks at exit (models/raft_batched.step_b), so the
 protocol logic -- and every trajectory -- is bit-identical to the dense
 layout (tests/test_tile.py pins dense == compacted across word-boundary N).
 Carry legs whose structural gate is off are passed through UNTOUCHED via
@@ -275,7 +275,7 @@ def pack_state(cfg: RaftConfig, dense, reuse=None):
 
 
 def unpack_state(cfg: RaftConfig, s):
-    """Compacted carry form -> dense ClusterState (the kernels' working
+    """Compacted carry form -> dense ClusterState (the kernel's working
     view). Exact inverse of `pack_state` for in-range values."""
     reps = {
         f: _unpack_leg(getattr(s, f), mode, shape, bits, bias, dt)
@@ -289,7 +289,7 @@ def unpack_state(cfg: RaftConfig, s):
 
 
 def unpack_inputs(cfg: RaftConfig, inp):
-    """Compacted StepInputs -> the kernels' dense view: the packed delivery
+    """Compacted StepInputs -> the kernel's dense view: the packed delivery
     mask ships flat ([N*W] uint32, sim/faults.py) and reshapes back to the
     [N, W] word plane here."""
     n = cfg.n_nodes

@@ -284,7 +284,7 @@ def to_raw(genome: ScenarioGenome) -> dict:
 # reproduces the old trajectory exactly: pre-v22 artifacts predate the
 # reconfiguration-plane cadences and pre-v25 artifacts the disk-fault axes;
 # an absent cadence/threshold decodes as its disabled value (0 -- disabled
-# streams draw nothing the kernels consume) and an absent torn_span as the
+# streams draw nothing the kernel consumes) and an absent torn_span as the
 # no-op span floor 1 (validate() requires span >= 1; with the torn threshold
 # 0 it is never consumed). CORE fields stay strict: a missing one is artifact
 # corruption and must raise, not silently replay a different scenario.

@@ -9,7 +9,7 @@ quorum-off-by-one kernel to an election-safety violation, the hunt is
 broken, not the kernel. Never instantiate these outside tests/demos; the
 class is deliberately NOT reachable from RaftConfig flags or scenario files.
 
-The weakening rides the config (cfg.quorum feeds both kernels' vote counts
+The weakening rides the config (cfg.quorum feeds the kernel's vote counts
 and commit rule), so no second kernel source exists to drift: the mutant
 compiles the same step code at a different quorum literal -- one extra jit
 compile, zero extra lowered program structures (literal-blind hashes equal;

@@ -71,7 +71,9 @@ def _single_cluster(cfg: RaftConfig, seed: int, batch: int, cluster: int):
 @functools.lru_cache(maxsize=8)
 def _replay_fn(cfg: RaftConfig, n_ticks: int, seg_len: int):
     """One jitted traced single-cluster scenario replay per (cfg, horizon,
-    seg_len): every ablation/halving trial reuses it (genomes are traced)."""
+    seg_len): every ablation/halving trial reuses it (genomes are traced).
+    `scan.run` ticks through the fleet's own tick body at B = 1, so a replay
+    follows the trajectory the search's batched run measured."""
     return jax.jit(
         lambda s, k, g: scan.run(
             cfg, s, k, n_ticks, trace_states=True, genome=g, seg_len=seg_len

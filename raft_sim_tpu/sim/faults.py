@@ -165,8 +165,8 @@ def _admin_cmds(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
     # broadcast a Python int into a real [B] array anyway -- no saving) and
     # the analyzer's eval_shape pricing (which needs shaped leaves). Cost:
     # the Pass C input-accounting prices 3x int32 = 12 B/cluster-tick on
-    # every tier; the VALUES are loop constants XLA folds, and the kernels
-    # never read them when the gate is off (the carry stays untouched).
+    # every tier; the VALUES are loop constants XLA folds, and the kernel
+    # never reads them when the gate is off (the carry stays untouched).
     nil = jnp.int32(NIL)
     if traced or cfg.reconfig:
         on = (rcfg_i > 0) & (now % jnp.maximum(rcfg_i, 1) == 0) & (now > 0)
@@ -198,7 +198,7 @@ def _storage_draws(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
     torn-tail write: the extra entries (1..span, uniform) a restart's tail
     checksum rejects beyond the un-fsynced suffix -- drawn every tick from
     the dedicated stream so the draw sequence is schedule-independent, and
-    consumed by the kernels only on restart ticks. `fs_i`/`span` are Python
+    consumed by the kernel only on restart ticks. `fs_i`/`span` are Python
     ints on the scalar path (statically gated) and traced genome data on the
     scenario path; `jit_t`/`torn_t` are uint32 thresholds either way. The
     fold_in(tkey, 7) stream is disjoint from the client-routing (3) and
@@ -217,7 +217,7 @@ def _storage_draws(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
     # defaults -- the dtype-comment contract fixes the rank per field, and
     # these leaves flow through vmap/eval_shape like the admin commands
     # above (Pass C prices ~5N B/cluster-tick of dead input on every tier;
-    # the kernels never read them when the gate is off).
+    # the kernel never reads them when the gate is off).
     return jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32)
 
 
@@ -390,8 +390,8 @@ def make_inputs(
 
         # Client commands: value = tick at injection + 1 -- a deterministic,
         # human-readable payload choice, nothing more. Since the v21 decoupling
-        # the commit-latency metric reads the offer-tick PLANE the kernels
-        # stamp at injection (ClusterState.log_tick), never the value: any
+        # the commit-latency metric reads the offer-tick PLANE the kernel
+        # stamps at injection (ClusterState.log_tick), never the value: any
         # int32 payload is legal (serve/ingest.py check_value), and a served
         # offer plane replaying this cadence is bit-exact with it
         # (tests/test_serve.py). Payload bytes carry no protocol meaning in

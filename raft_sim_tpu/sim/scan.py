@@ -1,4 +1,5 @@
-"""The jit-compiled tick loop: `lax.scan` over raft.step with on-device metrics.
+"""The jit-compiled tick loop: `lax.scan` over models/raft_batched.step_b with
+on-device metrics.
 
 This replaces the reference's blocking event loop -- `loop [node (init-node id)] (recur
 (wait system node))` (core.clj:202-203) -- with a single compiled scan. Where the
@@ -7,8 +8,10 @@ reference's observability is an unconditional println of node + message per iter
 the scan carry, and trace modes optionally stack per-tick `StepInfo` or full states for
 host-side inspection.
 
-Everything is written for ONE cluster and lifted over the batch axis with `vmap`
-(`run_batch`); sharding across chips happens one level up, in `raft_sim_tpu.parallel`.
+Every loop ticks through ONE body, `tick_batch_minor`: the fleet scan
+(`run_batch_minor`), the chunked and served loops, and the single-cluster traced
+scan (`run`, at B = 1). Sharding across chips happens one level up, in
+`raft_sim_tpu.parallel`.
 """
 
 from __future__ import annotations
@@ -20,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from raft_sim_tpu.models import raft
 from raft_sim_tpu.obs.scopes import tick_scope
 from raft_sim_tpu.sim import faults
 from raft_sim_tpu.types import NIL, ClusterState, StepInfo
@@ -103,7 +105,7 @@ class RunMetrics(NamedTuple):
 
 
 def init_metrics_batch(batch: int) -> RunMetrics:
-    """Zeroed RunMetrics with a leading [batch] axis (the run_batch/driver carry)."""
+    """Zeroed RunMetrics with a leading [batch] axis (the fleet/driver carry)."""
     return jax.vmap(lambda _: init_metrics())(jnp.arange(batch))
 
 
@@ -140,7 +142,7 @@ def init_metrics() -> RunMetrics:
 
 
 def _host_zero(x) -> bool:
-    """True for a host-side constant zero StepInfo leaf: the kernels emit np
+    """True for a host-side constant zero StepInfo leaf: the kernel emits np
     constants (never jnp.zeros, which would lower an op) for metrics whose
     structural gate is off, and skipping the fold keeps the corresponding
     RunMetrics carry leg loop-invariant -- XLA elides it from the per-tick
@@ -167,7 +169,7 @@ def step_bad(info):
     violations fold (metric accumulation, telemetry windows, the serve loop's
     first-violation tick). viol_read_stale joins the classic three only when
     its gate emitted a real array (cfg.read_lease AND check_invariants) --
-    the kernels emit a host-constant zero otherwise, and skipping the fold
+    the kernel emits a host-constant zero otherwise, and skipping the fold
     (a HOST predicate, like _add_gated's) keeps disabled-mode programs
     byte-identical."""
     bad = info.viol_election_safety | info.viol_commit | info.viol_log_matching
@@ -221,17 +223,21 @@ def run(
     genome=None,
     seg_len: int = 1,
 ):
-    """Scan one cluster forward `n_ticks`. Returns (final_state, metrics, outs) where
-    `outs` is None, stacked StepInfo (trace=True), or (StepInfo, stacked states)
-    (trace_states=True). `genome` (a ScenarioGenome with `[S]` leaves; `seg_len`
-    static) switches input generation to the scenario path (sim/faults.py) --
-    the step kernel itself is untouched."""
+    """Scan one cluster forward `n_ticks` through `tick_batch_minor` at
+    B = 1 (the tick body every loop runs). Returns (final_state, metrics,
+    outs) in the public layout without a batch axis, where `outs` is None,
+    stacked StepInfo (trace=True), or (StepInfo, stacked states)
+    (trace_states=True). `genome` (a ScenarioGenome with `[S]` leaves;
+    `seg_len` static) switches input generation to the scenario path
+    (sim/faults.py) -- the step kernel itself is untouched."""
+    from raft_sim_tpu.models import raft_batched
+
+    lead = lambda tree: jax.tree.map(lambda x: x[None], tree)  # a batch of one
+    keys, genome = lead((key, genome))
 
     def body(carry, _):
         s, m = carry
-        inp = faults.make_inputs(cfg, key, s.now, genome=genome, seg_len=seg_len)
-        s2, info = raft.step(cfg, s, inp)
-        m2 = _accumulate(m, info, s.now)
+        s2, m2, info = tick_batch_minor(cfg, s, keys, m, genome=genome, seg_len=seg_len)
         if trace_states:
             out = (info, s2)
         elif trace:
@@ -240,26 +246,10 @@ def run(
             out = None
         return (s2, m2), out
 
-    (final, metrics), outs = lax.scan(body, (state, init_metrics()), None, length=n_ticks)
+    carry0 = raft_batched.to_batch_minor(lead((state, init_metrics())))
+    carry, outs = lax.scan(body, carry0, None, length=n_ticks)
+    (final, metrics), outs = jax.tree.map(lambda x: x[..., 0], (carry, outs))
     return final, metrics, outs
-
-
-def run_batch(
-    cfg: RaftConfig,
-    state: ClusterState,
-    keys: jax.Array,
-    n_ticks: int,
-    trace: bool = False,
-    genome=None,
-    seg_len: int = 1,
-):
-    """vmap'd `run` over the leading batch axis of `state` / `keys` (and, when
-    given, the `[B, S]` genome rows -- one private fault setting per cluster)."""
-    if genome is None:
-        return jax.vmap(lambda s, k: run(cfg, s, k, n_ticks, trace=trace))(state, keys)
-    return jax.vmap(
-        lambda s, k, g: run(cfg, s, k, n_ticks, trace=trace, genome=g, seg_len=seg_len)
-    )(state, keys, genome)
 
 
 def run_batch_minor(
@@ -271,11 +261,11 @@ def run_batch_minor(
     genome=None,
     seg_len: int = 1,
 ):
-    """Batch-minor hot path: same trajectories as `run_batch` (bit-for-bit; see
-    tests/test_batched_parity.py) via models/raft_batched.step_b, with the batch axis
+    """Batch-minor hot path: every cluster follows the trajectory `run` gives
+    it alone (bit-for-bit; see tests/test_batched_parity.py), with the batch axis
     transposed to minor once at entry/exit so every per-tick array is TPU-tiled with
     the batch on the 128-lane dimension. State in/out keeps the public [B, ...]-leading
-    convention. No per-tick trace output (use run_batch for tracing).
+    convention. No per-tick trace output (use `run` for tracing).
 
     `step_fn(cfg, state_minor, inputs_minor)` overrides the tick kernel (the Pallas
     engine passes its kernelized step here so both engines share one scan body).
@@ -318,8 +308,9 @@ def tick_batch_minor(
     """ONE tick of the batch-minor path: input generation, step, metric
     accumulation. `s` is batch-minor; `keys` keep their [B]-leading layout (input
     draws are vmapped batch-leading, then transposed). The single shared tick body
-    for the scan loop above AND interactive single-tick drivers (Session.offer),
-    so the two can never drift. `client_cmd` overrides the scheduled client input
+    for every scan loop (`run`, `run_batch_minor`, the chunked, telemetry and
+    served loops) AND interactive single-tick drivers (Session.offer), so none
+    of them can drift. `client_cmd` overrides the scheduled client input
     for this tick. Returns (state, metrics, StepInfo) -- the per-tick info rides
     batch-minor ([B] scalars, [BINS, B] histogram); callers that only need the
     carry drop it (XLA dead-code-eliminates the unused output).
@@ -396,7 +387,7 @@ def simulate(cfg: RaftConfig, seed, batch: int, n_ticks: int):
     """One-call batched simulation from a seed: init + scan, fully on device.
 
     Returns (final_state, RunMetrics) with leading batch axis. Uses the batch-minor
-    hot path (same trajectories as run_batch, bit-for-bit).
+    hot path (each cluster bit-for-bit what `run` gives it alone).
     """
     root = jax.random.key(seed)
     k_init, k_run = jax.random.split(root)

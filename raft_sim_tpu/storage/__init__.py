@@ -6,7 +6,7 @@ with no fsync discipline, and its restart path forgets term/vote (SURVEY.md
 disk where every write is durable the instant it happens -- so the whole
 class of durability failures the dissertation's section 3.8 persistence
 requirements exist to prevent was inexpressible. This subsystem makes
-persistence explicit, as three rules both kernels state through
+persistence explicit, as three rules the kernel states through
 `storage.plane` and the scalar oracle restates independently
 (tests/oracle.py):
 

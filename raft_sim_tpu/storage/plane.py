@@ -1,11 +1,9 @@
-"""The durability rules as elementwise lattices -- ONE statement for both
-kernels.
+"""The durability rules as elementwise lattices -- ONE statement of them.
 
 Every rule here is a pure elementwise `jnp.where` lattice over per-node
-leaves, so the same functions serve the single-cluster kernel's `[N]`
-orientation and the batch-minor kernel's `[N, B]` (models/raft.py /
-raft_batched.py): broadcasting does the layout work, and the two kernels
-cannot drift on the semantics. The scalar oracle (tests/oracle.py)
+leaves, so the same functions serve a single cluster's `[N]` orientation and
+the batch-minor kernel's `[N, B]` (models/raft_batched.py): broadcasting
+does the layout work. The scalar oracle (tests/oracle.py)
 deliberately does NOT import this module -- it restates the rules in
 host-side numpy so kernel/oracle parity remains an independent check, not a
 tautology. The package docstring (storage/__init__.py) is the prose

@@ -8,8 +8,8 @@ Jepsen-style checkable history. Three load-bearing pieces:
   events.py   device-side event extraction: a compact per-cluster protocol
               event stream (role transitions, term bumps, votes, commit
               advances, log appends/truncations, crash/restart/drop/partition
-              fault events) derived from state deltas the kernels already
-              compute -- the extraction never touches the trajectory.
+              fault events) derived from state deltas the kernel already
+              computes -- the extraction never touches the trajectory.
   ring.py     the bounded per-cluster event buffer carried in the telemetry
               scan and drained every window (generalizing sim/telemetry.py's
               violation-frozen flight recorder into an always-recordable,

@@ -1,6 +1,6 @@
 """Whole-history checker: the five Raft safety properties over a complete run.
 
-The per-tick `viol_*` flags (models/raft.py phase 9) check each property's
+The per-tick `viol_*` flags (models/raft_batched.py phase 9) check each property's
 INSTANTANEOUS form -- two leaders this tick, a mutated prefix this tick. The
 Raft paper states them as HISTORY claims (fig. 3), and some violations only
 exist as history: two leaders elected for one term three windows apart never

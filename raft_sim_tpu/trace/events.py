@@ -15,17 +15,16 @@ kind, detail) fields per emitted event. The WITHIN-TICK ordering is
 NUMBERING is load-bearing for the checker: role-transition kinds come before
 commit/append/truncate kinds so that a node which loses leadership and
 accepts entries in the same tick is processed as "stepped down, then
-truncated" -- matching the kernel's phase order (models/raft.py phase 1
-adoption precedes phase 3 append) -- and fault kinds come last. The checker
+truncated" -- matching the kernel's phase order (models/raft_batched.py
+phase 1 adoption precedes phase 3 append) -- and fault kinds come last. The checker
 (trace/checker.py) replays events in exactly this order.
 
-Extraction is delta-based on purpose: both kernels (models/raft.py and
-models/raft_batched.py) produce the same ClusterState leaves, so ONE
-extractor serves both (and any step_fn override, e.g. the weak-quorum test
-mutant) without either kernel changing. The leaves read here -- role, term,
-voted_for, commit_index, log_len, and (durable storage plane) the
-dur_len/dur_term/dur_vote watermarks -- are the delta contract the kernels
-document; everything is elementwise over the node axis, so the same code
+Extraction is delta-based on purpose: ONE extractor serves the tick kernel
+(models/raft_batched.py) and any step_fn override (e.g. the weak-quorum
+test mutant) without the kernel changing. The leaves read here -- role,
+term, voted_for, commit_index, log_len, and (durable storage plane) the
+dur_len/dur_term/dur_vote watermarks -- are the delta contract the kernel
+documents; everything is elementwise over the node axis, so the same code
 runs on single-cluster [N] leaves and batch-minor [N, B] leaves.
 """
 
@@ -272,7 +271,7 @@ def extract(
         (burst, dropped),
     )
     # Reconfiguration-plane kinds, delta-derived like everything else (the
-    # serve-vs-cancel disambiguation rides the kernels' documented clear
+    # serve-vs-cancel disambiguation rides the kernel's documented clear
     # rules: a slot dropped while its holder stays a same-term leader was
     # SERVED; every cancel path -- role loss, term adoption, restart --
     # changes role/term or sets `restarted`). Structurally gated configs

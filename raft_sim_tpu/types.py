@@ -4,9 +4,11 @@ The reference keeps per-node state in a Clojure map (init-node, core.clj:31-38) 
 log atom {:entries [{:term,:val}] :commit-index} (log.clj:33-34), and exchanges messages
 as JSON over HTTP with core.async channels as mailboxes (server.clj:37, client.clj:18).
 
-Here one *cluster* is a pytree of dense arrays over the node axis N; `vmap` lifts every
-shape to [batch, N, ...]. Messages live in a dense [N, N] mailbox -- one in-flight slot
-per directed edge, indexed [dst, src] -- replacing the reference's buffered(5) channels.
+Here one *cluster* is a pytree of dense arrays over the node axis N; a fleet's public
+layout puts the batch first ([batch, N, ...]), and the tick kernel carries it last
+(models/raft_batched.to_batch_minor). Messages live in a dense [N, N] mailbox -- one
+in-flight slot per directed edge, indexed [dst, src] -- replacing the reference's
+buffered(5) channels.
 Overwriting an undelivered slot is a legal drop (the reference drops on any HTTP
 exception, client.clj:38-40), and requests/responses occupy separate mailboxes because a
 request sent at tick t is handled at t+1 and its response lands at t+2, mirroring the
@@ -26,7 +28,7 @@ means "no entry", log.clj:20-23).
 The `# [shape] dtype` comment on every field below is a CHECKED CONTRACT, not
 decoration: the static analyzer parses them (analysis/policy.py, rule
 `dtype-comment`) and verifies shape rank and dtype against the structures
-init_state/make_inputs/step actually build, across the policy tiers (the
+init_state/make_inputs/step_one actually build, across the policy tiers (the
 index_dtype/ack_dtype functions here ARE the policy). Keep them parseable --
 leading `[dims] dtype` (or `scalar dtype`), with `/`-separated alternatives
 resolved through the named policy function.
@@ -92,7 +94,7 @@ NIL = -1  # nil node id
 LAT_HIST_BINS = 16
 # Log value of a leader no-op entry (compaction only): appended on election win so
 # a current-term entry exists to pull old-term entries through the spec-5.4.2
-# commit gate (models/raft.py phase 6). Reserved: client commands may not use it.
+# commit gate (models/raft_batched.py phase 6). Reserved: client commands may not use it.
 NOOP = -2
 
 # log_capacity ceiling for int8 index planes: the single-pass window-start min
@@ -124,7 +126,7 @@ def index_dtype(cfg: RaftConfig):
 
 
 # n_nodes ceiling for int8 node-id wire fields (Mailbox xfer_tgt/v_to/a_ok_to and
-# the kernels' grant_to/a_ok_to casts): ids 0..n-1 plus the NIL = -1 sentinel and
+# the kernel's grant_to/a_ok_to casts): ids 0..n-1 plus the NIL = -1 sentinel and
 # the `n` sentinel the min-select patterns use must all fit the dtype. Derived
 # (utils/config.max_nodes_for, shared with analysis Pass E): 127 - 1 = 126
 # keeps n itself (the sentinel) a valid int8 value with a slot to spare.
@@ -218,7 +220,7 @@ class Mailbox(NamedTuple):
     # state (lastIncludedIndex/-Term + the checksum of the compacted prefix). An
     # edge whose req_off is the SNAP sentinel -1 is an InstallSnapshot analogue:
     # the receiver installs (req_base, req_base_term, req_base_chk) instead of
-    # appending entries (models/raft.py phase 3).
+    # appending entries (models/raft_batched.py phase 3).
     req_base: jax.Array  # [N] int32: sender's log_base (snapshot lastIncludedIndex)
     req_base_term: jax.Array  # [N] int32: snapshot lastIncludedTerm
     req_base_chk: jax.Array  # [N] uint32: checksum of the compacted prefix
@@ -289,7 +291,7 @@ class ClusterState(NamedTuple):
     # int32. Under cfg.compact_planes the CARRY form of these planes (and of
     # req_off/resp_kind/votes/the entry windows/the delivery mask) is the
     # bit-packed flat uint32 layout of ops/tile.py; the comments below state
-    # the dense contract the kernels compute on (tile.unpack_state at tick
+    # the dense contract the kernel computes on (tile.unpack_state at tick
     # entry, pack_state at exit -- bit-identical trajectories either way).
     next_index: jax.Array  # [N, N] index_dtype in [1, cap+1]; leader i's next index for peer j
     match_index: jax.Array  # [N, N] index_dtype in [0, cap]
@@ -704,7 +706,7 @@ def init_state(cfg: RaftConfig, key: jax.Array) -> ClusterState:
         # Compacted carry layout (ops/tile.py): the per-edge value planes
         # ride bit-packed flat uint32 legs, the narrow word/window planes
         # ride flattened. The field comments above document the DENSE
-        # contract (the kernels' working view; the layout tiers are priced
+        # contract (the kernel's working view; the layout tiers are priced
         # by Pass C, not re-declared here).
         from raft_sim_tpu.ops import tile
 

@@ -139,7 +139,7 @@ class RaftConfig:
     # workloads never exhaust the log. Entries below log_base live on only as
     # (log_base, base_term, base_chk); leaders whose peer's next_index falls below
     # their base send an InstallSnapshot analogue instead of entries
-    # (models/raft.py phase 3/8). Compaction configs carry absolute indices, so
+    # (models/raft_batched.py phase 3/8). Compaction configs carry absolute indices, so
     # the capacity-bounded next/match planes and the match/hint wire fields
     # widen to int32 (types.index_dtype).
     compact_margin: int = 0
@@ -277,8 +277,8 @@ class RaftConfig:
     # config-bounded value ranges as flat uint32 word legs, and the narrow
     # word/window planes (votes, the shared entry windows, the delivery
     # mask) are carried flattened so the TPU sublane tile stops padding
-    # their minor dim. PHYSICAL layout only: both kernels unpack at tick
-    # entry and repack at exit, so trajectories are bit-identical with the
+    # their minor dim. PHYSICAL layout only: the kernel unpacks at tick
+    # entry and repacks at exit, so trajectories are bit-identical with the
     # dense layout (tests/test_tile.py) -- a structural gate like pre_vote
     # (it changes which programs compile, never the protocol semantics).
     # Under compaction the unbounded int32 index planes stay dense; the
@@ -335,7 +335,7 @@ class RaftConfig:
         assert self.client_pipeline == 1 or self.client_redirect
         assert 1 <= self.client_pipeline <= 16
         # Compaction slack: client injections stop max(1, margin // 2) slots short
-        # of the ring so election no-ops always find room (models/raft.py phase 6);
+        # of the ring so election no-ops always find room (models/raft_batched.py phase 6);
         # margin >= 2 keeps that client ceiling above the steady-state retained
         # window (CAP - margin), and the margin must not consume the whole ring.
         assert self.compact_margin == 0 or 2 <= self.compact_margin < self.log_capacity

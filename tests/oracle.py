@@ -1,6 +1,7 @@
 """Scalar-Python oracle for the Raft tick semantics.
 
-An independent re-implementation of raft_sim_tpu.models.raft.step using plain Python
+An independent re-implementation of the tick semantics of
+raft_sim_tpu.models.raft_batched.step_b, one cluster at a time, using plain Python
 loops and if/else over numpy state -- the `cond`-cascade form of the handlers (the shape
 the reference writes them in, core.clj:91-169) -- used to cross-check the vectorized
 `jnp.where` lattice, whose branch precedence is the hard part of the rebuild
@@ -238,7 +239,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
     base_pend = np.asarray(s["base_pend"], np.int32).copy()  # [N]
     base_epoch = np.asarray(s["base_epoch"], np.int32).copy()  # [N]
     xfer_to = np.asarray(s["xfer_to"], np.int32).copy()
-    # Durable storage plane (models/raft.py phase -1 / 7.5): the fsynced
+    # Durable storage plane (models/raft_batched.py phase -1 / 7.5): the fsynced
     # prefix length and the term/vote snapshot at the last completed flush.
     dur = cfg.durable_storage
     dur_len = np.asarray(s["dur_len"], np.int32).copy()
@@ -270,7 +271,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 # truncate the un-fsynced (possibly torn) log suffix. The
                 # fsync watermark FLOORS the recovered length -- a completed
                 # flush never tears -- so torn_drop eats only the volatile
-                # tail (models/raft.py phase -1).
+                # tail (models/raft_batched.py phase -1).
                 term[d] = dur_term[d]
                 voted_for[d] = int(dur_vote[d]) if cfg.persist_vote else NIL
                 log_len[d] = max(
@@ -292,7 +293,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                     read_fr[d] = 0  # the staleness anchor dies with the slot
 
     # Log-carried configuration: the TICK-START derivation governs every
-    # quorum test this tick (models/raft.py); the end-of-tick block
+    # quorum test this tick (models/raft_batched.py); the end-of-tick block
     # recomputes it from the post-append log. Each node masks by ITS OWN
     # rows -- dual (both configs) while that node's own prefix holds an
     # uncompleted joint entry. The helper closes over SNAPSHOTS so later
@@ -339,7 +340,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
     req_in = edge_ok.T & alive[:, None] & recv_up[None, :] & (mb["req_type"] != 0)[:, None]
     resp_in = edge_ok & recv_up[:, None] & alive[None, :] & (mb["resp_kind"] != 0)
 
-    # Heard-a-leader denial window (thesis 4.2.3; models/raft.py): shared by
+    # Heard-a-leader denial window (thesis 4.2.3; models/raft_batched.py): shared by
     # the log-carried membership defense (rcf) and the lease vote denial
     # (rdl), bypassed per sender by the transfer override flag.
     if rcf or rdl:
@@ -401,7 +402,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             if up_to_date:
                 can.append(src)
         if rcf or rdl:
-            # Heard-a-leader vote denial (thesis 4.2.3; models/raft.py
+            # Heard-a-leader vote denial (thesis 4.2.3; models/raft_batched.py
             # phase 2): a voter that heard from a current leader within the
             # minimum election timeout on its LOCAL clock denies
             # RequestVote -- unless the sender carries the transfer
@@ -468,7 +469,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                     # The snapshot carries its configuration context: the
                     # sender's C_old/pending-toggle/entry-count at L, so the
                     # receiver's derivation stays exact over config entries
-                    # it never saw (models/raft.py phase 3).
+                    # it never saw (models/raft_batched.py phase 3).
                     base_mold[d] = np.asarray(mb["req_base_mold"][src], bool)
                     base_pend[d] = int(mb["req_base_pend"][src])
                     base_epoch[d] = int(mb["req_base_epoch"][src])
@@ -533,17 +534,17 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
         a_ok_to[d] = src
         a_match[d] = last_new
 
-    # Durable watermark after the AE conflict truncation (models/raft.py
+    # Durable watermark after the AE conflict truncation (models/raft_batched.py
     # phase 3): a truncation below the watermark drags it down with the log.
     if dur:
         dur_mid = np.minimum(dur_len, log_len.astype(np.int32))
 
     # NACK catch-up hint: every unsuccessful AE response carries the responder's
-    # (post-append) log length -- the conflict-index optimization (raft.py
+    # (post-append) log length -- the conflict-index optimization (raft_batched.py
     # phase 3). Per responder: the same hint toward every nacked sender.
     a_hint = log_len.astype(np.int32).copy()
 
-    # ---- phase 3.5: PreVote requests (thesis 9.6; raft.py): grant iff the
+    # ---- phase 3.5: PreVote requests (thesis 9.6; raft_batched.py): grant iff the
     # probe's prospective term is not behind us, its log is up to date, and we
     # are quiet (not leader, no valid AE within the minimum election timeout).
     pv_out = np.zeros((n, n), bool)
@@ -581,7 +582,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 if quiet and up and int(mb["req_term"][src]) >= int(term[d]):
                     pv_grant[d, src] = True
 
-    # ---- phase 3.7: TimeoutNow receipt (thesis 3.10; models/raft.py)
+    # ---- phase 3.7: TimeoutNow receipt (thesis 3.10; models/raft_batched.py)
     xfer_elect = np.zeros(n, bool)
     coup = np.zeros(n, bool)
     if xfr:
@@ -632,9 +633,9 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             leader_id[d] = d
             next_index[d, :] = log_len[d] + 1
             match_index[d, :] = 0
-            ack_age[d, :] = 0  # grace-zero every peer (see raft.py phase 4)
+            ack_age[d, :] = 0  # grace-zero every peer (see raft_batched.py phase 4)
 
-    # ---- phase 4.5: PreVote responses + promotion (thesis 9.6; raft.py)
+    # ---- phase 4.5: PreVote responses + promotion (thesis 9.6; raft_batched.py)
     pre_win = np.zeros(n, bool)
     if cfg.pre_vote:
         for d in range(n):
@@ -678,7 +679,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 next_index[d, src] = max(int(next_index[d, src]), m + 1)
             else:
                 # Back off to min(next-1, hint+1): the nack hint is the
-                # responder's log length (conflict-index hint, raft.py phase 4).
+                # responder's log length (conflict-index hint, raft_batched.py phase 4).
                 next_index[d, src] = max(
                     min(int(next_index[d, src]) - 1, int(mb["a_hint"][src]) + 1), 1
                 )
@@ -688,7 +689,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
     # ---- phase 5: leader commit advancement
     def masked_qmatch(match: np.ndarray, mask: np.ndarray, maj: int) -> int:
         """Largest index replicated to >= maj members of `mask` (0 if none);
-        candidates range over the members' own match values (raft.py)."""
+        candidates range over the members' own match values (raft_batched.py)."""
         best = 0
         for j in range(n):
             if not mask[j]:
@@ -703,11 +704,11 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             continue
         match = match_index[d].copy()
         # A leader's own quorum vote is its DURABLE length under the storage
-        # plane's ack gate (models/raft.py phase 5).
+        # plane's ack gate (models/raft_batched.py phase 5).
         match[d] = dur_mid[d] if (dur and cfg.durable_acks) else log_len[d]
         if rcf:
             # Each leader's OWN derived configuration masks its commit
-            # quorum (tick-start rows; models/raft.py phase 5).
+            # quorum (tick-start rows; models/raft_batched.py phase 5).
             quorum_match = masked_qmatch(
                 match, q_member_old[d], int(maj_old[d])
             )
@@ -726,7 +727,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
     # ---- phase 5.2: reconfiguration transitions moved INTO the log
     # (log-carried membership: no admin transition block -- config changes
     # are appends in phase 6, each node's configuration re-derives from its
-    # own prefix at end of tick; models/raft.py phase 5.2 comment)
+    # own prefix at end of tick; models/raft_batched.py phase 5.2 comment)
     xfer_pend = np.zeros(n, bool)
     if xfr:
         for d in range(n):
@@ -763,7 +764,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 confirmed = packed_quorum_row(d, acks_eff)
                 served = (confirmed if cfg.read_confirm else True) and alive[d]
                 if rdl and not served and alive[d]:
-                    # Lease fast path (thesis 6.4.1; models/raft.py): a
+                    # Lease fast path (thesis 6.4.1; models/raft_batched.py): a
                     # fresh config quorum of AE acks serves with NO
                     # confirmation round. The lease-skew mutant widens the
                     # window to the no-skew bound.
@@ -778,7 +779,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                     if xfr and xfer_pend[d]:
                         # Transfer handoff covers the read path: the lease
                         # fast path stops while a transfer pends
-                        # (models/raft.py phase 5).
+                        # (models/raft_batched.py phase 5).
                         served = False
                 if served:
                     # serve (the latency metric rides StepInfo, which the
@@ -814,7 +815,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 read_acks[d, :] = False
                 if rdl:
                     # Staleness anchor: the committed frontier at capture
-                    # (lat_frontier semantics -- models/raft.py phase 5).
+                    # (lat_frontier semantics -- models/raft_batched.py phase 5).
                     read_fr[d] = max(int(s["lat_frontier"]),
                                      int(commit.max()))
 
@@ -854,7 +855,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
     # ABSOLUTE entry index, anchored at the pre-compaction base (base_mid); the
     # same pass extends base_chk over the newly compacted span. Runs BEFORE
     # injection -- a write into a slot freed by this tick's rebase would alias
-    # under the anchored slot->index map (raft.py). Under compaction the sums are
+    # under the anchored slot->index map (raft_batched.py). Under compaction the sums are
     # maintained even with invariant checking off (base_chk is wire state).
     if cfg.check_invariants or cfg.compact_margin > 0:
         M = (1 << 32) - 1
@@ -871,7 +872,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             base_chk[d] = np.uint32(accb)
 
     # ---- phase 6: client injection (ring slot; space = retained window < CAP),
-    # redirect routing, and the election-win leader no-op (raft.py phase 6)
+    # redirect routing, and the election-win leader no-op (raft_batched.py phase 6)
     cmd_in = int(inp["client_cmd"])
     now0 = int(s["now"])  # pre-increment tick: a fresh offer's stamp is now0 + 1
     comp = cfg.compact_margin > 0
@@ -900,7 +901,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             log_cfg[d, log_len[d] % cap] = code
         log_len[d] += 1
 
-    # Config-entry origination (log-carried membership; models/raft.py
+    # Config-entry origination (log-carried membership; models/raft_batched.py
     # phase 6): config changes are LOG WRITES sharing the one-append-per-
     # node slot at priority no-op > config entry > client command, judged
     # on each leader's OWN tick-start derived configuration.
@@ -938,7 +939,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
         # one final-acting entry per change, no completing entry.)
 
     if cfg.client_redirect:
-        # K commands in flight chasing 302 redirects (raft.py phase 6): a fresh
+        # K commands in flight chasing 302 redirects (raft_batched.py phase 6): a fresh
         # offer takes the first free slot; at most one slot is accepted per
         # node per tick, lowest slot index first.
         pend = list(client_pend)
@@ -1053,7 +1054,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                     votes[d, d] = True
                     deadline[d] = clock[d] + int(inp["timeout_draw"][d])
 
-    # ---- phase 7.5: fsync flush + durability gates (models/raft.py) --------
+    # ---- phase 7.5: fsync flush + durability gates (models/raft_batched.py) --------
     # After elections finalize term/votedFor and injection finalizes log_len:
     # a completing flush snaps the durable snapshot to the live triple. Gates
     # (cfg.durable_acks; False = TEST-ONLY ack-before-fsync mutant): AE acks
@@ -1138,7 +1139,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
         elif win[src] or heartbeat[src]:
             # Shared entry window: starts at the minimum prev over RESPONSIVE peers
             # (acked an AE within ack_timeout_ticks), falling back to all peers when
-            # none are -- a dead peer must not pin the window (raft.py phase 8) --
+            # none are -- a dead peer must not pin the window (raft_batched.py phase 8) --
             # and never below the compaction base (those entries are gone; such
             # peers get the InstallSnapshot sentinel instead).
             prev_of = lambda dst: min(
@@ -1164,7 +1165,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
             out["req_base_chk"][src] = base_chk[src]
             if comp and rcf:
                 # Snapshot config header: the sender's configuration context
-                # at its base rides every AE broadcast (models/raft.py).
+                # at its base rides every AE broadcast (models/raft_batched.py).
                 out["req_base_mold"][src] = base_mold[src]
                 out["req_base_pend"][src] = base_pend[src]
                 out["req_base_epoch"][src] = base_epoch[src]
@@ -1188,7 +1189,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
                 else:
                     out["req_off"][src, dst] = min(max(p, ws), ws + e) - ws
             if xfr and xfer_to[src] != NIL:
-                # TimeoutNow fire (raft.py phase 8): replaces the heartbeat
+                # TimeoutNow fire (raft_batched.py phase 8): replaces the heartbeat
                 # slot once the target matched the leader's log; the AE
                 # window fields above stay populated (receivers gate on
                 # req_type == REQ_APPEND).
@@ -1224,7 +1225,7 @@ def oracle_step(cfg, s: dict, inp: dict) -> dict:
         # Late vote-completion response (phase 7.5): the flush that made this
         # voter's grant durable emits the RESP_VOTE the grant tick withheld --
         # toward the recorded candidate, only where the edge carries no
-        # response already (models/raft.py for the AE-collision argument).
+        # response already (models/raft_batched.py for the AE-collision argument).
         for r in range(n):
             if late_grant[r]:
                 q = int(voted_for[r])

@@ -99,10 +99,9 @@ def test_plane_widening_caught_and_reduction_exempt():
 
 
 def test_step_kernels_are_float_free_and_unwidened():
-    for batched in (False, True):
-        jx = jaxpr_audit.step_jaxpr(CFG3, batched=batched)
-        assert jaxpr_audit.check_float_ops("jaxpr:config3", jx) == []
-        assert jaxpr_audit.check_plane_widening("jaxpr:config3", jx, CFG3) == []
+    jx = jaxpr_audit.step_jaxpr(CFG3)
+    assert jaxpr_audit.check_float_ops("jaxpr:config3", jx) == []
+    assert jaxpr_audit.check_plane_widening("jaxpr:config3", jx, CFG3) == []
 
 
 def test_carry_passthrough_fires_on_rewritten_invariant_leg():

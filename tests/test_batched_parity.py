@@ -1,21 +1,22 @@
-"""Batch-minor kernel parity: models/raft_batched.step_b must match vmap(raft.step)
-bit-for-bit (which transitively pins it to the scalar oracle via
-tests/test_oracle_parity.py)."""
+"""Batch-minor kernel parity: models/raft_batched.step_b stepped at B = 16 is
+held, every tick, to the scalar oracle (tests/oracle.py) on a sample of its
+clusters -- the layout the chip runs, checked the way the benchmark's `correct`
+samples clusters -- and the fleet scan (`scan.run_batch_minor`) is held to the
+single-cluster traced scan (`scan.run`) cluster by cluster."""
 
 import jax
 import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_batch
-from raft_sim_tpu.models import raft, raft_batched
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.sim import faults, scan
+from tests import oracle
+from tests.test_oracle_parity import assert_state_equal
 
-# Budget note (round 11): the per-tick lockstep rows are the suite's most
-# expensive family (~20-36s each), and every config below is ALSO pinned
-# against the scalar oracle every tick in tests/test_oracle_parity.py, which
-# stays tier-1 in full (plus test_scenario's homogeneous-genome bit-exactness
-# pinning the batched scan path). Tier-1 keeps the plain row; the fault-mix
-# rows ride the slow tier (870s budget, ROADMAP.md).
+# Every config below is also pinned one cluster at a time against the oracle
+# in tests/test_oracle_parity.py; these rows hold the batch-minor layout
+# itself (B = 16, a sample of its clusters) to it, a few seconds each.
 CONFIGS = [
     pytest.param(RaftConfig(n_nodes=5, client_interval=8), id="n5"),
     pytest.param(
@@ -29,7 +30,6 @@ CONFIGS = [
             check_log_matching=True,
         ),
         id="n7-faults",
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -42,7 +42,6 @@ CONFIGS = [
             crash_down_ticks=10,
         ),
         id="n5-crashes",
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -59,7 +58,6 @@ CONFIGS = [
         ),
         id="n5-compaction-snap",  # ring wrap + rebase + InstallSnapshot sentinel,
         # wide (int32) index planes, ring-aware log-matching check
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -75,7 +73,6 @@ CONFIGS = [
         ),
         id="n5-redirect-compaction",  # 302 routing state + latency metric riding
         # the compaction ring
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -91,7 +88,6 @@ CONFIGS = [
             crash_down_ticks=8,
         ),
         id="n5-redirect-pipeline",  # K = 4 in-flight slots ([K, B] client state)
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -105,7 +101,6 @@ CONFIGS = [
             crash_down_ticks=8,
         ),
         id="n5-prevote",  # thesis-9.6 probe rounds under churn
-        marks=pytest.mark.slow,
     ),
     pytest.param(
         RaftConfig(
@@ -122,13 +117,8 @@ CONFIGS = [
         ),
         id="n5-reconfig-truncation",  # log-carried configs under partition +
         # crash churn: per-node derived member rows diverging and rolling
-        # back with truncations must match the vmap kernel bit-for-bit.
-        # Slow tier (budget re-tier, ISSUE 14 -- the PR 6 convention): the
-        # oracle pins the vmap form on the same config/seed family EVERY
-        # tick in tier-1's test_oracle_parity.py (its n5-reconfig-truncation
-        # row), the plain n5 batched row stays tier-1, and the homogeneous-
-        # genome bit-exactness test pins the batched scan path.
-        marks=pytest.mark.slow,
+        # back with truncations must match the oracle bit-for-bit in the
+        # batch-minor layout.
     ),
     pytest.param(
         RaftConfig(
@@ -144,10 +134,13 @@ CONFIGS = [
         ),
         id="n5-reconfig-compaction",  # config entries compacting away:
         # fold_span snapshot-context advance + req_base_mold install on
-        # snapshot catch-up, vs the vmap kernel
-        marks=pytest.mark.slow,
+        # snapshot catch-up, vs the oracle
     ),
 ]
+
+
+# Clusters of the B = 16 fleet checked against the oracle every tick.
+SAMPLE = (0, 5, 10, 15)
 
 
 def tree_eq(a, b):
@@ -155,10 +148,15 @@ def tree_eq(a, b):
         np.testing.assert_array_equal(x, y)
 
 
+def take(tree, c):
+    return jax.tree.map(lambda x: x[c], tree)
+
+
 @pytest.mark.parametrize("cfg", CONFIGS)
 def test_step_parity_along_trajectory(cfg):
-    """Step both kernels in lockstep from the same start for 120 ticks; states and
-    StepInfo must agree exactly at every tick (covers elections, replication, faults,
+    """Step the batch-minor kernel at B = 16 for 120 ticks; at every tick each
+    sampled cluster's state (mailbox included) equals the oracle's, stepped
+    alone on that cluster's inputs (covers elections, replication, faults,
     injection, timers as they actually occur)."""
     batch = 16
     key = jax.random.key(0)
@@ -166,21 +164,27 @@ def test_step_parity_along_trajectory(cfg):
     state = init_batch(cfg, k_init, batch)
     keys = jax.random.split(k_run, batch)
 
-    vstep = jax.jit(jax.vmap(lambda s, i: raft.step(cfg, s, i)))
+    draw = jax.jit(jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now)))
     bstep = jax.jit(lambda s, i: raft_batched.step_b(cfg, s, i))
 
-    s_lead = state
+    want = {c: oracle.state_to_dict(take(state, c), cfg) for c in SAMPLE}
+    now = state.now
     s_min = raft_batched.to_batch_minor(state)
     for t in range(120):
-        inp = jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now))(keys, s_lead.now)
-        s_lead, info_lead = vstep(s_lead, inp)
-        s_min, info_min = bstep(s_min, raft_batched.to_batch_minor(inp))
-        tree_eq(s_lead, raft_batched.from_batch_minor(s_min))
-        # StepInfo rides batch-minor too (the histogram leaf is [BINS, B] there).
-        tree_eq(info_lead, raft_batched.from_batch_minor(info_min))
+        inp = jax.device_get(draw(keys, now))
+        s_min, _ = bstep(s_min, raft_batched.to_batch_minor(inp))
+        got = jax.device_get(raft_batched.from_batch_minor(s_min))
+        now = got.now
+        for c in SAMPLE:
+            inp_c = {f: np.asarray(v)[c] for f, v in zip(inp._fields, inp)}
+            want[c] = oracle.oracle_step(cfg, want[c], inp_c)
+            assert_state_equal(oracle.state_to_dict(take(got, c), cfg), want[c], t)
 
 
 def test_run_batch_minor_matches_run_batch():
+    """The fleet scan at B = 32 against `scan.run` for each cluster: every
+    cluster follows the trajectory it has alone (batch independence), and
+    the batch-minor metrics transpose back to the public layout."""
     cfg = RaftConfig(n_nodes=5, client_interval=8, drop_prob=0.1)
     batch = 32
     key = jax.random.key(3)
@@ -188,7 +192,7 @@ def test_run_batch_minor_matches_run_batch():
     state = init_batch(cfg, k_init, batch)
     keys = jax.random.split(k_run, batch)
 
-    f_ref, m_ref, _ = jax.jit(lambda s, k: scan.run_batch(cfg, s, k, 250))(state, keys)
     f_min, m_min = jax.jit(lambda s, k: scan.run_batch_minor(cfg, s, k, 250))(state, keys)
+    f_ref, m_ref, _ = jax.jit(jax.vmap(lambda s, k: scan.run(cfg, s, k, 250)))(state, keys)
     tree_eq(f_ref, f_min)
     tree_eq(m_ref, m_min)

@@ -102,7 +102,7 @@ def test_golden_pins_every_audited_program():
     want = {
         f"{c}/{p}"
         for c in JA.AUDIT_CONFIGS
-        for p in ("step", "step_b", "simulate", "scenario_simulate",
+        for p in ("step_b", "simulate", "scenario_simulate",
                   "serve_simulate", "trace_simulate")
     }
     assert set(_golden()["programs"]) == want

@@ -23,7 +23,7 @@ def test_chunked_matches_monolithic():
     state = init_batch(CFG, k_init, 8)
     keys = jax.random.split(k_run, 8)
 
-    f_mono, m_mono, _ = scan.run_batch(CFG, state, keys, 300)
+    f_mono, m_mono = scan.run_batch_minor(CFG, state, keys, 300)
     f_chunk, m_chunk = chunked.run_chunked(CFG, state, keys, 300, chunk=100)
 
     for a, b in zip(jax.tree.leaves(jax.device_get(f_mono)), jax.tree.leaves(jax.device_get(f_chunk))):

@@ -5,7 +5,7 @@ compile, so this module costs seconds, not scan-compile minutes):
 
 1. **Op-histogram snapshot** (tests/golden_jaxpr_hist.json): primitive counts
    bucketed by output dtype for the N=5 (config3) and N=51 (config5) step
-   programs, both kernel forms. A hot-path regression -- a new [N, N, B]
+   programs (the batch-minor kernel, `step_b`). A hot-path regression -- a new [N, N, B]
    materialization, a dtype flip, a lost fusion opportunity -- shows up as a
    reviewable count diff instead of a benchmark surprise on the next chip
    session. Counts are exact for a fixed jax version (recorded in the file);
@@ -40,12 +40,10 @@ from raft_sim_tpu.utils.config import PRESETS
 
 GOLDEN_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden_jaxpr_hist.json")
 
-# The snapshotted step programs: (golden key, preset, batched kernel form).
+# The snapshotted step programs: (golden key, preset).
 SNAPSHOT_PROGRAMS = (
-    ("config3/step", "config3", False),
-    ("config3/step_b", "config3", True),
-    ("config5/step", "config5", False),
-    ("config5/step_b", "config5", True),
+    ("config3/step_b", "config3"),
+    ("config5/step_b", "config5"),
 )
 
 # Distinct lowerings across the preset matrix (8 presets, all structurally
@@ -84,7 +82,7 @@ PINNED_SERVE_SCAN_LOWERINGS = 13
 # The protocol-trace program (telemetry windowed scan + event ring + coverage
 # legs, raft_sim_tpu/trace): at most one per preset -- these are "the pinned
 # trace variants" ISSUE 9's acceptance names: tracing adds ZERO step lowerings
-# (extraction is delta-based outside the kernels) and the coverage search's
+# (extraction is delta-based outside the kernel) and the coverage search's
 # generations all reuse one trace program (genomes are traced data; the
 # analyzer's trace fork pairs pin value-invariance).
 # + config3p/config8/config9 trace variants; + config5c's compacted-layout
@@ -109,9 +107,9 @@ def _pins():
 
 def _histograms():
     out = {}
-    for key, preset, batched in SNAPSHOT_PROGRAMS:
+    for key, preset in SNAPSHOT_PROGRAMS:
         cfg, _ = PRESETS[preset]
-        out[key] = JA.op_histogram(JA.step_jaxpr(cfg, batched=batched))
+        out[key] = JA.op_histogram(JA.step_jaxpr(cfg))
     return out
 
 
@@ -158,7 +156,7 @@ def test_compile_count_pin():
         # among the standing presets.
         if name.startswith("config7"):
             continue
-        step_hashes.add(JA.program_hash(JA.step_jaxpr(cfg, batched=True)))
+        step_hashes.add(JA.program_hash(JA.step_jaxpr(cfg)))
         scan_hashes.add(JA.program_hash(JA.scan_jaxpr(cfg)))
         scenario_hashes.add(JA.program_hash(JA.scenario_scan_jaxpr(cfg)))
         serve_hashes.add(

@@ -21,7 +21,7 @@ from raft_sim_tpu import (
     StepInputs,
     init_state,
 )
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu import types as raft_types
 from raft_sim_tpu.ops import bitplane
 from raft_sim_tpu.types import REQ_APPEND, REQ_VOTE, RESP_APPEND, RESP_VOTE
@@ -73,7 +73,7 @@ import functools
 
 @functools.lru_cache(maxsize=None)
 def _jitted_step(cfg):
-    return jax.jit(lambda s_, i_: raft.step(cfg, s_, i_))
+    return jax.jit(lambda s_, i_: raft_batched.step_one(cfg, s_, i_))
 
 
 def step(cfg, s, inp=None):
@@ -591,7 +591,7 @@ def test_window_fallback_when_no_peer_responsive():
     """A leader whose peers ALL aged out of the ack window (total isolation longer
     than ack_timeout_ticks) falls back to the min prev over all peers for the shared
     window start, so its next heartbeat still ships the entries a healed laggard
-    needs (raft.py phase 8 fallback arm)."""
+    needs (raft_batched.py phase 8 fallback arm)."""
     s = with_log(base_state(), 0, [1, 1, 1])
     s = make_leader(s, 0, 1)
     s = s._replace(

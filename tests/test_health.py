@@ -654,7 +654,7 @@ def test_slice_units_are_views():
     assert view[0]["cmds"][0] == 99
 
 
-# ----------------------------------------------- bit-exactness (both kernels)
+# ------------------------------------------- bit-exactness (both layouts)
 
 
 @pytest.mark.parametrize("compact", [False, True], ids=["dense", "compact"])

@@ -4,10 +4,10 @@ on synthetic [N, CAP, B] planes.
 
 The reference below is that form as the batched kernel wrote it: the absolute
 index of every slot by a per-slot modulo, then log_ops.log2_bin and a one-hot
-over the histogram's bins (models/raft.py keeps the same form per cluster).
-Every output must be equal: lat_sum, lat_cnt, lat_hist, lat_excluded and the
-carried lat_frontier. test_batched_parity.py holds the whole kernel to
-raft.py along trajectories; this file reaches the corners a trajectory
+over the histogram's bins. Every output must be equal: lat_sum, lat_cnt,
+lat_hist, lat_excluded and the carried lat_frontier. test_oracle_parity.py
+and test_batched_parity.py hold the whole kernel to the scalar oracle along
+trajectories; this file reaches the corners a trajectory
 rarely does (every bin, a ring that wraps, split brain, stamps out of range).
 """
 

@@ -28,7 +28,7 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import bitplane
 from raft_sim_tpu.scenario.mutation import mutant_config
 from raft_sim_tpu.sim import scan
@@ -104,7 +104,7 @@ def test_lease_serves_in_one_tick_with_no_confirmation_round():
     zero AppendEntries confirmation responses ever arrive (the mailbox stays
     quiet), yet the read serves with latency 1 -- the zero-quorum-round
     steady state 6.4.1 promises. The slot and its staleness anchor clear."""
-    step = jax.jit(lambda st, i: raft.step(LCFG, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(LCFG, st, i))
     s = _leader_state(LCFG)
     s, info = step(s, _quiet_inputs(LCFG, read_cmd=jnp.int32(1)))
     assert int(s.read_idx[0]) == 2  # captured commit 1 (+1 encoding)
@@ -122,7 +122,7 @@ def test_expired_lease_withholds_the_serve():
     """With every ack older than the lease window (and no confirmation
     responses), the pending read stays pending: the lease never serves on
     stale acknowledgments."""
-    step = jax.jit(lambda st, i: raft.step(LCFG, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(LCFG, st, i))
     s = _leader_state(LCFG, ack_age_val=50)
     s, _ = step(s, _quiet_inputs(LCFG, read_cmd=jnp.int32(1)))
     assert int(s.read_idx[0]) == 2
@@ -137,7 +137,7 @@ def test_stale_lease_serve_raises_viol_read_stale_and_only_stale():
     capture-frontier is the linearizability break: viol_read_stale fires and
     folds into the violations predicate (scan.step_bad -- the hunt's fitness
     signal). The legal twin (anchor covered by the capture) stays clean."""
-    step = jax.jit(lambda st, i: raft.step(LCFG, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(LCFG, st, i))
     base = _leader_state(LCFG)
     stale = base._replace(
         read_idx=base.read_idx.at[0].set(2),   # captured commit 1...
@@ -163,7 +163,7 @@ def test_lease_vote_denial_and_local_clock_expiry():
     election_min_ticks of LOCAL clock denies RequestVote outright; once the
     local window elapses (or a restart wipes the memory), it grants."""
     n = LCFG.n_nodes
-    step = jax.jit(lambda st, i: raft.step(LCFG, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(LCFG, st, i))
     s = init_state(LCFG, jax.random.key(1))
     mb = s.mailbox
     # Node 1 broadcasts an up-to-date RequestVote at everyone's term.

@@ -16,17 +16,17 @@ import jax.numpy as jnp
 import pytest
 
 from raft_sim_tpu import NIL, RaftConfig, StepInputs, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import bitplane
 
 CFG = RaftConfig(n_nodes=5, log_capacity=64, max_entries_per_rpc=4, client_interval=1)
 
 
 def run_ticks(cfg, s, n_ticks, alive, cmd_base=100):
-    """Drive raft.step with full delivery, steady clocks, one offered command per
-    tick, and a fixed alive mask. Returns the final state."""
+    """Drive raft_batched.step_one with full delivery, steady clocks, one offered
+    command per tick, and a fixed alive mask. Returns the final state."""
     n = cfg.n_nodes
-    step = jax.jit(raft.step, static_argnums=0)
+    step = jax.jit(raft_batched.step_one, static_argnums=0)
     for t in range(n_ticks):
         inp = StepInputs(
             deliver_mask=bitplane.pack(jnp.ones((n, n), bool), axis=1),
@@ -83,7 +83,7 @@ def test_healed_laggard_catches_up():
         alive=jnp.ones((n,), bool),
         restarted=jnp.asarray([i == 4 for i in range(n)], bool),
     )
-    s, _ = jax.jit(raft.step, static_argnums=0)(CFG, s, restart)
+    s, _ = jax.jit(raft_batched.step_one, static_argnums=0)(CFG, s, restart)
     s = run_ticks(CFG, s, 120, [True] * n, cmd_base=500)
     # The healed node caught all the way up to the cluster commit frontier.
     assert int(s.commit_index[4]) >= gap, (

@@ -260,7 +260,7 @@ def test_compile_count_pin_full_matrix():
     with open(hist) as f:
         pins = json.load(f)["lowerings"]
     families = {
-        "step": lambda c: JA.step_jaxpr(c, batched=True),
+        "step": JA.step_jaxpr,
         "scan": JA.scan_jaxpr,
         "scenario_scan": JA.scenario_scan_jaxpr,
         "serve_scan": lambda c: JA.serve_scan_jaxpr(JA.serve_variant(c)),

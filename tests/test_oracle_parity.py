@@ -1,14 +1,15 @@
-"""Kernel-vs-oracle parity: the vectorized `where`-lattice in models/raft.py must agree
-bit-for-bit, tick by tick, with the scalar Python oracle (tests/oracle.py) across
-randomized trajectories including faults -- the mitigation SURVEY.md section 7.3 calls
-for against branch-precedence bugs."""
+"""Kernel-vs-oracle parity: the vectorized `where`-lattice in models/raft_batched.py
+(the kernel the chip runs, stepped one cluster at a time through `step_one`) must
+agree bit-for-bit, tick by tick, with the scalar Python oracle (tests/oracle.py)
+across randomized trajectories including faults -- the mitigation SURVEY.md
+section 7.3 calls for against branch-precedence bugs."""
 
 import jax
 import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.sim import faults
 from tests import oracle
 
@@ -371,7 +372,7 @@ CONFIGS = [
 
 
 def run_parity(cfg, state, k_run, ticks):
-    step = jax.jit(lambda s, i: raft.step(cfg, s, i)[0])
+    step = jax.jit(lambda s, i: raft_batched.step_one(cfg, s, i)[0])
     s_oracle = oracle.state_to_dict(state, cfg)
     for t in range(ticks):
         inp = faults.make_inputs(cfg, k_run, state.now)

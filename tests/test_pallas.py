@@ -1,8 +1,8 @@
 """Pallas engine parity (interpret mode on CPU).
 
 The Pallas engine runs the identical step_b body inside a pallas_call gridded over
-cluster blocks, so parity here extends the oracle -> raft.py -> raft_batched.py chain
-to the kernelized execution path. On this image's TPU toolchain the compiled path is
+cluster blocks, so parity here extends the oracle -> raft_batched.step_b chain to
+the kernelized execution path. On this image's TPU toolchain the compiled path is
 blocked by a compiler limitation, which demoted the engine to experiments/ (see
 experiments/pallas_engine.py docstring); interpret mode exercises the full
 pallas_call machinery (blocking, ref plumbing, shape lifting) on CPU and keeps the

@@ -4,8 +4,8 @@ what they cost where their gates are off.
 - With its gate off, a counter's metric leg passes through the tick
   unchanged: no equation of the tick computes it (the read metrics'
   zero-cost contract). With the gate on, the tick computes it.
-- Both kernels count the same: models/raft.py under vmap and
-  models/raft_batched.py, tick by tick, at the TiKV cell's widths.
+- The kernel counts them on trajectories the oracle (tests/oracle.py)
+  agrees with, tick by tick, at the TiKV cell's widths.
 - The etcd fleet cells' chunk program (`sim/chunked._chunk_donate` at the
   fleet-etcd5-jepsen and fleet-etcd3-b1000 fields) keeps the tick loop it
   had before the counters: the same loop carry and the same instructions in
@@ -31,8 +31,10 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_batch
-from raft_sim_tpu.models import raft, raft_batched
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.sim import chunked, faults, scan
+from tests import oracle
+from tests.test_oracle_parity import assert_state_equal
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GOLDEN_PATH = os.path.join(ROOT, "tests", "golden_chunk_ops.json")
@@ -99,32 +101,36 @@ def test_counters_read_zero_with_their_gates_off():
 
 
 def test_both_kernels_count_alike():
-    """Lockstep at the TiKV cell's widths with fast move and transfer
-    cadences: states and every StepInfo leaf agree each tick, and each
-    counter fires."""
+    """The batch-minor kernel at the TiKV cell's widths with fast move and
+    transfer cadences: every cluster's state equals the oracle's each tick,
+    each counter fires, and the lease never serves more reads than were
+    served."""
     import dataclasses
 
     cfg = dataclasses.replace(RaftConfig(**cell_fields("fleet-tikv3-shuffle")),
                               reconfig_interval=7, transfer_interval=5)
     b = 8
     k_init, k_run = jax.random.split(jax.random.key(4))
-    s_lead = init_batch(cfg, k_init, b)
-    s_min = raft_batched.to_batch_minor(s_lead)
+    state = init_batch(cfg, k_init, b)
     keys = jax.random.split(k_run, b)
-    vstep = jax.jit(jax.vmap(lambda s, i: raft.step(cfg, s, i)))
+    draw = jax.jit(jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now)))
     bstep = jax.jit(lambda s, i: raft_batched.step_b(cfg, s, i))
+    take = lambda tree, c: jax.tree.map(lambda x: x[c], tree)
+    want = [oracle.state_to_dict(take(state, c), cfg) for c in range(b)]
+    now = state.now
+    s_min = raft_batched.to_batch_minor(state)
     total = collections.Counter()
-    for _ in range(80):
-        inp = jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now))(keys, s_lead.now)
-        s_lead, i_lead = vstep(s_lead, inp)
+    for t in range(80):
+        inp = jax.device_get(draw(keys, now))
         s_min, i_min = bstep(s_min, raft_batched.to_batch_minor(inp))
-        pairs = zip(jax.tree.leaves(jax.device_get((s_lead, i_lead))),
-                    jax.tree.leaves(jax.device_get(raft_batched.from_batch_minor(
-                        (s_min, i_min)))))
-        for x, y in pairs:
-            np.testing.assert_array_equal(x, y)
-        assert np.all(i_lead.lease_reads <= i_lead.reads_served)
-        total.update({n: int(np.sum(getattr(i_lead, n))) for n in COUNTERS})
+        got, info = jax.device_get(raft_batched.from_batch_minor((s_min, i_min)))
+        now = got.now
+        for c in range(b):
+            inp_c = {f: np.asarray(v)[c] for f, v in zip(inp._fields, inp)}
+            want[c] = oracle.oracle_step(cfg, want[c], inp_c)
+            assert_state_equal(oracle.state_to_dict(take(got, c), cfg), want[c], t)
+        assert np.all(info.lease_reads <= info.reads_served)
+        total.update({n: int(np.sum(getattr(info, n))) for n in COUNTERS})
     assert all(total[n] > 0 for n in COUNTERS), total
 
 

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, StepInputs, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import bitplane
 from raft_sim_tpu.sim import scan
 from raft_sim_tpu.types import (
@@ -130,7 +130,7 @@ def test_expiry_starts_probe_not_election():
 
 
 def _run(cfg, s, inputs, ticks):
-    st = jax.jit(lambda s_, i_: raft.step(cfg, s_, i_), static_argnums=())
+    st = jax.jit(lambda s_, i_: raft_batched.step_one(cfg, s_, i_), static_argnums=())
     for _ in range(ticks):
         s, _ = st(s, inputs)
     return s

@@ -33,7 +33,7 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import bitplane
 from raft_sim_tpu.scenario.mutation import mutant_config
 from raft_sim_tpu.sim import scan, telemetry
@@ -123,7 +123,7 @@ def test_joint_dual_quorum_at_word_boundaries(n):
             member_new=_mask_rows(n, set(range(n)) - {removed}),
             cfg_pend=jnp.full((n,), 1000, jnp.int32),  # joint: exit far away
         )
-        s2, _ = jax.jit(lambda st, i: raft.step(cfg, st, i))(
+        s2, _ = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))(
             s, _quiet_inputs(cfg)
         )
         return int(s2.role[0]) == LEADER
@@ -150,7 +150,7 @@ def test_single_config_quorum_when_not_joint():
         voted_for=s.voted_for.at[2].set(2),
         votes=s.votes.at[2].set(_mask(n, {1, 2, 3, 4})),
     )
-    s2, _ = jax.jit(lambda st, i: raft.step(cfg, st, i))(s, _quiet_inputs(cfg))
+    s2, _ = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))(s, _quiet_inputs(cfg))
     assert int(s2.role[2]) == LEADER
 
 
@@ -174,7 +174,7 @@ def test_log_carried_joint_lifecycle_and_removed_leader_stepdown():
         ack_age=jnp.zeros((n, n), s.ack_age.dtype),  # everyone responsive
         deadline=s.deadline.at[0].set(1),  # heartbeats start immediately
     )
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     # Tick 1: the admin offers "toggle node 0" -> the leader appends the
     # joint entry and applies it ON APPEND: its own derived config goes
     # joint while every follower still derives the boot config (divergence).
@@ -224,7 +224,7 @@ def test_reconfig_command_refused_while_joint_and_below_two_voters():
         member_new=_mask_rows(n, {0, 1}),
         cfg_pend=jnp.full((n,), 1000, jnp.int32),  # joint pending, exit far
     )
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     s2, _ = step(s, _quiet_inputs(cfg, reconfig_cmd=jnp.int32(1)))
     assert int(s2.log_len[0]) == 0  # refused: joint phase pending, no entry
     # Not joint, but the toggle would strand a single voter: refused.
@@ -282,7 +282,7 @@ def test_config_divergence_and_truncation_rollback_at_word_boundaries(n):
         log_cfg=s.log_cfg.at[x, 0].set(v + 1),
         log_len=s.log_len.at[x].set(1),
     )
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     # Tick 1 (no delivery): the end-of-tick derivation APPLIES x's entry --
     # per-node divergence: x joint and missing v from C_new, majority boot.
     s, _ = step(s, _quiet_inputs(cfg))
@@ -322,7 +322,7 @@ def test_transfer_lease_blocks_writes_and_fires_timeout_now():
         ack_age=jnp.zeros((n, n), s.ack_age.dtype),  # everyone responsive
         deadline=s.deadline.at[0].set(1),  # heartbeat fires next tick
     )
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     s, _ = step(s, _quiet_inputs(
         cfg, transfer_cmd=jnp.int32(3), client_cmd=jnp.int32(77)
     ))
@@ -374,7 +374,7 @@ def test_transfer_fires_and_elects_during_joint_phase():
         cfg_pend=jnp.ones((n,), jnp.int32),
         cfg_epoch=jnp.ones((n,), jnp.int32),
     )
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     # Tick 1: transfer to node 1 accepted WHILE joint; the heartbeat slot
     # carries the TimeoutNow (target trivially caught up: equal logs).
     s, _ = step(s, _quiet_inputs(cfg, transfer_cmd=jnp.int32(1)))
@@ -434,7 +434,7 @@ def test_transfer_overrides_lease_denial_deterministically():
             deadline=s.deadline.at[0].set(1),  # leader heartbeat tick 1
         )
 
-    step = jax.jit(lambda st, i: raft.step(cfg, st, i))
+    step = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))
     # Transfer path: accepted tick 1 (TimeoutNow fires), received tick 2
     # (override election, flag set), granted tick 3 DESPITE the armed
     # denial, won tick 4.
@@ -511,7 +511,7 @@ def test_read_confirmation_uses_tick_start_config_at_joint_exit():
         read_acks=s.read_acks.at[0].set(_mask(n, {1, 4})),
     )
     inp = _quiet_inputs(cfg)
-    s2, _ = jax.jit(lambda st, i: raft.step(cfg, st, i))(s, inp)
+    s2, _ = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))(s, inp)
     assert np.all(np.asarray(s2.cfg_pend) == 0)  # joint state dissolved...
     assert int(s2.read_idx[0]) == 1  # ...but the read stayed pending
     inp_np = {f: np.asarray(v) for f, v in zip(inp._fields, inp)}
@@ -531,7 +531,6 @@ def test_tick_batch_minor_read_cmd_override():
     cadence -- a fleet fed reads via the override serves them; NIL feeds
     none. Uses a huge scheduled cadence so every served read is
     override-attributable."""
-    from raft_sim_tpu.models import raft_batched
     from raft_sim_tpu.types import init_batch
 
     cfg = RaftConfig(n_nodes=5, log_capacity=32, client_interval=4,

@@ -3,8 +3,8 @@
 ISSUE 6's acceptance surface, pinned:
   - Arbitrary-payload parity: values chosen to COLLIDE with the old tick
     encoding produce bit-exact state (minus the value planes themselves),
-    latency histograms, and telemetry windows vs non-colliding values, on both
-    kernels -- payload/latency decoupling (checkpoint v21) means the metric
+    latency histograms, and telemetry windows vs non-colliding values --
+    payload/latency decoupling (checkpoint v21) means the metric
     reads the offer-tick plane, never the payload.
   - The device-side commit-delta stream exactly equals the host snapshot-diff
     reconstruction on a fuzzed run, and ApplyLogWriter's per-node export.
@@ -16,8 +16,8 @@ ISSUE 6's acceptance surface, pinned:
 Compile budget: one served scan (`simulate_serve`, shared by the parity and
 export tests), one scheduled scan (the cadence-equivalence anchor), one serve
 chunk program (`_serve_chunk`, shared by every ServeSession test via the
-module fixture), and one unbatched step -- everything else is host-side or
-reuses programs other test modules compile.
+module fixture) -- everything else is host-side or reuses programs other
+test modules compile.
 """
 
 import os
@@ -29,7 +29,6 @@ import pytest
 
 from raft_sim_tpu import NIL, RaftConfig
 from raft_sim_tpu.types import NOOP
-from raft_sim_tpu.models import raft
 from raft_sim_tpu.serve import (
     CommandSource,
     DeltaStream,
@@ -41,8 +40,8 @@ from raft_sim_tpu.serve import (
 )
 from raft_sim_tpu.serve import deltas as deltas_mod
 from raft_sim_tpu.serve import ingest, loop
-from raft_sim_tpu.sim import faults, scan
-from raft_sim_tpu.types import init_batch, init_state
+from raft_sim_tpu.sim import scan
+from raft_sim_tpu.types import init_batch
 from raft_sim_tpu.utils import checkpoint
 
 # The scheduled twin (client_interval=1) and its serve-mode variant: ONE served
@@ -185,38 +184,6 @@ def test_arbitrary_payload_parity_batched():
     # Latency was measured (not silently skipped) and covered every commit.
     assert int(np.asarray(ma.lat_cnt).sum()) >= len(COLLIDING)
     assert int(np.asarray(ma.lat_excluded).sum()) == 0
-
-
-@pytest.mark.slow
-def test_arbitrary_payload_parity_unbatched_kernel():
-    """The same A/B on the UNBATCHED kernel (raft.step): every StepInfo leaf --
-    latency histogram included -- and all non-value state bit-exact. Slow
-    tier: the batched A/B above is the tier-1 gate, and the unbatched kernel's
-    offer-tick plane is already oracle-checked every tick by the parity
-    matrix (tests/test_oracle_parity.py client rows)."""
-    step = jax.jit(lambda s, i, c: raft.step(SCFG, s, i._replace(client_cmd=c)))
-    key = jax.random.key(3)
-    k_init, k_run = jax.random.split(key)
-
-    def drive(values):
-        # The unbatched kernel takes one scalar offer per tick: one column of
-        # the (broadcast) [T, B] plane.
-        plane = np.asarray(_plane(values, ticks=48, batch=1))[:, 0]
-        s = init_state(SCFG, k_init)
-        infos = []
-        for t in range(48):
-            inp = faults.make_inputs(SCFG, k_run, s.now)
-            s, info = step(s, inp, jnp.int32(plane[t]))
-            infos.append(jax.device_get(info))
-        return s, infos
-
-    sa, ia = drive(COLLIDING)
-    sb, ib = drive(ARBITRARY)
-    for t, (a, b) in enumerate(zip(ia, ib)):
-        assert_trees_equal(a, b, f"StepInfo diverged at tick {t}")
-    assert_equal_except_values(sa, sb)
-    assert sum(int(i.lat_cnt) for i in ia) == len(COLLIDING)
-    assert sum(int(i.lat_excluded) for i in ia) == 0
 
 
 @pytest.mark.slow

@@ -3,7 +3,7 @@ recovery truncation at word-edge cluster sizes, durability x compacted-carry
 bit-exactness, checkpoint v25, and the durability_lag SLI.
 
 The oracle-parity rows in test_oracle_parity.py carry the per-tick
-correctness claim (n5-durable-* rows, both kernels); this file pins the
+correctness claim (n5-durable-* rows); this file pins the
 plane's EDGES: the recovery arithmetic through the real kernel at N
 straddling the 32-bit vote-plane word boundary (31/32/33 -- elections over
 packed vote words are live around every recovery), the layout-independence
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import tile
 from raft_sim_tpu.sim import faults, scan
 from raft_sim_tpu.storage import plane
@@ -118,7 +118,7 @@ def test_kernel_lost_suffix_truncation_word_edges(n):
         fsync_fire=jnp.zeros(n, bool),
         client_cmd=jnp.int32(NIL),
     )
-    s2, _ = jax.jit(lambda st, i: raft.step(cfg, st, i))(s, inp)
+    s2, _ = jax.jit(lambda st, i: raft_batched.step_one(cfg, st, i))(s, inp)
     rs = np.asarray(restarted)
     expect = np.where(rs, np.maximum(dur_len, log_len - np.asarray(torn)),
                       log_len)
@@ -147,8 +147,8 @@ def test_durability_compact_planes_bitexact():
     k_init, k_run = jax.random.split(key)
     sd = init_state(cfg_d, k_init)
     sc = init_state(cfg_c, k_init)
-    step_d = jax.jit(lambda s, i: raft.step(cfg_d, s, i)[0])
-    step_c = jax.jit(lambda s, i: raft.step(cfg_c, s, i)[0])
+    step_d = jax.jit(lambda s, i: raft_batched.step_one(cfg_d, s, i)[0])
+    step_c = jax.jit(lambda s, i: raft_batched.step_one(cfg_c, s, i)[0])
     inp_d = jax.jit(lambda now: faults.make_inputs(cfg_d, k_run, now))
     inp_c = jax.jit(lambda now: faults.make_inputs(cfg_c, k_run, now))
     for _ in range(80):

@@ -84,7 +84,7 @@ def fuzz_run():
             np.stack([np.asarray(getattr(i, f)) for i in per_tick], axis=1)
             for f in StepInfo._fields
         )
-    )  # leaves [B, T, ...], like scan.run_batch(trace=True)
+    )  # leaves [B, T, ...], like vmap(scan.run)(trace=True)
     loop_final = raft_batched.from_batch_minor(s_t)
     loop_metrics = raft_batched.from_batch_minor(m_t)
     return SimpleNamespace(

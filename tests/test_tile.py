@@ -2,7 +2,7 @@
 exactness, dense-vs-compacted bit-equality, entry-channel backpressure, and
 the layout-keyed anchor guard (ISSUE 14).
 
-The layout is physical only -- both kernels unpack at tick entry and repack
+The layout is physical only -- the kernel unpacks at tick entry and repacks
 at exit -- so the load-bearing claims are (1) pack/unpack is the identity on
 every in-range value at every word-boundary N, (2) whole trajectories are
 bit-identical between the layouts (states, metrics, StepInfo), including
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from raft_sim_tpu import RaftConfig, init_state
-from raft_sim_tpu.models import raft
+from raft_sim_tpu.models import raft_batched
 from raft_sim_tpu.ops import tile
 from raft_sim_tpu.sim import faults, scan
 from raft_sim_tpu.types import compact_twin
@@ -123,8 +123,8 @@ def _fault_cfg(n, **kw):
 
 def _run_both(cfg_d, ticks, seed=0):
     """(dense_state, compact_state, infos_equal) after `ticks` jitted
-    raft.step ticks from the same seed -- step-level jits keep the compile
-    cost far under a scan-shaped program's."""
+    raft_batched.step_one ticks from the same seed -- step-level jits keep the
+    compile cost far under a scan-shaped program's."""
     cfg_c = compact_twin(cfg_d)
     key = jax.random.key(seed)
     k_init, k_run = jax.random.split(key)
@@ -134,8 +134,8 @@ def _run_both(cfg_d, ticks, seed=0):
     # layouts except the mask's flat shipping shape). Info equality is
     # asserted at the final tick only -- the per-tick info stream folds into
     # the metrics the batch-minor lockstep test compares in full.
-    step_d = jax.jit(lambda s, i: raft.step(cfg_d, s, i))
-    step_c = jax.jit(lambda s, i: raft.step(cfg_c, s, i))
+    step_d = jax.jit(lambda s, i: raft_batched.step_one(cfg_d, s, i))
+    step_c = jax.jit(lambda s, i: raft_batched.step_one(cfg_c, s, i))
     inp_fn_d = jax.jit(lambda now: faults.make_inputs(cfg_d, k_run, now))
     inp_fn_c = jax.jit(lambda now: faults.make_inputs(cfg_c, k_run, now))
     info_d = info_c = None

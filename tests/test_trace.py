@@ -121,9 +121,9 @@ def test_trace_requires_track_trace():
 
 
 def _host_delta_events(cfg, init, states, cluster):
-    """Re-derive the delta-based event kinds (1..9) host-side from an
-    UNBATCHED-kernel state stack -- the cross-kernel oracle: the batched
-    path's device events must match what raft.step's trajectory implies."""
+    """Re-derive the delta-based event kinds (1..9) host-side from a
+    `scan.run` state stack (the single-cluster traced scan): the windowed
+    trace path's device events must match what that trajectory implies."""
     fields = ("role", "term", "voted_for", "commit_index", "log_len")
     g0 = {f: np.asarray(getattr(init, f))[cluster] for f in fields}
     gs = {f: np.asarray(getattr(states, f))[cluster] for f in fields}
@@ -157,8 +157,9 @@ def _host_delta_events(cfg, init, states, cluster):
 
 
 def test_device_events_match_unbatched_kernel_replay():
-    # Reproduce simulate_windowed's exact init/key derivation, then drive the
-    # UNBATCHED kernel (raft.step) and re-extract events from its states.
+    # Reproduce simulate_windowed's exact init/key derivation, then replay
+    # each cluster through the single-cluster traced scan (scan.run) and
+    # re-extract events host-side from its stacked states.
     root = jax.random.key(SEED)
     k_init, k_run = jax.random.split(root)
     state0 = init_batch(CFG_T, k_init, BATCH)

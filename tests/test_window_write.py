@@ -6,7 +6,7 @@ The references below are those forms as the kernel wrote them: an
 [N, E, CAP, B] one-hot of every written entry's position (a per-slot modulo
 in the ring form), summed over E into a ring-sized value plane, then selected
 into the slots the window covers. Every slot must come out equal.
-test_batched_parity.py holds the whole kernel to raft.py along trajectories;
+test_batched_parity.py holds the whole kernel to the oracle along trajectories;
 this file reaches the corners a trajectory rarely does (windows that wrap or
 run off the ring, absolute starts many rings deep, the compaction skip `lo`,
 empty windows, the gate off, and the int32 extremes as values).

@@ -87,8 +87,8 @@ def shrink(
             continue
 
         # First offending cluster; replay it alone from the chunk start with
-        # full per-tick info + states (bit-identical to the batched run --
-        # tests/test_batched_parity.py).
+        # full per-tick info + states (scan.run: the same tick body at B = 1,
+        # bit-identical to the batched run -- tests/test_batched_parity.py).
         cluster = int(np.argmax(viol > 0))
         one = jax.tree.map(lambda x: x[cluster], state)
         _, _, (infos, states) = jax.jit(
