@@ -234,10 +234,12 @@ def ring_chk(log_term, log_val, base, uptos: tuple):
 # the lane dimension, which TPU gathers serialize (measured ~5x slower than the whole
 # rest of the tick). CAP/E are small static dims, so every indexed read is instead a
 # one-hot compare-and-reduce over the indexed axis -- pure elementwise work that
-# vectorizes across lanes -- and every window write is a select on each slot's window
-# offset (_at_offset). Equality with the gather forms is pinned by
-# tests/test_batched_parity.py; tests/test_window_write.py holds the writes to the
-# one-hot forms they replaced.
+# vectorizes across lanes; the ring's window read first narrows that axis to one
+# tile by a select tree (window_rb) -- and every window write is a select on each
+# slot's window offset (_at_offset). Equality with the gather forms is pinned by
+# tests/test_batched_parity.py; tests/test_window_write.py and
+# tests/test_window_read.py hold the writes and the tiled read to the one-hot forms
+# they replaced.
 # --------------------------------------------------------------------------------------
 
 
@@ -261,26 +263,36 @@ def iota(shape, d):
     """int32 iota built at its final rank. The single shared helper for all batched
     kernels: Mosaic (Pallas TPU) cannot lower the unit-dim-appending reshapes that
     `jnp.arange(...)[None, :, None]` produces, and these ops run inside the
-    pallas_engine kernel."""
+    pallas_engine kernel. (window_rb's tiled form, at CAP > _TILE, adds a wrapping
+    pad and unaligned static slices along CAP that no Mosaic lowering has been
+    tried on: tests/test_pallas.py runs CAP 8, one tile.)"""
     return jax.lax.broadcasted_iota(jnp.int32, shape, d)
+
+
+def _select_tree(cands: list, idx: jax.Array) -> jax.Array:
+    """cands[idx], elementwise: a select tree on idx's low bits (len(cands) - 1
+    selects), with no reduction over the candidates. Where idx >= len(cands) the
+    result is one of them. The candidates broadcast against idx."""
+    level = list(cands)
+    bit = 1
+    while len(level) > 1:
+        if len(level) % 2:
+            level.append(level[-1])  # reached only by idx >= len(cands)
+        odd = (idx & bit) != 0
+        level = [jnp.where(odd, v1, v0) for v0, v1 in zip(level[::2], level[1::2])]
+        bit <<= 1
+    return level[0]
 
 
 def _at_offset(vals: jax.Array, rel: jax.Array) -> jax.Array:
     """out[n, s, b] = vals[n, rel[n, s, b], b] for rel in [0, E), elementwise: a
-    select tree on rel's low bits (E - 1 selects), with no reduction over E and
-    no [N, E, CAP, B] one-hot, so the window writes fuse into their consumers
+    select tree on rel (E - 1 selects), with no reduction over E and no
+    [N, E, CAP, B] one-hot, so the window writes fuse into their consumers
     instead of materializing a ring-sized plane. Where rel >= E the result is
     one of vals' entries; the write forms mask those slots off.
     vals: [N, E, B]; rel: [N, CAP, B] -> [N, CAP, B]."""
-    level = [vals[:, k : k + 1] for k in range(vals.shape[1])]
-    bit = 1
-    while len(level) > 1:
-        if len(level) % 2:
-            level.append(level[-1])  # reached only by rel >= E
-        odd = (rel & bit) != 0
-        level = [jnp.where(odd, v1, v0) for v0, v1 in zip(level[::2], level[1::2])]
-        bit <<= 1
-    return jnp.broadcast_to(level[0], rel.shape)
+    cands = [vals[:, k : k + 1] for k in range(vals.shape[1])]
+    return jnp.broadcast_to(_select_tree(cands, rel), rel.shape)
 
 
 def term_at_b(log_term: jax.Array, index1: jax.Array) -> jax.Array:
@@ -347,6 +359,14 @@ def write_window_b(
 
 # ---- batch-minor ring forms (compaction; see the ring section above) -----------------
 
+# Slots per tile of window_rb's two-level read: a multiple of the 128-lane width,
+# so each tile's slice starts on a vector-register boundary. 256 over 128 from
+# traced runs of fleet-etcd3-b1000 (CAP 4,095, B 1,000) on one TPU v5e: 972.95
+# against 981.10 ns a cluster-tick (outbox 181.2 against 190.8 ns), and
+# 1,017,807 / 1,018,628 against 1,009,535 / 1,009,425 cluster-ticks/s untraced
+# on two seeds.
+_TILE = 256
+
 
 def term_at_rb(log_term, base, base_term, index1):
     """Batched term_at_r. log_term: [N, CAP, B]; base/base_term/index1: [N, B]."""
@@ -357,11 +377,32 @@ def term_at_rb(log_term, base, base_term, index1):
 
 
 def window_rb(arr: jax.Array, start0: jax.Array, e: int) -> jax.Array:
-    """Batched window_r. arr: [N, CAP, B]; start0: [N, B] -> [N, E, B]."""
+    """Batched window_r. arr: [N, CAP, B]; start0: [N, B] -> [N, E, B].
+
+    A ring of one tile (CAP <= _TILE) is read by an [N, E, CAP, B] one-hot.
+    A longer ring is read in two levels, so no reduction runs over CAP: the
+    start is reduced mod CAP once per [N, B] and split into a tile index q
+    and an in-tile offset r; a select tree on q's bits picks, per [N, B], the
+    (_TILE + E - 1)-slot slice starting at slot q * _TILE (slices past CAP
+    wrap to the ring's head); the one-hot then runs over that slice at r.
+    No gather: with the batch minor its indices would vary along the lanes
+    (see the section note above)."""
     cap = arr.shape[1]
-    pos = (start0[:, None, :] + iota((1, e, 1), 1)) % cap  # [N, E, B]
-    oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
-    return jnp.sum(jnp.where(oh, arr[:, None], 0), axis=2)
+    nt = -(-cap // _TILE)
+    if nt == 1:
+        pos = (start0[:, None, :] + iota((1, e, 1), 1)) % cap  # [N, E, B]
+        oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
+        return jnp.sum(jnp.where(oh, arr[:, None], 0), axis=2)
+    w = _TILE + e - 1
+    s = start0 % cap  # [N, B]
+    q = s // _TILE
+    r = s - q * _TILE
+    ring = jnp.pad(arr, ((0, 0), (0, nt * _TILE + e - 1 - cap), (0, 0)), mode="wrap")
+    tiles = [ring[:, t * _TILE : t * _TILE + w] for t in range(nt)]
+    tile = _select_tree(tiles, q[:, None, :])  # [N, w, B]
+    pos = r[:, None, :] + iota((1, e, 1), 1)  # [N, E, B], in [0, w)
+    oh = iota((1, 1, w, 1), 2) == pos[:, :, None, :]  # [N, E, w, B]
+    return jnp.sum(jnp.where(oh, tile[:, None], 0), axis=2)
 
 
 def write_window_rb(arr, start0, vals, gate, lo, count):

@@ -178,14 +178,11 @@ def _tick_loop(text: str):
     return types, body
 
 
-def test_fleet_tick_writes_no_ring_plane_from_window_operands(one_chip):
+@pytest.fixture(scope="module")
+def b1000_tick(one_chip):
     """fleet-etcd3-b1000's chunk program (B 1,000, N 3, a 4,095-slot ring; 8
-    ticks keep the loop body of the cell's 256): no instruction of the tick
-    loop outputs an [N, CAP, B] ring plane when none of its operands holds
-    ring slots ([..., CAP, B]). Such an output is a plane built from window-
-    sized operands alone, stored to HBM and read back by the select that
-    writes it into the ring: what the one-hot AppendEntries write made, once
-    for each written plane (term, value, offer stamp)."""
+    ticks keep the loop body of the cell's 256), compiled once: (cfg, batch,
+    output types by name, the tick loop's instructions)."""
     from benchmark.cell import Cell
 
     cell = Cell(ROOT, "fleet-etcd3-b1000")
@@ -196,8 +193,18 @@ def test_fleet_tick_writes_no_ring_plane_from_window_operands(one_chip):
     compiled = chunked._chunk_donate.lower(
         cfg, _placed(state, one_chip), _placed(keys, one_chip), 8, None, 1
     ).compile()
+    return (cfg, batch, *_tick_loop(compiled.as_text()))
+
+
+def test_fleet_tick_writes_no_ring_plane_from_window_operands(b1000_tick):
+    """No instruction of fleet-etcd3-b1000's tick loop outputs an [N, CAP, B]
+    ring plane when none of its operands holds ring slots ([..., CAP, B]).
+    Such an output is a plane built from window-sized operands alone, stored
+    to HBM and read back by the select that writes it into the ring: what the
+    one-hot AppendEntries write made, once for each written plane (term,
+    value, offer stamp)."""
+    cfg, batch, types, body = b1000_tick
     ring = (cfg.n_nodes, cfg.log_capacity, batch)
-    types, body = _tick_loop(compiled.as_text())
     assert any(ring in _shapes(out) for _, _, out, _ in body)  # the ring is there
     built = [
         name
@@ -208,3 +215,29 @@ def test_fleet_tick_writes_no_ring_plane_from_window_operands(one_chip):
                     for s in _shapes(types.get(op, "")))
     ]
     assert not built, f"ring planes built from window-sized operands: {built}"
+
+
+# Instructions that move data without computing: carry plumbing, and the
+# static slices that wrap the ring's head past its end.
+_PLUMBING = ("parameter", "get-tuple-element", "tuple", "bitcast", "copy", "slice")
+
+
+def test_fleet_tick_reads_no_window_from_whole_ring(b1000_tick):
+    """No computing instruction of fleet-etcd3-b1000's tick loop maps an
+    [N, CAP, B] ring plane straight to an [N, E, B] window: the ring's window
+    reads (replicate's stored terms, outbox's shipped entries) first select
+    one tile of the ring per cluster, so the reduction over window positions
+    runs over a tile, not over CAP. The one-hot over the whole ring made one
+    such fusion per read (replicate's, and outbox's for its planes)."""
+    cfg, batch, types, body = b1000_tick
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    win = (cfg.n_nodes, cfg.max_entries_per_rpc, batch)
+    assert any(win in _shapes(out) for _, _, out, _ in body)  # windows are read
+    whole = [
+        name
+        for name, kind, out, operands in body
+        if kind not in _PLUMBING
+        and win in _shapes(out)
+        and any(ring in _shapes(types.get(op, "")) for op in operands)
+    ]
+    assert not whole, f"window reads reduced over the whole ring: {whole}"
