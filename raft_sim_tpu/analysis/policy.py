@@ -396,7 +396,11 @@ def invariant_leaves(cfg: RaftConfig) -> set[str]:
         inv |= {
             "dur_len", "dur_term", "dur_vote",
             "metric.fsync_lag_sum", "metric.fsync_lag_max",
+            "metric.fsync_stalls", "metric.torn_lost",
         }
+    if not (cfg.durable_storage and cfg.durable_acks):
+        # Late vote responses exist only behind the section-3.8 grant gate.
+        inv |= {"metric.late_grants"}
     return inv
 
 

@@ -447,6 +447,7 @@ def _step_b(
         deadline=jnp.where(rs, s.clock + inp.timeout_draw, s.deadline),
     )
     if dur:
+        phase("storage")
         # Crash recovery (storage/plane.recover, elementwise, so the [N, B]
         # orientation broadcasts through): the disk holds the fsynced prefix
         # for sure plus whatever un-fsynced tail the in-flight writes
@@ -458,7 +459,12 @@ def _step_b(
             s.dur_len, s.dur_term, s.dur_vote,
             s.term, s.voted_for, s.log_len,
         )
+        # Entries the restarts dropped (r_len is s.log_len off restarts).
+        torn_lost = jnp.sum(s.log_len - r_len, axis=0).astype(jnp.int32)
         s = s._replace(term=r_term, voted_for=r_vote, log_len=r_len)
+        phase("deliver")
+    else:
+        torn_lost = np.zeros((b,), np.int32)
     if cfg.pre_vote or rdl or rcf:
         # A restarted node remembers no leader contact: "quiet" immediately
         # (pre-votes grantable, and -- under the lease or log-carried-config
@@ -790,7 +796,9 @@ def _step_b(
         # the durable-log contract is about the entries the recovery would
         # reconstruct, and those are gone). Appends do NOT advance it -- only
         # a completed flush does (phase 7.5).
+        phase("storage")
         dur_mid = jnp.minimum(s.dur_len, log_len)
+        phase("replicate")
     # The offer-stamp plane replicates with the entries it tags, and the
     # config-entry plane under the SAME masks (non-config entries ship 0, so
     # an accepted window scrubs any stale config command off the slots it
@@ -1592,7 +1600,9 @@ def _step_b(
     # post-injection log length and the post-election term/vote. Between
     # flushes the watermark carries (clamped by truncation, dur_mid above).
     if dur:
+        phase("storage")
         fs_fire = inp.fsync_fire & inp.alive
+        fsync_stalls = jnp.sum(inp.fsync_stall & inp.alive, axis=0).astype(jnp.int32)
         dur2_len, dur2_term, dur2_vote = storage_plane.flush(
             fs_fire, dur_mid, s.dur_term, s.dur_vote, log_len, term, voted_for
         )
@@ -1621,6 +1631,9 @@ def _step_b(
                 node_dtype(cfg)
             )
             late_grant = covered2 & ~covered0 & ~granted_any
+        phase("outbox")
+    else:
+        fsync_stalls = np.zeros((b,), np.int32)
 
     # ---- phase 8: outbox ---------------------------------------------------------
     send_append = win | heartbeat  # fresh leaders heartbeat immediately (core.clj:137-138)
@@ -1787,6 +1800,7 @@ def _step_b(
     else:
         out_pv_grant = mb.pv_grant  # zeros, loop-invariant carry component
     if dur and cfg.durable_acks:
+        phase("storage")
         # Late vote-completion response (phase 7.5 gate 2): the flush that
         # just made this voter's grant durable emits the RESP_VOTE edge the
         # grant tick withheld -- toward the recorded candidate, only where
@@ -1795,11 +1809,12 @@ def _step_b(
         # no longer needs). v_to already names the candidate via covered2.
         vfc = jnp.clip(voted_for, 0, n - 1)
         late_edge = (ids2[:, :, None] == vfc[None, :, :]) & late_grant[None, :, :]
-        out_resp_kind = jnp.where(
-            late_edge & (out_resp_kind == 0),
-            jnp.int8(RESP_VOTE),
-            out_resp_kind,
-        )
+        late_sent = late_edge & (out_resp_kind == 0)
+        late_grants = jnp.sum(late_sent, axis=(0, 1)).astype(jnp.int32)
+        out_resp_kind = jnp.where(late_sent, jnp.int8(RESP_VOTE), out_resp_kind)
+        phase("outbox")
+    else:
+        late_grants = np.zeros((b,), np.int32)
     if comp:
         pterm = log_ops.term_at_rb(log_term_arr, base, bterm, ws)
     else:
@@ -1960,7 +1975,8 @@ def _step_b(
         cfg, s, new_state, req_in, resp_in, inp.alive, cmds_cnt, chk_ok,
         lat_sum, lat_cnt, lat_hist, lat_excluded, noop_blocked,
         reads_served, read_lat_sum, read_hist, viol_read_stale,
-        fsync_lag_sum, fsync_lag_max, lease_reads, sh,
+        fsync_lag_sum, fsync_lag_max, lease_reads,
+        fsync_stalls, torn_lost, late_grants, sh,
     )
     phase.close()
     return new_state, info
@@ -1987,6 +2003,9 @@ def _step_info_b(
     fsync_lag_sum: jax.Array,
     fsync_lag_max: jax.Array,
     lease_reads: jax.Array,
+    fsync_stalls: jax.Array,
+    torn_lost: jax.Array,
+    late_grants: jax.Array,
     sh: NodeShardCtx | None = None,
 ) -> StepInfo:
     """Phase 9: on-device safety invariants + observability reductions. All
@@ -2190,4 +2209,7 @@ def _step_info_b(
         joint_ticks=joint_ticks,
         xfer_fired=xfer_fired,
         lease_reads=lease_reads,
+        fsync_stalls=fsync_stalls,
+        torn_lost=torn_lost,
+        late_grants=late_grants,
     )

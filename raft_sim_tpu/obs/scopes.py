@@ -12,14 +12,15 @@ readers all import them.
 | Scope | Code |
 |---|---|
 | `tick.faults` | input draws (`sim/faults.make_inputs`), offer overrides, the batch-minor transpose |
-| `tick.deliver` | phases -1 (restart), 0 (delivery), 1 (term adoption) |
+| `tick.deliver` | phases -1 (restart; recovery is `tick.storage`), 0 (delivery), 1 (term adoption) |
 | `tick.elect` | phases 2, 3.5, 3.7, 4.5 and 7 (RequestVote, PreVote, TimeoutNow, promotion, timers) |
 | `tick.replicate` | phases 3 and 4 (AppendEntries requests, snapshot install, responses) |
 | `tick.commit` | phases 5 and 5.2 (quorum commit, reconfiguration), offer->commit latency |
 | `tick.compact` | phase 5.5 (compaction) and the committed-prefix checksum |
 | `tick.client` | phase 6 (client injection, redirect, election no-op), config origination |
-| `tick.outbox` | phases 7.5 (fsync gates) and 8 (outbox) |
+| `tick.outbox` | phase 8 (outbox) |
 | `tick.membership` | the config derivation from each node's log and the removed-server stepdown (end of tick), the compaction fold of config entries (phase 5.5); reconfiguration only |
+| `tick.storage` | the durable storage plane: crash recovery (phase -1), the truncation clamp of the durable watermark (phase 3), the fsync flush and the section-3.8 gates (phase 7.5), the late vote responses (phase 8); `cfg.durable_storage` only |
 | `tick.check` | the invariants and StepInfo (`_step_info_b`), metric accumulation |
 """
 
@@ -27,7 +28,7 @@ from __future__ import annotations
 
 TICK_PHASES = (
     "faults", "deliver", "elect", "replicate", "commit", "compact", "client",
-    "outbox", "membership", "check",
+    "outbox", "membership", "storage", "check",
 )  # each runs under the named scope `tick.<phase>`
 
 

@@ -319,9 +319,31 @@ def last_index_term_b(log_term: jax.Array, log_len: jax.Array):
 
 def window_b(arr: jax.Array, start0: jax.Array, e: int) -> jax.Array:
     """Batched window. arr: [N, CAP, B]; start0: [N, B] -> [N, E, B], or
-    [N, M, B] -> [N, M, E, B]. Out-of-range slots clamp to the last slot (callers mask
-    with an explicit count), matching the clipped gather form."""
+    [N, M, B] -> [N, M, E, B]. Out-of-range slots clamp to the first or last slot
+    (callers mask with an explicit count), matching the clipped gather form.
+
+    An [N, B] start into a log longer than one tile (CAP > _TILE) is read in
+    window_rb's two levels over the log extended by E - 1 copies of its first
+    slot in front and copies of its last slot behind, so a clamped position
+    reads the slot it clamps to and no reduction runs over CAP. Only the tiles
+    that reach past either end are assembled; the others are slices of the
+    log."""
     cap = arr.shape[1]
+    if start0.ndim == 2 and cap > _TILE:
+        nt = -(-(cap + e - 1) // _TILE)
+        s = jnp.clip(start0, 1 - e, cap - 1) + (e - 1)  # [N, B], in [0, CAP + E - 1)
+        q = s // _TILE
+        r = s - q * _TILE
+        tiles = []
+        for t in range(nt):
+            lo, hi = t * _TILE - (e - 1), (t + 1) * _TILE  # log slots of tile t
+            parts = [arr[:, max(lo, 0) : min(hi, cap)]]
+            if lo < 0:
+                parts.insert(0, jnp.repeat(arr[:, :1], -lo, axis=1))
+            if hi > cap:
+                parts.append(jnp.repeat(arr[:, cap - 1 :], hi - cap, axis=1))
+            tiles.append(jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0])
+        return _tile_read(tiles, q, r, e)
     if start0.ndim == 2:  # [N, B]
         pos = jnp.clip(start0[:, None, :] + iota((1, e, 1), 1), 0, cap - 1)  # [N, E, B]
         oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
@@ -393,12 +415,22 @@ def window_rb(arr: jax.Array, start0: jax.Array, e: int) -> jax.Array:
         pos = (start0[:, None, :] + iota((1, e, 1), 1)) % cap  # [N, E, B]
         oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
         return jnp.sum(jnp.where(oh, arr[:, None], 0), axis=2)
-    w = _TILE + e - 1
     s = start0 % cap  # [N, B]
     q = s // _TILE
     r = s - q * _TILE
     ring = jnp.pad(arr, ((0, 0), (0, nt * _TILE + e - 1 - cap), (0, 0)), mode="wrap")
+    w = _TILE + e - 1
     tiles = [ring[:, t * _TILE : t * _TILE + w] for t in range(nt)]
+    return _tile_read(tiles, q, r, e)
+
+
+def _tile_read(tiles: list, q: jax.Array, r: jax.Array, e: int) -> jax.Array:
+    """The two-level window read of window_rb and window_b: `tiles` are the
+    [N, _TILE + E - 1, B] slices that start at slots 0, _TILE, 2 * _TILE, ...;
+    q/r [N, B] are the tile index and the in-tile offset of each window's
+    first slot. A select tree on q's bits picks each window's tile; an
+    [N, E, _TILE + E - 1, B] one-hot reads the window out of it at r."""
+    w = _TILE + e - 1
     tile = _select_tree(tiles, q[:, None, :])  # [N, w, B]
     pos = r[:, None, :] + iota((1, e, 1), 1)  # [N, E, B], in [0, w)
     oh = iota((1, 1, w, 1), 2) == pos[:, :, None, :]  # [N, E, w, B]

@@ -269,6 +269,12 @@ class FleetSummary(NamedTuple):
     joint_ticks: int
     xfer_fired: int
     lease_reads: int
+    # Storage-plane counters (RunMetrics.fsync_stalls/torn_lost/late_grants;
+    # zeros unless cfg.durable_storage): fleet-total due flushes that jitter
+    # stalled, log entries lost at recovery, and late vote responses.
+    fsync_stalls: int
+    torn_lost: int
+    late_grants: int
 
 
 def gather_metrics(metrics):
@@ -373,6 +379,9 @@ def summarize(metrics) -> FleetSummary:
         joint_ticks=int(np.sum(m.joint_ticks, dtype=np.int64)),
         xfer_fired=int(np.sum(m.xfer_fired, dtype=np.int64)),
         lease_reads=int(np.sum(m.lease_reads, dtype=np.int64)),
+        fsync_stalls=int(np.sum(m.fsync_stalls, dtype=np.int64)),
+        torn_lost=int(np.sum(m.torn_lost, dtype=np.int64)),
+        late_grants=int(np.sum(m.late_grants, dtype=np.int64)),
         **_fsync_lag_rollup(m),
         **_latency_rollup(m),
     )

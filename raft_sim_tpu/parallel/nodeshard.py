@@ -171,6 +171,7 @@ _INPUT_PAD = {
     "read_cmd": ((), 0),
     "fsync_fire": ((0,), False),
     "torn_drop": ((0,), 0),
+    "fsync_stall": ((0,), False),
 }
 
 # A new state/mailbox/input leg without a pad rule would silently corrupt the

@@ -51,6 +51,9 @@ def merge_metrics(a: scan.RunMetrics, b: scan.RunMetrics) -> scan.RunMetrics:
         joint_ticks=a.joint_ticks + b.joint_ticks,
         xfer_fired=a.xfer_fired + b.xfer_fired,
         lease_reads=a.lease_reads + b.lease_reads,
+        fsync_stalls=a.fsync_stalls + b.fsync_stalls,
+        torn_lost=a.torn_lost + b.torn_lost,
+        late_grants=a.late_grants + b.late_grants,
         multi_leader=a.multi_leader + b.multi_leader,
         ticks=a.ticks + b.ticks,
     )

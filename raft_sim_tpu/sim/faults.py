@@ -190,11 +190,12 @@ def _admin_cmds(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
 
 def _storage_draws(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
                    fs_i, jit_t, torn_t, span, traced: bool):
-    """(fsync_fire, torn_drop) draws -- the durable storage plane's disk-fault
-    lattice (raft_sim_tpu/storage). A node's flush completes on the fsync
-    cadence tick unless its per-node latency-jitter Bernoulli stalls it (the
-    slow-disk model: the due flush waits for the NEXT cadence tick, so the
-    durable watermark falls a full interval behind). torn_drop is the
+    """(fsync_fire, fsync_stall, torn_drop) draws -- the durable storage
+    plane's disk-fault lattice (raft_sim_tpu/storage). A node's flush
+    completes on the fsync cadence tick unless its per-node latency-jitter
+    Bernoulli stalls it (the slow-disk model: the due flush waits for the
+    NEXT cadence tick, so the durable watermark falls a full interval
+    behind); fsync_stall marks those stalled due flushes. torn_drop is the
     torn-tail write: the extra entries (1..span, uniform) a restart's tail
     checksum rejects beyond the un-fsynced suffix -- drawn every tick from
     the dedicated stream so the draw sequence is schedule-independent, and
@@ -207,18 +208,19 @@ def _storage_draws(cfg: RaftConfig, tkey: jax.Array, now: jax.Array,
     if traced or cfg.durable_storage:
         k_jit, k_torn, k_span = jax.random.split(jax.random.fold_in(tkey, 7), 3)
         stall = bern_u32(k_jit, jit_t, (n,))
-        fire = (fs_i > 0) & (now % jnp.maximum(fs_i, 1) == 0) & ~stall
+        due = (fs_i > 0) & (now % jnp.maximum(fs_i, 1) == 0)
+        fire = due & ~stall
         torn = bern_u32(k_torn, torn_t, (n,))
         # Traced span bound is fine (precedent: crash_down in _alive_at_t).
         extra = jax.random.randint(k_span, (n,), 1, span + 1)
         torn_drop = jnp.where(torn, extra, 0).astype(jnp.int32)
-        return fire, torn_drop
+        return fire, due & stall, torn_drop
     # Gate off: real (dead) [N] arrays, not the StepInputs Python-int
     # defaults -- the dtype-comment contract fixes the rank per field, and
     # these leaves flow through vmap/eval_shape like the admin commands
-    # above (Pass C prices ~5N B/cluster-tick of dead input on every tier;
+    # above (Pass C prices ~6N B/cluster-tick of dead input on every tier;
     # the kernel never reads them when the gate is off).
-    return jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32)
+    return jnp.zeros((n,), bool), jnp.zeros((n,), bool), jnp.zeros((n,), jnp.int32)
 
 
 def _client_routing(cfg: RaftConfig, tkey: jax.Array):
@@ -351,7 +353,7 @@ def make_inputs(
             cfg, tkey, now, g.reconfig_interval, g.transfer_interval,
             g.read_interval, traced=True,
         )
-        fsync_fire, torn_drop = _storage_draws(
+        fsync_fire, fsync_stall, torn_drop = _storage_draws(
             cfg, tkey, now, g.fsync_interval, g.fsync_jitter, g.torn,
             g.torn_span, traced=True,
         )
@@ -415,7 +417,7 @@ def make_inputs(
             cfg, tkey, now, cfg.reconfig_interval, cfg.transfer_interval,
             cfg.read_interval, traced=False,
         )
-        fsync_fire, torn_drop = _storage_draws(
+        fsync_fire, fsync_stall, torn_drop = _storage_draws(
             cfg, tkey, now, cfg.fsync_interval,
             jnp.uint32(p_to_u32(cfg.fsync_jitter_prob)),
             jnp.uint32(p_to_u32(cfg.torn_tail_prob)),
@@ -445,4 +447,5 @@ def make_inputs(
         read_cmd=read_cmd,
         fsync_fire=fsync_fire,
         torn_drop=torn_drop,
+        fsync_stall=fsync_stall,
     )

@@ -92,6 +92,13 @@ class RunMetrics(NamedTuple):
     joint_ticks: jax.Array  # int32
     xfer_fired: jax.Array  # int32
     lease_reads: jax.Array  # int32
+    # Storage-plane counters (StepInfo.fsync_stalls/torn_lost/late_grants;
+    # zero and loop-invariant unless cfg.durable_storage): due flushes that
+    # jitter stalled on live nodes, log entries recoveries dropped, and vote
+    # responses sent once the flush under their grant landed.
+    fsync_stalls: jax.Array  # int32
+    torn_lost: jax.Array  # int32
+    late_grants: jax.Array  # int32
     # Split-brain exposure: ticks with >= 2 concurrent LEADER roles
     # (StepInfo.n_leaders). LEGAL under partitions (a deposed leader has not
     # heard the news yet) -- only SAME-term double leadership violates
@@ -136,6 +143,9 @@ def init_metrics() -> RunMetrics:
         joint_ticks=z,
         xfer_fired=z,
         lease_reads=z,
+        fsync_stalls=z,
+        torn_lost=z,
+        late_grants=z,
         multi_leader=z,
         ticks=z,
     )
@@ -208,6 +218,9 @@ def _accumulate(m: RunMetrics, info: StepInfo, tick: jax.Array) -> RunMetrics:
         joint_ticks=_add_gated(m.joint_ticks, info.joint_ticks),
         xfer_fired=_add_gated(m.xfer_fired, info.xfer_fired),
         lease_reads=_add_gated(m.lease_reads, info.lease_reads),
+        fsync_stalls=_add_gated(m.fsync_stalls, info.fsync_stalls),
+        torn_lost=_add_gated(m.torn_lost, info.torn_lost),
+        late_grants=_add_gated(m.late_grants, info.late_grants),
         multi_leader=m.multi_leader + (info.n_leaders >= 2),
         ticks=m.ticks + 1,
     )

@@ -114,6 +114,9 @@ def init_recorder(cfg: RaftConfig, k: int, batch: int) -> FlightRecorder:
         joint_ticks=leaf(jnp.int32),
         xfer_fired=leaf(jnp.int32),
         lease_reads=leaf(jnp.int32),
+        fsync_stalls=leaf(jnp.int32),
+        torn_lost=leaf(jnp.int32),
+        late_grants=leaf(jnp.int32),
     )
     return FlightRecorder(
         ring=ring,

@@ -49,6 +49,9 @@ byte-identical, and the disk is perfect again. The cadence and every
 disk-fault probability are tuning knobs inside the gate: the scenario
 genome retimes them as traced data (disk-fault axes, scenario/genome.py),
 so fault sweeps never recompile (jaxpr_audit FORK_PAIRS, config10).
+On, the kernel books the plane's ops under the `tick.storage` scope and
+counts stalled flushes, entries lost at recovery and late vote responses
+(StepInfo/RunMetrics `fsync_stalls`, `torn_lost`, `late_grants`).
 
 v1 restriction: mutually exclusive with ring-log compaction
 (compact_margin > 0) -- the durable watermark does not fold across

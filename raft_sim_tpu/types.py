@@ -497,11 +497,15 @@ class StepInputs(NamedTuple):
     # per-node latency-jitter stall draw); torn_drop is the extra entries a
     # recovery's tail checksum rejects IF the node restarts this tick (the
     # torn-tail write; consumed only on restart ticks, drawn every tick so
-    # the key stream is schedule-independent). Python-scalar defaults like
-    # the admin commands above; make_inputs always materializes real [N]
+    # the key stream is schedule-independent). fsync_stall marks the nodes
+    # whose flush was due this tick (the cadence tick) but the jitter draw
+    # stalled: fsync_fire and fsync_stall partition the due ticks, and the
+    # kernel reads fsync_stall only to count stalls. Python-scalar defaults
+    # like the admin commands above; make_inputs always materializes real [N]
     # arrays (the dtype-comment contract fixes the rank per field).
     fsync_fire: jax.Array = False  # [N] bool; True = flush completes this tick
     torn_drop: jax.Array = 0  # [N] int32: torn-tail entries dropped at recovery
+    fsync_stall: jax.Array = False  # [N] bool; True = a due flush stalled this tick
 
 
 class StepInfo(NamedTuple):
@@ -595,6 +599,15 @@ class StepInfo(NamedTuple):
     joint_ticks: jax.Array = 0  # int32: 1 when any node's cfg_pend > 0
     xfer_fired: jax.Array = 0  # int32: TimeoutNow requests sent
     lease_reads: jax.Array = 0  # int32: reads served by the lease alone
+    # Storage-plane counters (cfg.durable_storage; host-constant zeros
+    # otherwise, folded like the plane counters above): due flushes a live
+    # node's latency jitter stalled this tick, log entries restarting nodes
+    # lost at recovery (log_len minus the recovered length: the un-fsynced
+    # tail past the torn-tail salvage), and vote responses sent late, on the
+    # tick the flush that made their grant durable landed.
+    fsync_stalls: jax.Array = 0  # int32: due flushes stalled on live nodes
+    torn_lost: jax.Array = 0  # int32: log entries dropped by recoveries
+    late_grants: jax.Array = 0  # int32: late vote responses sent
 
 
 def empty_mailbox(cfg: RaftConfig) -> Mailbox:

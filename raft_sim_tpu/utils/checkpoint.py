@@ -120,7 +120,11 @@ from raft_sim_tpu.utils.config import RaftConfig
 #      lease_reads (int32 per cluster), each zero and loop-invariant unless
 #      its gate (cfg.reconfig, leader_transfer, read_lease) is on.
 #      ClusterState and Mailbox unchanged.
-_FORMAT_VERSION = 26
+# v27: storage-plane counters -- RunMetrics gained fsync_stalls, torn_lost
+#      and late_grants (int32 per cluster), each zero and loop-invariant
+#      unless cfg.durable_storage (late_grants also needs durable_acks).
+#      ClusterState and Mailbox unchanged.
+_FORMAT_VERSION = 27
 
 # The single exported source of truth for the on-disk format version
 # (re-exported as raft_sim_tpu.CHECKPOINT_FORMAT_VERSION). Everything that
@@ -136,7 +140,7 @@ FORMAT_VERSION = _FORMAT_VERSION
 # refreshing this pin -- the convention the v2..v19 log always relied on,
 # now machine-checked. Refresh with:
 #     python -c "from raft_sim_tpu.analysis import policy; print(policy.schema_fingerprint())"
-_SCHEMA_FINGERPRINT = (26, "e696d29533a07475")
+_SCHEMA_FINGERPRINT = (27, "554d6bcd934b1eaf")
 
 
 def _normalize(path: str) -> str:

@@ -169,11 +169,12 @@ def test_types_comments_parse_and_hold():
     assert len(specs["ClusterState"]) == 41  # v23: +read_fr (lease anchor)
     # v24: +req_disrupt +ent_cfg +req_base_mold/req_base_pend/req_base_epoch
     assert len(specs["Mailbox"]) == 28  # v22: +xfer_tgt
-    # v25: +fsync_fire/torn_drop (disk-fault lattice draws)
-    assert len(specs["StepInputs"]) == 13  # v22: +reconfig/transfer/read cmds
+    # v25: +fsync_fire/torn_drop (disk-fault lattice draws); v27: +fsync_stall
+    assert len(specs["StepInputs"]) == 14  # v22: +reconfig/transfer/read cmds
     # v25: +fsync_lag_sum/fsync_lag_max (durability-lag SLI counters);
-    # v26: +joint_ticks/xfer_fired/lease_reads (plane counters)
-    assert len(specs["StepInfo"]) == 25  # v23: +viol_read_stale
+    # v26: +joint_ticks/xfer_fired/lease_reads (plane counters);
+    # v27: +fsync_stalls/torn_lost/late_grants (storage-plane counters)
+    assert len(specs["StepInfo"]) == 28  # v23: +viol_read_stale
     assert ast_lint.check_dtype_comments() == []
 
 

@@ -1,11 +1,16 @@
-"""The plane counters (RunMetrics.joint_ticks / xfer_fired / lease_reads) and
-what they cost where their gates are off.
+"""The plane counters (RunMetrics.joint_ticks / xfer_fired / lease_reads, and
+the storage plane's fsync_stalls / torn_lost / late_grants) and what they
+cost where their gates are off.
 
 - With its gate off, a counter's metric leg passes through the tick
   unchanged: no equation of the tick computes it (the read metrics'
   zero-cost contract). With the gate on, the tick computes it.
 - The kernel counts them on trajectories the oracle (tests/oracle.py)
   agrees with, tick by tick, at the TiKV cell's widths.
+- The storage counters are the tick's own facts, restated from its inputs
+  and state (stalls: due flushes jitter stalled on live nodes; torn_lost:
+  what the restarts' recoveries cut off), sum across chunks, and survive
+  `summarize`.
 - The etcd fleet cells' chunk program (`sim/chunked._chunk_donate` at the
   fleet-etcd5-jepsen and fleet-etcd3-b1000 fields) keeps the tick loop it
   had before the counters: the same loop carry and the same instructions in
@@ -19,6 +24,7 @@ Regenerate the pin after an intended change to the fleet tick:
 from __future__ import annotations
 
 import collections
+import dataclasses
 import json
 import os
 import re
@@ -38,7 +44,8 @@ from tests.test_oracle_parity import assert_state_equal
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 GOLDEN_PATH = os.path.join(ROOT, "tests", "golden_chunk_ops.json")
-COUNTERS = ("joint_ticks", "xfer_fired", "lease_reads")
+STORAGE = ("fsync_stalls", "torn_lost", "late_grants")
+COUNTERS = ("joint_ticks", "xfer_fired", "lease_reads", *STORAGE)
 FLEET_CELLS = ("fleet-etcd5-jepsen", "fleet-etcd3-b1000")
 
 
@@ -50,11 +57,15 @@ def cell_fields(workload: str) -> dict:
 
 BASE = RaftConfig(n_nodes=3, log_capacity=8, compact_margin=2, client_interval=2,
                   client_redirect=True, read_interval=1)
-# Each counter's gate alone on (the lease needs election_min >= 2L + 4).
+# Each counter's gate alone on (the lease needs election_min >= 2L + 4; the
+# storage plane excludes compaction and gates its three counters at once).
+DURABLE = dict(compact_margin=0, fsync_interval=2, fsync_jitter_prob=0.3,
+               torn_tail_prob=0.5, lost_suffix_span=3)
 GATED = {
-    "joint_ticks": dict(reconfig_interval=5),
-    "xfer_fired": dict(transfer_interval=5),
-    "lease_reads": dict(read_lease_ticks=2, election_min_ticks=8),
+    ("joint_ticks",): dict(reconfig_interval=5),
+    ("xfer_fired",): dict(transfer_interval=5),
+    ("lease_reads",): dict(read_lease_ticks=2, election_min_ticks=8),
+    STORAGE: DURABLE,
 }
 
 
@@ -83,12 +94,10 @@ def passthrough_counters(cfg: RaftConfig) -> set[str]:
 
 
 def test_counters_pass_through_with_their_gates_off():
-    import dataclasses
-
     assert passthrough_counters(BASE) == set(COUNTERS)
-    for name, gate in GATED.items():
+    for names, gate in GATED.items():
         cfg = dataclasses.replace(BASE, **gate)
-        assert passthrough_counters(cfg) == set(COUNTERS) - {name}, name
+        assert passthrough_counters(cfg) == set(COUNTERS) - set(names), names
     for cell in FLEET_CELLS:
         assert passthrough_counters(RaftConfig(**cell_fields(cell))) == set(COUNTERS)
 
@@ -103,10 +112,8 @@ def test_counters_read_zero_with_their_gates_off():
 def test_both_kernels_count_alike():
     """The batch-minor kernel at the TiKV cell's widths with fast move and
     transfer cadences: every cluster's state equals the oracle's each tick,
-    each counter fires, and the lease never serves more reads than were
+    each of its plane's counters fires, and the lease never serves more reads than were
     served."""
-    import dataclasses
-
     cfg = dataclasses.replace(RaftConfig(**cell_fields("fleet-tikv3-shuffle")),
                               reconfig_interval=7, transfer_interval=5)
     b = 8
@@ -131,7 +138,49 @@ def test_both_kernels_count_alike():
             assert_state_equal(oracle.state_to_dict(take(got, c), cfg), want[c], t)
         assert np.all(info.lease_reads <= info.reads_served)
         total.update({n: int(np.sum(getattr(info, n))) for n in COUNTERS})
-    assert all(total[n] > 0 for n in COUNTERS), total
+    assert all(total[n] > 0 for n in COUNTERS if n not in STORAGE), total
+
+
+def test_storage_counters_restate_the_tick_and_sum_across_chunks():
+    """A durable fleet under crashes: each tick's fsync_stalls and torn_lost
+    equal what its inputs and pre-tick state say (due flushes stalled on
+    live nodes; log_len minus the recovered length on restarting nodes), a
+    late vote response is a vote response in the mailbox, each counter
+    fires, and a run in three chunks accumulates the per-tick sums, which
+    `summarize` reports."""
+    from raft_sim_tpu.parallel import summarize
+    from raft_sim_tpu.types import RESP_VOTE
+
+    cfg = dataclasses.replace(BASE, **DURABLE, crash_prob=0.2, crash_period=16,
+                              crash_down_ticks=4)
+    b, ticks = 8, 96
+    k_init, k_run = jax.random.split(jax.random.key(7))
+    state = init_batch(cfg, k_init, b)
+    keys = jax.random.split(k_run, b)
+    draw = jax.jit(jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now)))
+    bstep = jax.jit(lambda s, i: raft_batched.step_b(cfg, s, i))
+    s_min, now = raft_batched.to_batch_minor(state), state.now
+    total = collections.Counter()
+    for t in range(ticks):
+        inp = jax.device_get(draw(keys, now))
+        pre = jax.device_get(raft_batched.from_batch_minor(s_min))
+        s_min, i_min = bstep(s_min, raft_batched.to_batch_minor(inp))
+        got, info = jax.device_get(raft_batched.from_batch_minor((s_min, i_min)))
+        now = got.now
+        stalls = np.sum(inp.fsync_stall & inp.alive, axis=1)
+        rec = np.maximum(pre.dur_len, pre.log_len - inp.torn_drop)
+        lost = np.sum(np.where(inp.restarted, pre.log_len - rec, 0), axis=1)
+        np.testing.assert_array_equal(info.fsync_stalls, stalls, err_msg=f"tick {t}")
+        np.testing.assert_array_equal(info.torn_lost, lost, err_msg=f"tick {t}")
+        votes = np.sum(got.mailbox.resp_kind == RESP_VOTE, axis=(1, 2))
+        assert np.all(info.late_grants <= votes), t
+        total.update({n: int(np.sum(getattr(info, n))) for n in STORAGE})
+    assert all(total[n] > 0 for n in STORAGE), total
+    _, m = chunked.run_chunked(cfg, state, keys, ticks, chunk=32)
+    m = jax.device_get(m)
+    s = summarize(m)
+    for n in STORAGE:
+        assert int(np.sum(getattr(m, n))) == total[n] == getattr(s, n), n
 
 
 # ------------------------------------------------ the etcd cells' tick loop

@@ -2,7 +2,9 @@
 
 - Every `tick.<phase>` named scope reaches the op metadata of the compiled
   chunk program, at a config that turns on compaction, client redirect,
-  ReadIndex, PreVote and reconfiguration (so no phase is compiled away).
+  ReadIndex, PreVote and reconfiguration (so no phase is compiled away);
+  `tick.storage`, at a config with the durable storage plane on (which
+  excludes compaction), and in no program without that plane.
 - The span ring is bounded, and the loops record their host steps in loop
   order: `run_chunked` (and the telemetry soak) `fleet.init`, `fleet.copy`, then
   dispatch/merge/callback per chunk; `ServeSession.serve` its serve steps.
@@ -36,6 +38,13 @@ CFG = RaftConfig(
 )
 # The membership phase exists only with the reconfiguration plane on.
 SCOPED = dataclasses.replace(CFG, reconfig_interval=5)
+# The storage phase exists only with the durable storage plane on, which
+# excludes compaction; crashes and disk faults make every rule of it live.
+DURABLE = dataclasses.replace(
+    CFG, compact_margin=0, fsync_interval=2, fsync_jitter_prob=0.2,
+    torn_tail_prob=0.3, lost_suffix_span=2, crash_prob=0.1, crash_period=16,
+    crash_down_ticks=4,
+)
 BATCH, CHUNK = 4, 8
 
 
@@ -44,18 +53,39 @@ def _setup(cfg=CFG, batch=BATCH, seed=0):
     return init_batch(cfg, ki, batch), jax.random.split(kr, batch)
 
 
+def _chunk_hlo(cfg):
+    """The optimized HLO text of the donating chunk program, compiled on
+    the CPU at `cfg`."""
+    state, keys = _setup(cfg)
+    return chunked._chunk_donate.lower(
+        cfg, state, keys, CHUNK, None, 1).compile().as_text()
+
+
 @pytest.fixture(scope="module")
 def chunk_hlo():
-    """The optimized HLO text of the donating chunk program, compiled on
-    the CPU at the scoped test config."""
-    state, keys = _setup(SCOPED)
-    return chunked._chunk_donate.lower(
-        SCOPED, state, keys, CHUNK, None, 1).compile().as_text()
+    return _chunk_hlo(SCOPED)
+
+
+@pytest.fixture(scope="module")
+def durable_hlo():
+    return _chunk_hlo(DURABLE)
 
 
 @pytest.mark.parametrize("phase", TICK_PHASES)
-def test_tick_scopes_in_chunk_program(chunk_hlo, phase):
-    assert f"/tick.{phase}/" in chunk_hlo
+def test_tick_scopes_in_chunk_program(request, phase):
+    hlo = request.getfixturevalue("durable_hlo" if phase == "storage" else "chunk_hlo")
+    assert f"/tick.{phase}/" in hlo
+
+
+def test_storage_scope_only_in_durable_programs(chunk_hlo, durable_hlo):
+    """The storage plane's ops carry `tick.storage`; a program without the
+    plane (the scoped config, and the durable config with the plane off)
+    has no op under it."""
+    assert "/tick.storage/" in durable_hlo
+    assert "/tick.storage/" not in chunk_hlo
+    assert "/tick.storage/" not in _chunk_hlo(
+        dataclasses.replace(DURABLE, fsync_interval=0, fsync_jitter_prob=0.0,
+                            torn_tail_prob=0.0, lost_suffix_span=1))
 
 
 def test_phase_scopes_close_on_error_and_reject_unknown_phases():

@@ -178,14 +178,14 @@ def _tick_loop(text: str):
     return types, body
 
 
-@pytest.fixture(scope="module")
-def b1000_tick(one_chip):
-    """fleet-etcd3-b1000's chunk program (B 1,000, N 3, a 4,095-slot ring; 8
-    ticks keep the loop body of the cell's 256), compiled once: (cfg, batch,
-    output types by name, the tick loop's instructions)."""
+def _fleet_tick(one_chip, workload):
+    """`workload`'s chunk program at its traffic's batch (8 ticks keep the
+    loop body of the cell's 256), compiled for one described v5e: (cfg,
+    batch, output types by name, the tick loop's instructions, the
+    compiled program)."""
     from benchmark.cell import Cell
 
-    cell = Cell(ROOT, "fleet-etcd3-b1000")
+    cell = Cell(ROOT, workload)
     cfg = RaftConfig(**cell.raft_fields)
     batch = cell.traffic["batch"]
     state = jax.eval_shape(lambda k: init_batch(cfg, k, batch), jax.random.key(0))
@@ -193,7 +193,34 @@ def b1000_tick(one_chip):
     compiled = chunked._chunk_donate.lower(
         cfg, _placed(state, one_chip), _placed(keys, one_chip), 8, None, 1
     ).compile()
-    return (cfg, batch, *_tick_loop(compiled.as_text()))
+    return (cfg, batch, *_tick_loop(compiled.as_text()), compiled)
+
+
+@pytest.fixture(scope="module")
+def b1000_tick(one_chip):
+    """fleet-etcd3-b1000's chunk program (B 1,000, N 3, a 4,095-slot ring),
+    compiled once."""
+    return _fleet_tick(one_chip, "fleet-etcd3-b1000")[:4]
+
+
+@pytest.fixture(scope="module")
+def wal_tick(one_chip):
+    """fleet-etcd5-wal-jepsen's chunk program (B 10,000, N 5, a 4,095-slot
+    ring without compaction, the durable storage plane on), compiled once."""
+    return _fleet_tick(one_chip, "fleet-etcd5-wal-jepsen")
+
+
+def _ring_planes_from_window_operands(cfg, batch, types, body) -> list:
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    assert any(ring in _shapes(out) for _, _, out, _ in body)  # the ring is there
+    return [
+        name
+        for name, kind, out, operands in body
+        if kind != "parameter"
+        and ring in _shapes(out)
+        and not any(s[-2:] == ring[-2:] for op in operands
+                    for s in _shapes(types.get(op, "")))
+    ]
 
 
 def test_fleet_tick_writes_no_ring_plane_from_window_operands(b1000_tick):
@@ -203,23 +230,26 @@ def test_fleet_tick_writes_no_ring_plane_from_window_operands(b1000_tick):
     to HBM and read back by the select that writes it into the ring: what the
     one-hot AppendEntries write made, once for each written plane (term,
     value, offer stamp)."""
-    cfg, batch, types, body = b1000_tick
-    ring = (cfg.n_nodes, cfg.log_capacity, batch)
-    assert any(ring in _shapes(out) for _, _, out, _ in body)  # the ring is there
-    built = [
-        name
-        for name, kind, out, operands in body
-        if kind != "parameter"
-        and ring in _shapes(out)
-        and not any(s[-2:] == ring[-2:] for op in operands
-                    for s in _shapes(types.get(op, "")))
-    ]
+    built = _ring_planes_from_window_operands(*b1000_tick)
     assert not built, f"ring planes built from window-sized operands: {built}"
 
 
 # Instructions that move data without computing: carry plumbing, and the
 # static slices that wrap the ring's head past its end.
 _PLUMBING = ("parameter", "get-tuple-element", "tuple", "bitcast", "copy", "slice")
+
+
+def _window_reads_over_whole_ring(cfg, batch, types, body) -> list:
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    win = (cfg.n_nodes, cfg.max_entries_per_rpc, batch)
+    assert any(win in _shapes(out) for _, _, out, _ in body)  # windows are read
+    return [
+        name
+        for name, kind, out, operands in body
+        if kind not in _PLUMBING
+        and win in _shapes(out)
+        and any(ring in _shapes(types.get(op, "")) for op in operands)
+    ]
 
 
 def test_fleet_tick_reads_no_window_from_whole_ring(b1000_tick):
@@ -229,15 +259,21 @@ def test_fleet_tick_reads_no_window_from_whole_ring(b1000_tick):
     one tile of the ring per cluster, so the reduction over window positions
     runs over a tile, not over CAP. The one-hot over the whole ring made one
     such fusion per read (replicate's, and outbox's for its planes)."""
-    cfg, batch, types, body = b1000_tick
-    ring = (cfg.n_nodes, cfg.log_capacity, batch)
-    win = (cfg.n_nodes, cfg.max_entries_per_rpc, batch)
-    assert any(win in _shapes(out) for _, _, out, _ in body)  # windows are read
-    whole = [
-        name
-        for name, kind, out, operands in body
-        if kind not in _PLUMBING
-        and win in _shapes(out)
-        and any(ring in _shapes(types.get(op, "")) for op in operands)
-    ]
+    whole = _window_reads_over_whole_ring(*b1000_tick)
+    assert not whole, f"window reads reduced over the whole ring: {whole}"
+
+
+def test_durable_etcd5_tick_keeps_the_tiled_ring_paths(wal_tick):
+    """fleet-etcd5-wal-jepsen's chunk program fits one v5e at B = 10,000,
+    and at N = 5 its tick loop keeps both ring pins above: no ring plane
+    built from window-sized operands, no window read reduced over the whole
+    ring."""
+    *tick, compiled = wal_tick
+    m = compiled.memory_analysis()
+    print(f"fleet-etcd5-wal-jepsen AOT memory_analysis: args "
+          f"{m.argument_size_in_bytes} B, temp {m.temp_size_in_bytes} B")
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+    built = _ring_planes_from_window_operands(*tick)
+    assert not built, f"ring planes built from window-sized operands: {built}"
+    whole = _window_reads_over_whole_ring(*tick)
     assert not whole, f"window reads reduced over the whole ring: {whole}"
