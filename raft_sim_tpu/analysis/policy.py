@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from raft_sim_tpu import types as rst_types
+from raft_sim_tpu.ops import log_ops
 from raft_sim_tpu.sim.scan import RunMetrics
 from raft_sim_tpu.types import ClusterState, Mailbox, StepInfo
 from raft_sim_tpu.utils.config import RaftConfig
@@ -401,6 +402,10 @@ def invariant_leaves(cfg: RaftConfig) -> set[str]:
     if not (cfg.durable_storage and cfg.durable_acks):
         # Late vote responses exist only behind the section-3.8 grant gate.
         inv |= {"metric.late_grants"}
+    if not (cfg.track_offer_ticks and cfg.log_capacity > log_ops._TILE):
+        # The latency pass has a window form only on a ring of more than
+        # one tile (models/raft_batched.offer_latency_b).
+        inv |= {"metric.lat_ring_ticks"}
     return inv
 
 

@@ -91,6 +91,7 @@ co-occur on them). See trace/events.py.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -231,8 +232,8 @@ def step_one(
 def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
                     gsum, gmax, gmin):
     """The tick's offer->commit latency accounting (client workloads only):
-    StepInfo's lat_sum, lat_cnt, lat_hist [BINS, B] and lat_excluded, and the
-    carried lat_frontier.
+    StepInfo's lat_sum, lat_cnt, lat_hist [BINS, B] and lat_excluded, the
+    carried lat_frontier, and StepInfo.lat_ring_ticks.
 
     Each client entry's offer stamp rides the log_tick plane (phase 6 writes
     it at injection; AE replication carries it via Mailbox.ent_tick), so the
@@ -242,7 +243,8 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
     VALUES are never read here: payloads are arbitrary int32, so a value
     colliding with a tick cannot corrupt the histogram. Called after phase 5
     and before compaction/injection can touch slots (the checksum pass's
-    aliasing rule).
+    aliasing rule); on a prefix log of more than one tile, after the
+    injection, which writes above every commit (_step_b).
 
     log_tick [N, CAP, B] offer stamps; lead [N, B] live leaders; commit [N, B];
     base [N, B] log_base, or None without compaction; frontier, now [B];
@@ -250,33 +252,79 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
     g* folds reduce over the node axis across shards (identity unsharded,
     as in _step_b).
 
-    One pass over the ring, equal to the per-slot form
-    tests/test_latency_accounting.py keeps as the reference: no per-slot
-    modulo, and the histogram comes from threshold counts, not from a
-    per-slot bin index."""
-    cap = log_tick.shape[1]
-    # A slot's ring offset from the base slot, without a per-slot modulo
-    # (base >= 0): its absolute index is abs0 + off + 1.
-    sl = log_ops.iota((1, cap, 1), 1)
-    if base is None:
-        off, abs0 = sl, 0
+    Only entries in (frontier, max commit] can count, and each tick that
+    span is the cluster's max-commit advance. A ring of one tile
+    (CAP <= log_ops._TILE) is swept whole. On a longer ring the counts run
+    over the tile that holds entry frontier + 1, extended to cover the
+    log_ops.LAT_WINDOW entries above the frontier (log_ops.ring_tile), on
+    every tick where each cluster's advance fits them; under lax.cond, the
+    ticks where one does not sweep the whole ring, and lat_ring_ticks is 1
+    on those, 0 otherwise. The advance is node-folded, so every shard of a
+    node-sharded program takes the same branch. Both forms equal the
+    per-slot form tests/test_latency_accounting.py keeps as the reference,
+    given the ring's invariant commit <= base + CAP."""
+    cap, b = log_tick.shape[1], log_tick.shape[-1]
+    counts = functools.partial(
+        _latency_counts, lead=lead, commit=commit, now=now, ids2=ids2, n=n,
+        gsum=gsum, gmax=gmax, gmin=gmin,
+    )
+
+    def whole(log_tick):
+        # A slot's ring offset from the base slot, without a per-slot modulo
+        # (base >= 0): its absolute index is abs0 + off + 1.
+        sl = log_ops.iota((1, cap, 1), 1)
+        if base is None:
+            off, abs0 = sl, 0
+        else:
+            off = sl - (base % cap)[:, None, :]
+            off = jnp.where(off < 0, off + cap, off)
+            abs0 = base
+        # Dedup across leader changes AND restarts: a freshly elected
+        # leader's own commit trails the cluster's prior frontier and would
+        # re-count entries its predecessor already reported, so only entries
+        # above the CARRIED monotone frontier contribute (the per-node commit
+        # vector is restart-mutable -- ClusterState.lat_frontier). Compared
+        # in offset space: abs1 > frontier <=> off > frontier - abs0 - 1.
+        lo = frontier[None, :] - abs0 - 1  # [N or 1, B]
+        newly = (off > lo[:, None, :]) & (off <= (commit - abs0 - 1)[:, None, :])
+        return counts(log_tick, newly)
+
+    def window(log_tick):
+        # The ring's tile that holds entry frontier + 1 (slot frontier mod
+        # CAP in either layout), extended to cover LAT_WINDOW entries from
+        # it: tile slot j holds entry frontier + 1 - r + j wherever that
+        # entry is live on the row -- in the ring, above the row's base; in
+        # the prefix layout, at or below CAP, which commit bounds. Counted
+        # as it lies: no reduction aligns the window.
+        lt, r = log_ops.ring_tile(log_tick, frontier[None, :], log_ops.LAT_WINDOW)
+        abs1 = (frontier + 1 - r)[:, None, :] + log_ops.iota((1, lt.shape[1], 1), 1)
+        newly = (abs1 > frontier[None, None, :]) & (abs1 <= commit[:, None, :])
+        if base is not None:
+            newly = newly & (abs1 > base[:, None, :])
+        return counts(lt, newly)
+
+    if cap <= log_ops._TILE:
+        out, ring_ticks = whole(log_tick), np.zeros((b,), np.int32)
     else:
-        off = sl - (base % cap)[:, None, :]
-        off = jnp.where(off < 0, off + cap, off)
-        abs0 = base
-    # Dedup across leader changes AND restarts: a freshly elected leader's
-    # own commit trails the cluster's prior frontier and would re-count
-    # entries its predecessor already reported, so only entries above the
-    # CARRIED monotone frontier contribute (the per-node commit vector is
-    # restart-mutable -- ClusterState.lat_frontier). Compared in offset
-    # space: abs1 > frontier <=> off > frontier - abs0 - 1. Stamps are offer
-    # tick + 1, always in (0, now] at commit time; slots holding no client
-    # entry (no-ops, unwritten) carry stamp 0 and fall out of `cli`.
-    lo = frontier[None, :] - abs0 - 1  # [N or 1, B]
-    newly = (off > lo[:, None, :]) & (off <= (commit - abs0 - 1)[:, None, :])
-    cli = (log_tick >= 1) & (log_tick <= now[None, None, :])
+        adv = gmax(jnp.max(commit, axis=0)) - frontier  # [B]
+        fits = jnp.max(adv) <= log_ops.LAT_WINDOW
+        out = lax.cond(fits, window, whole, log_tick)
+        ring_ticks = jnp.broadcast_to(~fits, (b,)).astype(jnp.int32)
+    lat_frontier = jnp.maximum(frontier, gmax(jnp.max(commit, axis=0)))
+    return (*out, lat_frontier, ring_ticks)
+
+
+def _latency_counts(lt, newly, lead, commit, now, ids2, n, gsum, gmax, gmin):
+    """offer_latency_b's counts over stamps `lt` [N, S, B] and their
+    newly-committed mask [N, S, B] (S slots: the whole ring or the window):
+    (lat_sum, lat_cnt, lat_hist, lat_excluded). Histogram from threshold
+    counts, not from a per-slot bin index."""
+    # Stamps are offer tick + 1, always in (0, now] at commit time; slots
+    # holding no client entry (no-ops, unwritten) carry stamp 0 and fall out
+    # of `cli`.
+    cli = (lt >= 1) & (lt <= now[None, None, :])
     lm = lead[:, None, :] & newly & cli
-    lats = jnp.where(lm, (now + 1)[None, None, :] - log_tick, 0)  # [N, CAP, B]
+    lats = jnp.where(lm, (now + 1)[None, None, :] - lt, 0)  # [N, S, B]
     lat_sum = gsum(jnp.sum(lats, axis=(0, 1)).astype(jnp.int32))
     # Histogram by threshold counts: floor(log2(l)) >= k <=> l >= 2^k, and
     # l >= 1 exactly on lm, so ge[k] counts the entries of bin k or above
@@ -301,8 +349,7 @@ def offer_latency_b(log_tick, lead, commit, base, frontier, now, ids2, n,
     lat_excluded = jnp.maximum(
         gsum(jnp.sum(crossed, axis=(0, 1)).astype(jnp.int32)) - lat_cnt, 0
     )
-    lat_frontier = jnp.maximum(frontier, gmax(jnp.max(commit, axis=0)))
-    return lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier
+    return lat_sum, lat_cnt, lat_hist, lat_excluded
 
 
 def step_b(
@@ -1295,17 +1342,30 @@ def _step_b(
         lease_reads = np.zeros((b,), np.int32)
 
     # ---- offer->commit latency (client workloads only; offer_latency_b) ----------
+    # On a prefix log of more than one tile the pass reads the stamp plane
+    # after phase 6's injection (lat_late), which writes entry log_len + 1,
+    # above every commit (commit <= log_len): no slot the pass counts
+    # changes, and the plane between the AppendEntries write and the
+    # injection is never stored. In the ring a slot this tick's compaction
+    # frees can take the injection (the checksum pass's aliasing rule
+    # below), so there, and on one tile, the pass reads the plane here.
+    lat_late = track and not comp and cap > log_ops._TILE
     if track:
-        lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier = offer_latency_b(
-            log_tick_arr, is_leader & inp.alive, commit, base if comp else None,
-            s.lat_frontier, s.now, ids2, n, gsum, gmax, gmin,
+        lat_pass = functools.partial(
+            offer_latency_b, lead=is_leader & inp.alive, commit=commit,
+            base=base if comp else None, frontier=s.lat_frontier, now=s.now,
+            ids2=ids2, n=n, gsum=gsum, gmax=gmax, gmin=gmin,
         )
+        if not lat_late:
+            (lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier,
+             lat_ring_ticks) = lat_pass(log_tick_arr)
     else:
         lat_sum = jnp.zeros_like(s.now)
         lat_cnt = jnp.zeros_like(s.now)
         lat_hist = jnp.zeros((LAT_HIST_BINS, b), jnp.int32)
         lat_excluded = jnp.zeros_like(s.now)
         lat_frontier = s.lat_frontier
+        lat_ring_ticks = np.zeros((b,), np.int32)
 
     phase("compact")
     # ---- phase 5.5: log compaction -------------------------------------------------
@@ -1520,6 +1580,11 @@ def _step_b(
             inj_oh, jnp.where(cfg_write, cfg_code, 0)[:, None, :], log_cfg_arr
         )
     log_len = log_len + do_write
+
+    if lat_late:
+        phase("commit")
+        (lat_sum, lat_cnt, lat_hist, lat_excluded, lat_frontier,
+         lat_ring_ticks) = lat_pass(log_tick_arr)
 
     phase("elect")
     # ---- phase 7: timers (generate-timeout core.clj:171-174; dispatch :193-195) ----
@@ -1973,7 +2038,7 @@ def _step_b(
 
     info = _step_info_b(
         cfg, s, new_state, req_in, resp_in, inp.alive, cmds_cnt, chk_ok,
-        lat_sum, lat_cnt, lat_hist, lat_excluded, noop_blocked,
+        lat_sum, lat_cnt, lat_hist, lat_excluded, lat_ring_ticks, noop_blocked,
         reads_served, read_lat_sum, read_hist, viol_read_stale,
         fsync_lag_sum, fsync_lag_max, lease_reads,
         fsync_stalls, torn_lost, late_grants, sh,
@@ -1995,6 +2060,7 @@ def _step_info_b(
     lat_cnt: jax.Array,
     lat_hist: jax.Array,
     lat_excluded: jax.Array,
+    lat_ring_ticks: jax.Array,
     noop_blocked: jax.Array,
     reads_served: jax.Array,
     read_lat_sum: jax.Array,
@@ -2198,6 +2264,7 @@ def _step_info_b(
         lat_cnt=lat_cnt,
         lat_hist=lat_hist,
         lat_excluded=lat_excluded,
+        lat_ring_ticks=lat_ring_ticks,
         noop_blocked=noop_blocked,
         lm_skipped_pairs=lm_skipped,
         reads_served=reads_served,

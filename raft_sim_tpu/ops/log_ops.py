@@ -389,6 +389,21 @@ def write_window_b(
 # on two seeds.
 _TILE = 256
 
+# Entries above the latency frontier that raft_batched.offer_latency_b
+# covers at CAP > _TILE: it counts over the (_TILE + LAT_WINDOW - 1)-slot
+# slice of the stamp ring from the tile holding the frontier, and a tick on
+# which some cluster's max commit advances further sweeps the whole ring.
+# The largest advance per tick over a fleet, on one TPU v5e: at most 6
+# entries at B = 1,000 (fleet-etcd3-b1000 and serve-etcd3-saturated's
+# load); at B = 10,000 under fleet-etcd5-wal-jepsen's nemeses a median of
+# 11, 73 at the 90th percentile, 117-129 at the 99th, 225 at most (2 seeds
+# of 1,024 ticks). At 16 or 64, 40% or 25% of that cell's ticks swept the
+# ring; at 128, 1 tick in 512. Traced ns a cluster-tick, parent -> 16 /
+# 128 / 256, with the pass read before the injection in both cells: b1000
+# 972.86 -> 877.43 / 843.88 / 856.55; wal 2,179.34 -> 2,139.83 / 2,142.87
+# / 2,220.04.
+LAT_WINDOW = 128
+
 
 def term_at_rb(log_term, base, base_term, index1):
     """Batched term_at_r. log_term: [N, CAP, B]; base/base_term/index1: [N, B]."""
@@ -410,18 +425,37 @@ def window_rb(arr: jax.Array, start0: jax.Array, e: int) -> jax.Array:
     No gather: with the batch minor its indices would vary along the lanes
     (see the section note above)."""
     cap = arr.shape[1]
-    nt = -(-cap // _TILE)
-    if nt == 1:
+    if cap <= _TILE:
         pos = (start0[:, None, :] + iota((1, e, 1), 1)) % cap  # [N, E, B]
         oh = iota((1, 1, cap, 1), 2) == pos[:, :, None, :]  # [N, E, CAP, B]
         return jnp.sum(jnp.where(oh, arr[:, None], 0), axis=2)
+    tiles, q, r = _ring_tiles(arr, start0, e)
+    return _tile_read(tiles, q, r, e)
+
+
+def ring_tile(arr: jax.Array, start0: jax.Array, e: int):
+    """The first level of window_rb's read alone, at CAP > _TILE: (tile, r)
+    with tile [N, _TILE + E - 1, B] the ring's slots from q * _TILE on
+    (wrapping past CAP), picked per [N, B] by a select tree, and r [N, B]
+    the offset in it of slot start0 mod CAP, where start0 mod CAP =
+    q * _TILE + r. Slots r .. r + E - 1 of the tile are the E-slot window
+    at start0. arr: [N, CAP, B]; start0: [N, B] (or [1, B], broadcast)."""
+    tiles, q, r = _ring_tiles(arr, start0, e)
+    return _select_tree(tiles, q[:, None, :]), r
+
+
+def _ring_tiles(arr, start0, e):
+    """window_rb's candidate tiles: the (_TILE + E - 1)-slot slices of the
+    ring starting at slots 0, _TILE, 2 * _TILE, ... (wrapped past CAP by a
+    pad), and the tile index q and in-tile offset r of start0 mod CAP."""
+    cap = arr.shape[1]
+    nt = -(-cap // _TILE)
     s = start0 % cap  # [N, B]
     q = s // _TILE
     r = s - q * _TILE
     ring = jnp.pad(arr, ((0, 0), (0, nt * _TILE + e - 1 - cap), (0, 0)), mode="wrap")
     w = _TILE + e - 1
-    tiles = [ring[:, t * _TILE : t * _TILE + w] for t in range(nt)]
-    return _tile_read(tiles, q, r, e)
+    return [ring[:, t * _TILE : t * _TILE + w] for t in range(nt)], q, r
 
 
 def _tile_read(tiles: list, q: jax.Array, r: jax.Array, e: int) -> jax.Array:

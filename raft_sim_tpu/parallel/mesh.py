@@ -275,6 +275,11 @@ class FleetSummary(NamedTuple):
     fsync_stalls: int
     torn_lost: int
     late_grants: int
+    # Latency-pass fallback (RunMetrics.lat_ring_ticks; zero unless
+    # track_offer_ticks at CAP > 256): fleet-total cluster-ticks whose
+    # offer->commit pass swept the whole ring instead of the window; over
+    # the cluster-ticks run, the fallback share.
+    lat_ring_ticks: int
 
 
 def gather_metrics(metrics):
@@ -382,6 +387,7 @@ def summarize(metrics) -> FleetSummary:
         fsync_stalls=int(np.sum(m.fsync_stalls, dtype=np.int64)),
         torn_lost=int(np.sum(m.torn_lost, dtype=np.int64)),
         late_grants=int(np.sum(m.late_grants, dtype=np.int64)),
+        lat_ring_ticks=int(np.sum(m.lat_ring_ticks, dtype=np.int64)),
         **_fsync_lag_rollup(m),
         **_latency_rollup(m),
     )

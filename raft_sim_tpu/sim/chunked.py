@@ -54,6 +54,7 @@ def merge_metrics(a: scan.RunMetrics, b: scan.RunMetrics) -> scan.RunMetrics:
         fsync_stalls=a.fsync_stalls + b.fsync_stalls,
         torn_lost=a.torn_lost + b.torn_lost,
         late_grants=a.late_grants + b.late_grants,
+        lat_ring_ticks=a.lat_ring_ticks + b.lat_ring_ticks,
         multi_leader=a.multi_leader + b.multi_leader,
         ticks=a.ticks + b.ticks,
     )

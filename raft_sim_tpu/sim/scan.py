@@ -99,6 +99,10 @@ class RunMetrics(NamedTuple):
     fsync_stalls: jax.Array  # int32
     torn_lost: jax.Array  # int32
     late_grants: jax.Array  # int32
+    # Latency-pass fallback ticks (StepInfo.lat_ring_ticks; zero and
+    # loop-invariant unless track_offer_ticks at CAP > 256): ticks whose
+    # offer->commit pass swept the whole ring, not the window.
+    lat_ring_ticks: jax.Array  # int32
     # Split-brain exposure: ticks with >= 2 concurrent LEADER roles
     # (StepInfo.n_leaders). LEGAL under partitions (a deposed leader has not
     # heard the news yet) -- only SAME-term double leadership violates
@@ -146,6 +150,7 @@ def init_metrics() -> RunMetrics:
         fsync_stalls=z,
         torn_lost=z,
         late_grants=z,
+        lat_ring_ticks=z,
         multi_leader=z,
         ticks=z,
     )
@@ -221,6 +226,7 @@ def _accumulate(m: RunMetrics, info: StepInfo, tick: jax.Array) -> RunMetrics:
         fsync_stalls=_add_gated(m.fsync_stalls, info.fsync_stalls),
         torn_lost=_add_gated(m.torn_lost, info.torn_lost),
         late_grants=_add_gated(m.late_grants, info.late_grants),
+        lat_ring_ticks=_add_gated(m.lat_ring_ticks, info.lat_ring_ticks),
         multi_leader=m.multi_leader + (info.n_leaders >= 2),
         ticks=m.ticks + 1,
     )

@@ -117,6 +117,7 @@ def init_recorder(cfg: RaftConfig, k: int, batch: int) -> FlightRecorder:
         fsync_stalls=leaf(jnp.int32),
         torn_lost=leaf(jnp.int32),
         late_grants=leaf(jnp.int32),
+        lat_ring_ticks=leaf(jnp.int32),
     )
     return FlightRecorder(
         ring=ring,

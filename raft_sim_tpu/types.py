@@ -608,6 +608,14 @@ class StepInfo(NamedTuple):
     fsync_stalls: jax.Array = 0  # int32: due flushes stalled on live nodes
     torn_lost: jax.Array = 0  # int32: log entries dropped by recoveries
     late_grants: jax.Array = 0  # int32: late vote responses sent
+    # Latency-pass fallback (track_offer_ticks with CAP > 256, where the
+    # offer->commit pass reads the log_ops.LAT_WINDOW entries above the
+    # frontier; a host-constant zero otherwise, folded like the counters
+    # above): 1 on a tick whose pass swept the whole ring instead, because
+    # some cluster of the batch advanced its max commit past the window
+    # (raft_batched.offer_latency_b). Summed over clusters and ticks, over
+    # the cluster-ticks run, it is the share of fallback ticks.
+    lat_ring_ticks: jax.Array = 0  # int32: 1 when the pass swept the ring
 
 
 def empty_mailbox(cfg: RaftConfig) -> Mailbox:

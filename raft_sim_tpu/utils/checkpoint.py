@@ -124,7 +124,11 @@ from raft_sim_tpu.utils.config import RaftConfig
 #      and late_grants (int32 per cluster), each zero and loop-invariant
 #      unless cfg.durable_storage (late_grants also needs durable_acks).
 #      ClusterState and Mailbox unchanged.
-_FORMAT_VERSION = 27
+# v28: RunMetrics gained lat_ring_ticks (int32 per cluster: ticks whose
+#      offer->commit latency pass swept the whole ring, not the window),
+#      zero and loop-invariant unless track_offer_ticks at CAP > 256.
+#      ClusterState and Mailbox unchanged.
+_FORMAT_VERSION = 28
 
 # The single exported source of truth for the on-disk format version
 # (re-exported as raft_sim_tpu.CHECKPOINT_FORMAT_VERSION). Everything that
@@ -140,7 +144,7 @@ FORMAT_VERSION = _FORMAT_VERSION
 # refreshing this pin -- the convention the v2..v19 log always relied on,
 # now machine-checked. Refresh with:
 #     python -c "from raft_sim_tpu.analysis import policy; print(policy.schema_fingerprint())"
-_SCHEMA_FINGERPRINT = (27, "554d6bcd934b1eaf")
+_SCHEMA_FINGERPRINT = (28, "3ee601eab9b342f5")
 
 
 def _normalize(path: str) -> str:

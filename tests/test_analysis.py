@@ -173,8 +173,9 @@ def test_types_comments_parse_and_hold():
     assert len(specs["StepInputs"]) == 14  # v22: +reconfig/transfer/read cmds
     # v25: +fsync_lag_sum/fsync_lag_max (durability-lag SLI counters);
     # v26: +joint_ticks/xfer_fired/lease_reads (plane counters);
-    # v27: +fsync_stalls/torn_lost/late_grants (storage-plane counters)
-    assert len(specs["StepInfo"]) == 28  # v23: +viol_read_stale
+    # v27: +fsync_stalls/torn_lost/late_grants (storage-plane counters);
+    # v28: +lat_ring_ticks (latency-pass fallback ticks)
+    assert len(specs["StepInfo"]) == 29  # v23: +viol_read_stale
     assert ast_lint.check_dtype_comments() == []
 
 

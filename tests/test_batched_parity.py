@@ -10,13 +10,40 @@ import pytest
 
 from raft_sim_tpu import RaftConfig, init_batch
 from raft_sim_tpu.models import raft_batched
+from raft_sim_tpu.ops import log_ops
 from raft_sim_tpu.sim import faults, scan
 from tests import oracle
 from tests.test_oracle_parity import assert_state_equal
 
-# Every config below is also pinned one cluster at a time against the oracle
-# in tests/test_oracle_parity.py; these rows hold the batch-minor layout
-# itself (B = 16, a sample of its clusters) to it, a few seconds each.
+
+def wide_ring(compaction: bool, durable: bool = False) -> RaftConfig:
+    """A tracked 3-node config on a ring longer than one tile (CAP 300 >
+    log_ops._TILE), under loss, partitions and kills: the latency pass
+    reads a window above the frontier there, and sweeps the whole ring on
+    ticks whose commit advance overruns the window. `durable` adds the
+    storage plane of etcd5-wal's cell (a flush each tick, 5% of them late,
+    torn tails on half the restarts), which needs the prefix log."""
+    disk = dict(fsync_interval=1, fsync_jitter_prob=0.05, torn_tail_prob=0.5,
+                lost_suffix_span=8) if durable else {}
+    return RaftConfig(
+        n_nodes=3,
+        log_capacity=300,
+        compact_margin=8 if compaction else 0,
+        client_interval=1,
+        drop_prob=0.1,
+        partition_period=16,
+        partition_prob=0.5,
+        crash_prob=0.5,
+        crash_period=20,
+        crash_down_ticks=10,
+        **disk,
+    )
+
+
+# Every config below but the last is also pinned one cluster at a time
+# against the oracle in tests/test_oracle_parity.py; these rows hold the
+# batch-minor layout itself (B = 16, a sample of its clusters) to it, a few
+# seconds each.
 CONFIGS = [
     pytest.param(RaftConfig(n_nodes=5, client_interval=8), id="n5"),
     pytest.param(
@@ -136,6 +163,7 @@ CONFIGS = [
         # fold_span snapshot-context advance + req_base_mold install on
         # snapshot catch-up, vs the oracle
     ),
+    pytest.param(wide_ring(True), id="n3-cap300-latency-window"),
 ]
 
 
@@ -196,3 +224,53 @@ def test_run_batch_minor_matches_run_batch():
     f_ref, m_ref, _ = jax.jit(jax.vmap(lambda s, k: scan.run(cfg, s, k, 250)))(state, keys)
     tree_eq(f_ref, f_min)
     tree_eq(m_ref, m_min)
+
+
+@pytest.mark.parametrize(
+    "compaction, durable",
+    [(True, False), (False, False), (False, True)],
+    ids=["ring", "prefix", "prefix-durable"],
+)
+def test_latency_window_matches_whole_ring_along_trajectory(
+    compaction, durable, monkeypatch
+):
+    """`wide_ring` at B = 16 for 150 ticks with a 2-entry latency window,
+    so that both forms of the pass run (`lat_ring_ticks` marks the ticks
+    that swept the ring), against the same run with the window off (every
+    tick sweeps the ring) and against the same run on a log of one tile
+    (`log_ops._TILE` past CAP: the whole-ring pass before compaction and
+    injection, the prefix log's placement too, and every window read by
+    its one-hot): every latency leaf of StepInfo is equal on every tick."""
+    cfg = wide_ring(compaction, durable)
+    batch = 16
+    k_init, k_run = jax.random.split(jax.random.key(1))
+    state = raft_batched.to_batch_minor(init_batch(cfg, k_init, batch))
+    keys = jax.random.split(k_run, batch)
+    draw = jax.vmap(lambda k, now: faults.make_inputs(cfg, k, now))
+
+    def run(window, tile=log_ops._TILE):
+        monkeypatch.setattr(log_ops, "LAT_WINDOW", window)
+        monkeypatch.setattr(log_ops, "_TILE", tile)
+
+        def tick(s, _):
+            inp = raft_batched.to_batch_minor(draw(keys, s.now))
+            return raft_batched.step_b(cfg, s, inp)
+
+        return jax.device_get(
+            jax.jit(lambda s: jax.lax.scan(tick, s, None, length=150))(state)
+        )
+
+    (end_w, win), (end_r, ring), (end_1, one) = (
+        run(2), run(-1), run(2, tile=2 * cfg.log_capacity)
+    )
+    swept = win.lat_ring_ticks[:, 0]
+    assert (win.lat_ring_ticks == swept[:, None]).all()  # batch-wide
+    assert 0 < swept.sum() < len(swept)  # both forms ran
+    assert ring.lat_ring_ticks.all()
+    assert not one.lat_ring_ticks.any()
+    assert win.lat_cnt.sum() > 0
+    for f in ("lat_sum", "lat_cnt", "lat_hist", "lat_excluded"):
+        np.testing.assert_array_equal(getattr(win, f), getattr(ring, f), err_msg=f)
+        np.testing.assert_array_equal(getattr(win, f), getattr(one, f), err_msg=f)
+    for end in (end_r, end_1):
+        tree_eq(end_w, end)

@@ -373,12 +373,12 @@ def test_checkpoint_v20_carries_scenario_and_gates_plain_resume(tmp_path):
 def test_checkpoint_v22_migration_error_names_versions(tmp_path):
     """A v22 file (the pre-lease format: no read_fr staleness leg) errors
     with the migration hint -- the PR 3 hygiene rule, applied across the
-    v23-v27 bumps (the v23-file case rides tests/test_reconfig.py)."""
+    v23-v28 bumps (the v23-file case rides tests/test_reconfig.py)."""
     from raft_sim_tpu.sim.scan import init_metrics_batch
     from raft_sim_tpu.types import init_batch
 
-    assert checkpoint._FORMAT_VERSION == 27  # v27: storage-plane counters
-    assert checkpoint._SCHEMA_FINGERPRINT[0] == 27
+    assert checkpoint._FORMAT_VERSION == 28  # v28: latency-pass fallback ticks
+    assert checkpoint._SCHEMA_FINGERPRINT[0] == 28
     cfg = RaftConfig(n_nodes=2, log_capacity=4, max_entries_per_rpc=1)
     key = jax.random.key(0)
     path = checkpoint.save(
@@ -392,4 +392,4 @@ def test_checkpoint_v22_migration_error_names_versions(tmp_path):
     with pytest.raises(ValueError) as ex:
         checkpoint.load(path)
     msg = str(ex.value)
-    assert "v22" in msg and "v27" in msg and "version log" in msg
+    assert "v22" in msg and "v28" in msg and "version log" in msg

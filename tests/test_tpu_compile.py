@@ -133,12 +133,13 @@ def _shapes(hlo_type: str) -> list:
     return [tuple(int(d) for d in g.split(",") if d) for g in _SHAPE.findall(hlo_type)]
 
 
-def _tick_loop(text: str):
+def _tick_loop(text: str, skip=()):
     """The optimized HLO's tick loop: every instruction's output type by name,
     and (name, kind, output type, operand names) for each instruction of the
-    entry's widest-carry `while` body and of the control flow it calls. Fused
-    computations are not entered: a fusion is one kernel, and its own operands
-    and output are what cross HBM."""
+    entry's widest-carry `while` body and of the control flow it calls, but
+    for the computations named in `skip`. Fused computations are not
+    entered: a fusion is one kernel, and its own operands and output are
+    what cross HBM."""
     comps, types, cur, entry = {}, {}, None, None
     for line in text.splitlines():
         if line and not line[0].isspace():
@@ -156,7 +157,7 @@ def _tick_loop(text: str):
         for m, line in comps[entry]
         if m.group(3) == "while"
     ]
-    body, seen, todo = [], set(), [max(loops)[1]]
+    body, seen, todo = [], set(skip), [max(loops)[1]]
     while todo:
         c = todo.pop()
         if c in seen:
@@ -200,7 +201,7 @@ def _fleet_tick(one_chip, workload):
 def b1000_tick(one_chip):
     """fleet-etcd3-b1000's chunk program (B 1,000, N 3, a 4,095-slot ring),
     compiled once."""
-    return _fleet_tick(one_chip, "fleet-etcd3-b1000")[:4]
+    return _fleet_tick(one_chip, "fleet-etcd3-b1000")
 
 
 @pytest.fixture(scope="module")
@@ -230,7 +231,7 @@ def test_fleet_tick_writes_no_ring_plane_from_window_operands(b1000_tick):
     to HBM and read back by the select that writes it into the ring: what the
     one-hot AppendEntries write made, once for each written plane (term,
     value, offer stamp)."""
-    built = _ring_planes_from_window_operands(*b1000_tick)
+    built = _ring_planes_from_window_operands(*b1000_tick[:4])
     assert not built, f"ring planes built from window-sized operands: {built}"
 
 
@@ -259,7 +260,7 @@ def test_fleet_tick_reads_no_window_from_whole_ring(b1000_tick):
     one tile of the ring per cluster, so the reduction over window positions
     runs over a tile, not over CAP. The one-hot over the whole ring made one
     such fusion per read (replicate's, and outbox's for its planes)."""
-    whole = _window_reads_over_whole_ring(*b1000_tick)
+    whole = _window_reads_over_whole_ring(*b1000_tick[:4])
     assert not whole, f"window reads reduced over the whole ring: {whole}"
 
 
@@ -277,3 +278,105 @@ def test_durable_etcd5_tick_keeps_the_tiled_ring_paths(wal_tick):
     assert not built, f"ring planes built from window-sized operands: {built}"
     whole = _window_reads_over_whole_ring(*tick)
     assert not whole, f"window reads reduced over the whole ring: {whole}"
+
+
+# ------------------------------------ the offer->commit latency pass's window
+
+
+def _latency_conditionals(text: str) -> list:
+    """The branch computations of each conditional in the tick's commit
+    phase: the latency pass's (models/raft_batched.offer_latency_b), whose
+    first branch is the whole-ring fallback and whose second reads the
+    window (lax.cond's false and true branches, in that order)."""
+    return [
+        re.findall(r"%[\w.\-]+", re.search(r"branch_computations=\{([^}]*)\}",
+                                         line).group(1))
+        for line in text.splitlines()
+        if " conditional(" in line and "tick.commit" in line
+    ]
+
+
+@pytest.mark.parametrize("tick", ["b1000_tick", "wal_tick"])
+def test_fleet_tick_counts_latency_over_a_window(tick, request):
+    """In fleet-etcd3-b1000's and fleet-etcd5-wal-jepsen's tick loops (a
+    4,095-slot ring), no instruction outside the latency pass's whole-ring
+    fallback reduces an [N, CAP, B] plane into the [LAT_HIST_BINS, B]
+    histogram or a [B] sum: the pass counts over a window above the
+    frontier. And no copy of an [N, CAP, B] plane feeds the conditional, so
+    the stamp plane reaches both branches in place. The whole-ring pass
+    alone made two such fusions in each tick loop (b1000's
+    %convert_reduce_fusion.37/.38, wal's .35/.37)."""
+    from raft_sim_tpu.types import LAT_HIST_BINS
+
+    cfg, batch, _, _, compiled = request.getfixturevalue(tick)
+    text = compiled.as_text()
+    conds = _latency_conditionals(text)
+    assert len(conds) == 1 and len(conds[0]) == 2, conds
+    types, body = _tick_loop(text, skip={conds[0][0]})
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    sums = {(batch,), (LAT_HIST_BINS, batch)}
+    # Each reduce's output type and operands, by the computation it is in,
+    # and the computation each fusion calls. Names are unique in a module.
+    reduces, calls, cur = {}, {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            m = _COMP.match(line)
+            cur = m.group(1) if m else None
+        elif cur is not None and (m := _INSTR.match(line)):
+            types.setdefault(m.group(1), m.group(2))
+            if m.group(3) == "reduce":
+                ops = re.findall(r"%[\w.\-]+", line[m.end():].split(")", 1)[0])
+                reduces.setdefault(cur, []).append((m.group(2), ops))
+            elif c := re.search(r"calls=(%[\w.\-]+)", line):
+                calls[m.group(1)] = c.group(1)
+    reduced = {
+        name
+        for name, kind, out, operands in body
+        for red_out, red_ops in (
+            reduces.get(calls.get(name), []) if kind == "fusion"
+            else [(out, operands)] if kind == "reduce" else []
+        )
+        if sums & set(_shapes(red_out))
+        and any(ring in _shapes(types.get(op, "")) for op in red_ops)
+    }
+    assert not reduced, f"latency counts over the whole ring: {reduced}"
+    kinds = {name: (kind, operands) for name, kind, _, operands in body}
+    feeds = [
+        op
+        for name, kind, _, operands in body
+        if kind == "conditional"
+        for tup in operands[1:]
+        for op in kinds.get(tup, ("", [tup]))[1]
+    ]
+    copied = [
+        op for op in feeds
+        if kinds.get(op, ("",))[0].startswith("copy")
+        and ring in _shapes(types.get(op, ""))
+    ]
+    assert feeds and not copied, f"ring planes copied into the conditional: {copied}"
+
+
+def _planes_stored_twice(cfg, batch, types, body) -> list:
+    ring = (cfg.n_nodes, cfg.log_capacity, batch)
+    return [
+        name for name, kind, out, _ in body
+        if kind not in _PLUMBING and _shapes(out).count(ring) >= 2
+    ]
+
+
+def test_durable_etcd5_tick_stores_the_stamp_plane_once(wal_tick):
+    """fleet-etcd5-wal-jepsen's log is a prefix, so its latency pass reads
+    the stamp plane after phase 6's injection, and no instruction of the
+    tick loop outputs two [N, CAP, B] planes. Read before the injection,
+    the plane between the AppendEntries write and the injection was stored
+    beside the carried one by one fusion with both outputs (%fusion.890, a
+    third of that fusion's HBM traffic)."""
+    twice = _planes_stored_twice(*wal_tick[:4])
+    assert not twice, f"ring planes stored twice by one instruction: {twice}"
+
+
+def test_jepsen_tick_keeps_the_whole_ring_latency_pass(one_chip):
+    """fleet-etcd5-jepsen's ring (64 slots) is one tile: its latency pass is
+    the whole-ring one, with no conditional in the commit phase."""
+    *_, compiled = _fleet_tick(one_chip, "fleet-etcd5-jepsen")
+    assert _latency_conditionals(compiled.as_text()) == []
